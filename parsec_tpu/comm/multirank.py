@@ -41,9 +41,10 @@ def run_multirank(nranks: int, fn: Callable[[Context, int, int], Any],
     funneled mode) — the default for tests, deterministic and cheap.
 
     ``transport="device"`` attaches the device-backed engine
-    (:mod:`parsec_tpu.comm.device_fabric`): rank *i* owns JAX device *i* and
-    payloads move device-to-device — the configuration the driver's
-    multichip dryrun certifies.
+    (:mod:`parsec_tpu.comm.device_fabric`): rank *i* owns JAX device *i* —
+    its payloads move device-to-device and its device chores run on that
+    chip alone — the configuration the driver's multichip dryrun
+    certifies.
     """
     if transport == "device":
         from .device_fabric import DeviceFabric
@@ -54,7 +55,9 @@ def run_multirank(nranks: int, fn: Callable[[Context, int, int], Any],
     errors: list[BaseException | None] = [None] * nranks
 
     def rank_main(rank: int) -> None:
-        ctx = Context(nb_cores=nb_cores, nb_ranks=nranks, my_rank=rank)
+        ctx = Context(nb_cores=nb_cores, nb_ranks=nranks, my_rank=rank,
+                      accelerators=[fabric.devices[rank]]
+                      if transport == "device" else None)
         eng = RemoteDepEngine(ctx, fabric.attach(rank))
         try:
             ctx.start()

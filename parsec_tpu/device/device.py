@@ -4,9 +4,11 @@ Rebuild of ``parsec/mca/device/device.{c,h}`` (SURVEY §2.5): devices register
 with the process-global registry; each carries transfer/execution statistics
 (``device.h:151-156``), per-precision gflops ratings and a load accumulator
 (``device.h:161-166``); ``best_device`` implements
-``parsec_get_best_device`` = argmin over (device_load + time_estimate(task))
-with task classes contributing ``time_estimate`` functions
-(``parsec_internal.h:441``).
+``parsec_get_best_device``: the device that already owns the task's RW
+data, else argmin over (device_load + time_estimate(task)) with task
+classes contributing ``time_estimate`` functions
+(``parsec_internal.h:441``); the chosen device carries the task's estimate
+in its load from selection until the task completes.
 """
 
 from __future__ import annotations
@@ -16,6 +18,7 @@ from typing import Any
 
 from ..core.params import params as _params
 from ..core.info import InfoObjectArray
+from ..data.data import ACCESS_WRITE
 
 # ---------------------------------------------------------------------------
 # process-wide XLA dispatch ledger
@@ -44,6 +47,16 @@ def xla_calls_total() -> int:
         return _xla_calls
 
 
+# load a task of a class without a ``time_estimate`` contributes: unrated
+# classes still spread over devices by task count
+_NOMINAL_TASK_S = 1e-6
+
+
+def task_load(task: Any, dev: "Device") -> float:
+    te = task.task_class.time_estimate
+    return te(task, dev) if te is not None else _NOMINAL_TASK_S
+
+
 class Device:
     """Base device module (cf. ``parsec_device_module_t``)."""
 
@@ -62,13 +75,27 @@ class Device:
         self.gflops_fp32 = 1.0
         self.gflops_fp64 = 1.0
         self.device_load = 0.0
-        self._load_lock = threading.Lock()
+        # guards device_load and executed_tasks, which worker threads of
+        # several streams (and several in-process ranks) update
+        self._lock = threading.Lock()
         self.infos = InfoObjectArray(self)
 
-    # load accounting around task execution
+    # load accounting around task execution: best_device adds a task's
+    # estimate when it selects this device, release_task takes it back
     def load_add(self, delta: float) -> None:
-        with self._load_lock:
+        with self._lock:
             self.device_load += delta
+
+    def release_task(self, task: Any) -> None:
+        """The task left this device (completed, or requeued by a
+        demotion): its estimate no longer counts toward the load."""
+        if task.selected_device is self:
+            task.selected_device = None
+            self.load_add(-task_load(task, self))
+
+    def note_executed(self, n: int = 1) -> None:
+        with self._lock:
+            self.executed_tasks += n
 
     def taskpool_register(self, taskpool: Any) -> None:
         """Hook for per-taskpool device state (kernel resolution etc.)."""
@@ -122,19 +149,43 @@ class DeviceRegistry:
     def get(self, index: int) -> Device:
         return self.devices[index]
 
-    def best_device(self, task: Any, device_type: str | None = None) -> Device | None:
-        """``parsec_get_best_device``: min (load + time_estimate)."""
-        cands = [d for d in self.devices
-                 if d.enabled and (device_type is None or d.type == device_type)]
+    def best_device(self, task: Any, device_type: str | None = None,
+                    allowed: Any = None) -> Device | None:
+        """``parsec_get_best_device``.  A task keeps the device already
+        selected for it.  Otherwise the device that owns the data of the
+        task's first written flow wins, so a chain of updates to one tile
+        stays where the tile lives; failing that, min (load +
+        time_estimate).  The choice is committed: recorded in
+        ``task.selected_device`` and added to the device's load until
+        :meth:`Device.release_task`.  ``allowed`` restricts the candidates
+        to a set of device indices (a rank bound to its own chip)."""
+        def usable(d: Device) -> bool:
+            return (d.enabled
+                    and (device_type is None or d.type == device_type)
+                    and (allowed is None or d.device_index in allowed))
+
+        prev = task.selected_device
+        if prev is not None:
+            if usable(prev):
+                return prev
+            prev.release_task(task)
+        cands = [d for d in self.devices if usable(d)]
         if not cands:
             return None
-        te = task.task_class.time_estimate
-
-        def cost(d: Device) -> float:
-            est = te(task, d) if te is not None else 0.0
-            return d.device_load + est
-
-        return min(cands, key=cost)
+        dev = None
+        for f in task.task_class.flows:
+            if f.is_ctl or not (f.access & ACCESS_WRITE):
+                continue
+            datum = getattr(task.data[f.flow_index], "original", None)
+            if datum is not None:
+                dev = next((d for d in cands
+                            if d.device_index == datum.owner_device), None)
+                break
+        if dev is None:
+            dev = min(cands, key=lambda d: d.device_load + task_load(task, d))
+        task.selected_device = dev
+        dev.load_add(task_load(task, dev))
+        return dev
 
     def dump_statistics(self) -> dict[str, dict[str, float]]:
         return {d.name: d.stats() for d in self.devices}
@@ -145,7 +196,7 @@ class DeviceRegistry:
 
 
 registry = DeviceRegistry()
-registry.add(CPUDevice())
+cpu_device = registry.add(CPUDevice())
 
 _params.register("device_tpu_enabled", True,
                         "enable the TPU device module")

@@ -14,21 +14,20 @@ from types import SimpleNamespace
 from typing import Any, Callable
 
 from ..runtime.task import (HOOK_RETURN_DONE, HOOK_RETURN_NEXT)
-from .device import registry
 
 
 def make_device_hook(device_type: str, body: Callable | None,
                      dyld: str | None, ptg: Any = None) -> Callable:
     def hook(es: Any, task: Any) -> int:
-        dev = registry.best_device(task, device_type)
+        dev = es.context.best_device(task, device_type)
         if dev is None:
             return HOOK_RETURN_NEXT  # no such device: fall through to next chore
-        task.selected_device = dev
         submit = body
         if submit is None and dyld is not None:
             from .kernels import find_incarnation
             submit = find_incarnation(dyld, dev)
             if submit is None:
+                dev.release_task(task)
                 return HOOK_RETURN_NEXT
         sched = getattr(dev, "kernel_scheduler", None)
         if sched is not None:
@@ -40,7 +39,8 @@ def make_device_hook(device_type: str, body: Callable | None,
             rc = submit(es, task, g, l)
         else:
             rc = submit(es, task)
-        dev.executed_tasks += 1
+        dev.release_task(task)
+        dev.note_executed()
         return HOOK_RETURN_DONE if rc is None else rc
 
     return hook

@@ -95,11 +95,11 @@ def trsm_tpu_body(es: Any, task: Any, device: Any) -> Any:
     jax, jnp, jsl = _jax()
     lkk = task.data[0].value
     c = task.data[1]
-    # right-solve against Lᵀ via the explicit triangular inverse — even
-    # standalone (no CSE) this measures faster than the direct rhs solve
-    # on v5e (150ms vs 213ms at nb=1024: XLA specializes the identity-rhs
-    # solve, and the MXU eats the extra matmul); slightly weaker forward
-    # error than substitution on ill-conditioned panels
+    # right-solve against Lᵀ via the explicit triangular inverse: XLA
+    # specializes the identity-rhs solve and the MXU eats the extra
+    # matmul (its gain over the direct rhs solve: not measured on this
+    # machine); slightly weaker forward error than substitution on
+    # ill-conditioned panels
     c.value = _trsm_traceable(lkk, c.value)
     c.version += 1
     return c.value

@@ -125,11 +125,10 @@ def stencil_1d_ptg(V: VectorTwoDimCyclic, weights: np.ndarray,
         rg = (jnp.zeros((R_,), ct) if right is None
               else right[:R_].astype(ct))
         padded = jnp.concatenate([lg, cw, rg])
-        # the tap loop FUSES into one pass (measured ~370 GB/s effective
-        # standalone on v5e — near half of HBM); a hand kernel gains
-        # nothing here (ops/stencil.py carries the Pallas variant for
-        # shapes XLA fuses poorly), the lowered program's cost lives in
-        # the per-level store reshuffles instead
+        # the tap loop FUSES into one pass (its bandwidth: not measured on
+        # this machine); ops/stencil.py carries the Pallas variant for
+        # shapes XLA fuses poorly, and the lowered program's cost lives
+        # in the per-level store reshuffles instead
         return stencil1d_xla(padded, Wd).astype(dt)
 
     from ..ptg.lowering import Traceable

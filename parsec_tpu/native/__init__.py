@@ -9,8 +9,9 @@ implementing the satisfied-mask protocol (``parsec_update_deps_with_mask``,
 
 ``ensure_built()`` compiles the shared library on demand (cached under
 ``build/``, rebuilt when the source is newer).  Loading is best-effort: when
-no toolchain is available the runtime falls back to the pure-Python
-structures, controlled by the ``runtime_native`` MCA param.
+the build fails the compiler's output is reported once and the runtime
+falls back to the pure-Python structures, controlled by the
+``runtime_native`` MCA param.
 
 Integration points:
 
@@ -46,18 +47,30 @@ _tried = False
 
 def ensure_built(force: bool = False) -> str | None:
     """Compile ``core.cpp`` → ``build/libparsec_tpu_native.so`` if stale.
-    Returns the library path, or None when the build fails."""
-    try:
-        if (not force and os.path.exists(_SO)
-                and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-            return _SO
-        os.makedirs(os.path.dirname(_SO), exist_ok=True)
-        cmd = ["g++", "-O3", "-fPIC", "-std=c++17", "-Wall", "-mcx16",
-               "-pthread", "-shared", "-o", _SO, _SRC, "-latomic"]
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+    Returns the library path, or None when the build fails.  The compiler
+    writes to a name of its own and the result is renamed into place, so
+    a rank loading the library while another builds it never maps a
+    half-written file."""
+    if (not force and os.path.exists(_SO)
+            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
         return _SO
-    except Exception:
+    os.makedirs(os.path.dirname(_SO), exist_ok=True)
+    tmp = f"{_SO}.{os.getpid()}.{threading.get_ident()}.tmp"
+    cmd = ["g++", "-O3", "-fPIC", "-std=c++17", "-Wall", "-mcx16",
+           "-pthread", "-shared", "-o", tmp, _SRC, "-latomic"]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
+        return _SO
+    except (OSError, subprocess.SubprocessError) as e:
+        from ..core.output import warning
+        stderr = getattr(e, "stderr", None) or b""
+        warning(f"native core build failed ({e!r}); falling back to the "
+                f"Python structures\n{stderr.decode(errors='replace')}")
         return None
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:

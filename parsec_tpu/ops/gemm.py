@@ -2,15 +2,10 @@
 
 Kernel incarnations for the tiled-GEMM task bodies (the cuBLAS analog of the
 reference's GEMM tests, e.g. ``tests/dsl/dtd/dtd_test_simple_gemm.c``):
-
-- :func:`matmul_xla` — jitted ``C + A@B`` with fp32 accumulation; XLA tiles
-  this onto the MXU and is the default incarnation.
-- :func:`matmul_pallas` — hand-tiled Pallas kernel (VMEM-blocked, fp32
-  accumulator scratch), for cases where fusion with custom epilogues is
-  needed; falls back to interpret mode off-TPU.
-
-Both register in the kernel registry under ``"gemm"`` so PTG/DTD bodies can
-resolve them by name (``dyld=`` contract).
+:func:`matmul_xla` is a jitted ``C + A@B`` with fp32 accumulation, which
+XLA tiles onto the MXU.  The device and host bodies register in the kernel
+registry under ``"gemm"`` so PTG/DTD bodies can resolve them by name
+(``dyld=`` contract).
 """
 
 from __future__ import annotations
@@ -42,55 +37,6 @@ def _gemm_update(a, b, c, precision=None):
 
 def matmul_xla(a: Any, b: Any, c: Any) -> Any:
     return _gemm_update(a, b, c)
-
-
-# ---------------------------------------------------------------------------
-# Pallas tiled kernel
-# ---------------------------------------------------------------------------
-
-def _pallas_matmul_kernel(a_ref, b_ref, c_ref, acc_ref, *, k_steps: int):
-    from jax.experimental import pallas as pl
-
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    acc_ref[:] += jnp.dot(a_ref[:], b_ref[:],
-                          preferred_element_type=jnp.float32)
-
-    @pl.when(pl.program_id(2) == k_steps - 1)
-    def _done():
-        c_ref[:] = acc_ref[:].astype(c_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def matmul_pallas(a: Any, b: Any, bm: int = 256, bn: int = 256,
-                  bk: int = 256, interpret: bool = False) -> Any:
-    """Blocked ``A@B`` with a VMEM fp32 accumulator (double-buffered HBM→VMEM
-    pipelining comes from the grid spec; see /opt/skills/guides/pallas_guide.md)."""
-    from jax.experimental import pallas as pl
-
-    m, k = a.shape
-    k2, n = b.shape
-    assert k == k2, (a.shape, b.shape)
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
-    k_steps = k // bk
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    grid = (m // bm, n // bn, k_steps)
-    return pl.pallas_call(
-        functools.partial(_pallas_matmul_kernel, k_steps=k_steps),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, l: (i, l)),
-            pl.BlockSpec((bk, bn), lambda i, j, l: (l, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(a, b)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Ragged paged-attention kernels: the LLM decode incarnations.
 
 The per-page online-softmax update at the heart of the decode task class
-(``parsec_tpu/llm/decode.py``), in three incarnations sharing one math:
+(``parsec_tpu/llm/decode.py``), in two incarnations sharing one math:
 
 - :func:`attn_page_update_np` / :func:`attn_out_np` — plain numpy, the
   CPU task bodies (fast for the host-dispatched dynamic path: no tracing
@@ -11,12 +11,8 @@ The per-page online-softmax update at the heart of the decode task class
   every live sequence's decode task into ONE XLA call — page shapes are
   uniform by construction (the fill count rides inside the page tensor,
   :mod:`parsec_tpu.data_dist.paged_kv`), which is exactly what makes the
-  ragged batch vmappable;
-- a **Pallas** build seam (:func:`build_pallas_page_update`), resolved
-  through the lazy kernel registry (``device/kernels.py``) when the
-  ``llm_use_pallas`` MCA param is set — the "Ragged Paged Attention"
-  (arxiv 2604.15464) kernel slot; off-TPU it runs in interpret mode so
-  the seam stays CI-testable.
+  ragged batch vmappable.  The device bodies resolve through the lazy
+  kernel registry (``device/kernels.py``).
 
 The accumulator tile is ``(H, D+2)``: columns ``[:D]`` the unnormalized
 weighted value sum, ``[D]`` the running max, ``[D+1]`` the running
@@ -30,13 +26,8 @@ from typing import Any
 
 import numpy as np
 
-from ..core.params import params as _params
 from ..device.kernels import register_kernel, register_lazy_kernel
 from ..ptg.lowering import register_traceable
-
-_params.register("llm_use_pallas", False,
-                 "resolve the ragged decode page kernel through the Pallas "
-                 "build (interpret mode off-TPU) instead of the jnp body")
 
 NEG_INF = -1e30          # finite sentinel: exp(x - m) underflows to 0.0
 
@@ -442,65 +433,13 @@ register_traceable("llm_prefill_copy", _prefill_copy_jnp)
 
 
 # ---------------------------------------------------------------------------
-# Pallas seam: the arxiv-2604.15464 kernel slot
-# ---------------------------------------------------------------------------
-
-def build_pallas_page_update(interpret: bool = False) -> Any:
-    """One-page ragged attention as a Pallas kernel (whole tiles in VMEM
-    — decode pages are far under the VMEM budget; production shapes
-    would pad H·D to the (8, 128) f32 tile, /opt/skills/guides/
-    pallas_guide.md).  ``interpret=True`` runs it off-TPU."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    def kernel(q_ref, page_ref, acc_ref, out_ref):
-        D = acc_ref.shape[1] - 2
-        P = page_ref.shape[1]
-        q = q_ref[0]                                     # (H, D)
-        k = page_ref[0]                                  # (P, H, D)
-        v = page_ref[1]
-        fill = page_ref[2, 0, 0, 0]
-        acc = acc_ref[:]
-        # VPU-shaped reduction: (P,H,D) * (H,D) summed over D
-        scores = jnp.sum(k * q[None], axis=-1) / jnp.sqrt(jnp.float32(D))
-        valid = (jax.lax.broadcasted_iota(jnp.int32, (P, 1), 0)
-                 < fill.astype(jnp.int32))
-        scores = jnp.where(valid, scores, NEG_INF)
-        l_prev = acc[:, D + 1]
-        m_prev = jnp.where(l_prev > 0, acc[:, D], NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0))
-        w = jnp.where(valid, jnp.exp(scores - m_new[None, :]), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        o = acc[:, :D] * alpha[:, None] + jnp.sum(w[:, :, None] * v, axis=0)
-        out_ref[:, :D] = o
-        out_ref[:, D] = m_new
-        out_ref[:, D + 1] = l_prev * alpha + jnp.sum(w, axis=0)
-
-    @jax.jit
-    def page_update(q3, page, acc):
-        return pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
-            interpret=interpret,
-        )(q3.astype(jnp.float32), page.astype(jnp.float32),
-          acc.astype(jnp.float32))
-
-    return page_update
-
-
-# ---------------------------------------------------------------------------
 # device bodies, resolved lazily (register_lazy_kernel: the loaders only
-# build jits — and possibly trace Pallas — on the first real dispatch)
+# build jits on the first real dispatch)
 # ---------------------------------------------------------------------------
 
 def _load_page_body() -> Any:
     import jax
-    if _params.get("llm_use_pallas"):
-        fn = build_pallas_page_update(
-            interpret=jax.default_backend() != "tpu")
-    else:
-        fn = jax.jit(_page_update_jnp)
+    fn = jax.jit(_page_update_jnp)
 
     def body(es: Any, task: Any, device: Any) -> Any:
         acc = task.data[2]

@@ -16,9 +16,8 @@ heads — each device then holds *full sequences for a subset of heads*
 from __future__ import annotations
 
 import jax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import shard_map
 
 
 def seq_to_heads_local(x, axis_name: str = "sp"):

@@ -92,7 +92,8 @@ def make_pipeline_step(mesh: Any, stage_fn: Callable, nstages: int,
     """
     import jax
     import jax.numpy as jnp
-    from ._compat import pcast, shard_map
+    from jax import shard_map
+    from jax.lax import pcast
     from jax.sharding import PartitionSpec as P
 
     # no wraparound pair: the last stage's activation retires into ys, and

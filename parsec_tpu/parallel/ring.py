@@ -24,9 +24,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import shard_map
 
 _NEG_INF = -1e30
 

@@ -23,9 +23,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import shard_map
 
 from .ring import ring_attention_local
 

@@ -16,8 +16,8 @@ survive a wedged run:
   stage-in state to stderr and a ``flightrec-<rank>.json`` artifact.
   ``Context.wait()`` fires it on a :class:`ContextWaitTimeout
   <parsec_tpu.runtime.context.ContextWaitTimeout>` and ``Context.fini()``
-  on a bounded drain that cannot complete — a hung relay produces a
-  diagnosis instead of silence (the round-5 zero-evidence failure mode).
+  on a bounded drain that cannot complete — a hung device or peer
+  produces a diagnosis instead of silence.
 - **Metrics snapshotter** — a thread sampling :data:`SdeCounters
   <parsec_tpu.prof.counters.sde>` and the live properties dictionary on
   ``prof_snapshot_interval`` into a bounded in-memory series.

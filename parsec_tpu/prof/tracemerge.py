@@ -17,8 +17,8 @@ Mechanics:
 - **clock alignment** — ``perf_counter_ns`` clocks are per-process; each
   rank's export carries a ``parsec_clock_sync`` anchor (``unix_ns`` vs
   ``perf_ns``), and every timestamp is shifted onto the shared
-  wall-clock axis before merging (host NTP skew, not relay latency, is
-  the residual error).
+  wall-clock axis before merging (host NTP skew is the residual
+  error).
 - **pid namespacing** — rank *r*'s pids are remapped to ``r*100 + pid``
   (the rank tag comes from the *filename*, ``rank<N>``, for the same
   shell-glob reason as dotmerge).

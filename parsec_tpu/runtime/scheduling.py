@@ -18,11 +18,12 @@ from typing import Any
 
 from ..core.params import params as _params
 from ..data.reshape import reshape_for_edge, reshape_for_writeback
+from ..device.device import cpu_device as _cpu_device
 from ..prof import pins
 from ..prof.pins import PinsEvent
-from .task import (HOOK_RETURN_AGAIN, HOOK_RETURN_ASYNC, HOOK_RETURN_DISABLE,
-                   HOOK_RETURN_DONE, HOOK_RETURN_ERROR, HOOK_RETURN_NEXT,
-                   Task, TaskClass)
+from .task import (DEV_CPU, HOOK_RETURN_AGAIN, HOOK_RETURN_ASYNC,
+                   HOOK_RETURN_DISABLE, HOOK_RETURN_DONE, HOOK_RETURN_ERROR,
+                   HOOK_RETURN_NEXT, Task, TaskClass)
 
 _params.register(
     "runtime_keep_highest_priority_task", True,
@@ -176,6 +177,10 @@ def execute_task(es: ExecutionStream, task: Task) -> int:
                 chore.enabled = False
                 task.chore_mask &= ~(1 << i)
                 continue
+            if chore.device_type == DEV_CPU and rc != HOOK_RETURN_AGAIN:
+                # host bodies run inline, never through a device module:
+                # this is where the CPU device's statistics see them
+                _cpu_device.note_executed()
             return rc
         return HOOK_RETURN_ERROR
     finally:

@@ -1,18 +1,18 @@
 #!/usr/bin/env bash
 # AOT lowering/compile cache warmer (ISSUE 8): populate the persistent
-# lowering + XLA compilation caches BEFORE a bench run, so r06+ TPU
-# stages pay deserialization instead of the ~141s compiles that killed
-# BENCH_r04/r05 (rc 124).  See docs/PERF.md, "Region lowering & compile
-# budgets".
+# lowering + XLA compilation caches BEFORE a bench run, so its stages pay
+# deserialization instead of minutes of XLA compile inside a stage
+# deadline.  See docs/PERF.md, "Region lowering & compile budgets".
 #
 #   scripts/warm_cache.sh                        # default workload set
 #   scripts/warm_cache.sh cholesky gemm          # named workloads
 #   WARM_N=8192 WARM_NB=512 scripts/warm_cache.sh cholesky
 #   WARM_MODES=region WARM_BUDGET=120 scripts/warm_cache.sh cholesky
 #
-# The cache directory is PARSEC_TPU_COMPILE_CACHE_DIR (default
-# <tmp>/parsec-tpu-xla-cache) with a per-(jax version, backend) leaf, so
-# one dir can be shared by CPU and TPU processes safely.
+# The cache directory is JAX_COMPILATION_CACHE_DIR when set (JAX reads it
+# itself), else <checkout>/.jax_cache (parsec_tpu/device/compile_cache.py).
+# JAX keys entries by backend and version, so CPU and TPU processes share
+# one directory safely.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

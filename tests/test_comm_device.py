@@ -129,9 +129,10 @@ def _gemm_body(ctx, rank, nranks):
 
 @pytest.mark.parametrize("nranks", [2, 4])
 def test_block_cyclic_gemm_on_device_transport(nranks):
-    """Distributed GEMM through the task runtime with payloads moving
-    device-to-device; every rank's local tiles must match the dense product
-    — and must match the single-rank run (the dryrun_multichip contract)."""
+    """Distributed GEMM through the task runtime on the device transport;
+    every rank's local tiles must match the dense product — and must match
+    the single-rank run (the dryrun_multichip contract).  Every rank holds
+    the whole of ``a`` and ``b``, so no operand tile crosses ranks here."""
     res = run_multirank(nranks, _gemm_body, transport="device", timeout=180)
     n = 64
     rng = np.random.RandomState(7)

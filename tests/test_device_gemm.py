@@ -195,9 +195,8 @@ class TestVmapBatching:
 
     def test_fused_batch_is_one_xla_call(self, accel_device):
         """The whole batch — on-device stacking, vmapped exec, per-task
-        output slicing — rides ONE enqueue (VERDICT r4 item 5: through a
-        high-latency relay the enqueue count IS the dynamic-path wall;
-        round 4 paid F stacks + exec + unbind per batch)."""
+        output slicing — rides ONE enqueue (a stack-per-flow pipeline pays
+        F stacks + exec + unbind per batch)."""
         self._run(accel_device, True)
         assert accel_device.executed_tasks == 4 * 4 * 4
         assert accel_device.batched_dispatches > 0

@@ -8,12 +8,12 @@ platform-independent XLA, so this coverage is real:
 
 - OOM during stage-in -> LRU eviction + deferred w2r drain, with the
   byte-accounting invariants checked at every drain;
-- an XLA dispatch raising MID-RUN (relay reset) after earlier batches
+- an XLA dispatch raising MID-RUN (device failure) after earlier batches
   left dirty device tiles -> salvage-writeback + demote + requeue, with
   the salvaged values verified against the partial computation;
 - a salvage that cannot write back a newer-than-host tile -> fail-stop
   escalation (wrong answers are worse than stopping);
-- the relay dying during stage-in (``device_put`` raising) -> the same
+- the device dying during stage-in (``device_put`` raising) -> the same
   demote protocol from the H2D boundary.
 """
 
@@ -82,7 +82,7 @@ def test_eviction_accounting_invariants_hold_at_every_drain(dev):
 def test_mid_run_dispatch_failure_salvages_dirty_tiles_and_requeues(
         dev, param):
     """Batches 1..k succeed and leave dirty C tiles device-resident; then
-    the relay 'resets' (the vmapped XLA call raises).  The manager must
+    the device fails (the vmapped XLA call raises).  The manager must
     salvage the PARTIAL results back to host copies, disable the device,
     and requeue the uncompleted tasks onto the CPU incarnation — final
     numerics prove both the salvage values and the requeue set were
@@ -98,7 +98,7 @@ def test_mid_run_dispatch_failure_salvages_dirty_tiles_and_requeues(
     def hook(batch):
         calls["n"] += 1
         if calls["n"] > 2:
-            raise ConnectionResetError("relay reset mid-batch")
+            raise ConnectionResetError("device reset mid-batch")
 
     dev._dispatch_hook = hook
     ctx = Context(nb_cores=0)
@@ -126,7 +126,7 @@ def test_unsalvageable_dirty_tile_fails_stop(dev, param):
     def hook(batch):
         calls["n"] += 1
         if calls["n"] > 1:
-            raise ConnectionResetError("relay reset")
+            raise ConnectionResetError("device reset")
 
     dev._dispatch_hook = hook
 
@@ -180,7 +180,7 @@ def test_fini_reraises_never_surfaced_background_failure():
     ctx.fini()
 
 
-def test_relay_disconnect_during_stage_in_demotes(dev, monkeypatch, param):
+def test_device_failure_during_stage_in_demotes(dev, monkeypatch, param):
     """The H2D boundary dies (device_put raises after N transfers): the
     demote protocol must fire from the stage-in phase too, and the CPU
     incarnations must finish with exact numerics."""
@@ -194,7 +194,7 @@ def test_relay_disconnect_during_stage_in_demotes(dev, monkeypatch, param):
     def flaky_put(x, device=None, **kw):
         calls["n"] += 1
         if calls["n"] > 1:
-            raise ConnectionResetError("relay reset during H2D")
+            raise ConnectionResetError("device reset during H2D")
         return real_put(x, device, **kw)
 
     monkeypatch.setattr(jax, "device_put", flaky_put)

@@ -1,7 +1,7 @@
 """The always-on runtime flight recorder: ring wraparound, the disabled
 path's zero-allocation contract, the stall dump a wedged run must produce
-(the round-5 lesson: a hung relay left NO self-reported evidence), the
-metrics snapshotter, and the unified run-report export."""
+(a hung device or peer must not leave a run without self-reported
+evidence), the metrics snapshotter, and the unified run-report export."""
 
 import json
 import threading

@@ -144,8 +144,7 @@ def test_page_chain_matches_dense_reference_all_incarnations():
     want = ra.ragged_attention_reference(q3[0], ks, vs)
     for name, step in [
             ("numpy", ra.attn_page_update_np),
-            ("jnp", lambda q, p, a: np.asarray(ra._page_update_jnp(q, p, a))),
-            ("pallas", ra.build_pallas_page_update(interpret=True))]:
+            ("jnp", lambda q, p, a: np.asarray(ra._page_update_jnp(q, p, a)))]:
         acc = np.zeros((H, D + 2), np.float32)
         for page in pages:
             acc = np.asarray(step(q3, page, acc))
@@ -352,39 +351,20 @@ def test_superpool_eos_mid_pool_predicated_tail_is_discarded():
     assert _tokens_of(TOK, "b", 8) == want_b
 
 
-def test_superpool_through_device_tier_with_pallas_interpret(
-        accel_device, param):
-    """The ISSUE-9 satellite gating arxiv 2604.15464 end-to-end off-TPU:
-    the FULL k-step pools-vs-oracle token-equality test with the ATTN
-    page kernel resolved through the Pallas build (interpret mode) —
-    not just the kernel-level incarnation equality."""
-    from parsec_tpu.device import kernels as dk
-    param("llm_use_pallas", True)
-    # re-arm the lazy seam: an earlier device-tier test may have
-    # promoted the jnp body already, and the loader reads the param at
-    # build time — drop the eager entry so THIS dispatch builds Pallas
-    with dk._lock:
-        dk._kernels.pop(("ragged_attn_page", "tpu"), None)
-    dk.register_lazy_kernel("ragged_attn_page", "tpu", ra._load_page_body)
-    try:
-        prompts = {"a": [3, 7, 11, 5, 9, 2], "b": [1, 40, 8]}
-        steps = {"a": 5, "b": 5}
-        kv, TOK, tp = _superpool_setup(prompts, steps, devices="tpu")
-        with Context(nb_cores=0) as ctx:
-            ctx.add_taskpool(tp)
-            ctx.wait(timeout=240)
-            accel_device.sync()
-        for seq, prompt in prompts.items():
-            want = MODEL.reference_generate(prompt, steps[seq])
-            assert _tokens_of(TOK, seq, steps[seq]) == want, seq
-        assert accel_device.executed_tasks > 0
-    finally:
-        # leave the seam lazy so later consumers rebuild under the
-        # restored llm_use_pallas value
-        with dk._lock:
-            dk._kernels.pop(("ragged_attn_page", "tpu"), None)
-        dk.register_lazy_kernel("ragged_attn_page", "tpu",
-                                ra._load_page_body)
+def test_superpool_through_device_tier(accel_device):
+    """The full k-step pools-vs-oracle token-equality test with every
+    class dispatched through the device module (jnp bodies)."""
+    prompts = {"a": [3, 7, 11, 5, 9, 2], "b": [1, 40, 8]}
+    steps = {"a": 5, "b": 5}
+    kv, TOK, tp = _superpool_setup(prompts, steps, devices="tpu")
+    with Context(nb_cores=0) as ctx:
+        ctx.add_taskpool(tp)
+        ctx.wait(timeout=240)
+        accel_device.sync()
+    for seq, prompt in prompts.items():
+        want = MODEL.reference_generate(prompt, steps[seq])
+        assert _tokens_of(TOK, seq, steps[seq]) == want, seq
+    assert accel_device.executed_tasks > 0
 
 
 # ---------------------------------------------------------------------------

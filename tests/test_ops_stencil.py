@@ -1,12 +1,12 @@
 """Stencil kernel incarnations (ops/stencil.py): the XLA tap loop and the
-VMEM-resident Pallas variant agree with the numpy oracle across shapes,
-dtypes, batching, and the fallback paths.
+blocked Pallas variant (interpret mode here; Mosaic in chip_smoke.py) agree
+with the numpy oracle across shapes, dtypes, batching and lane tiles.
 """
 
 import numpy as np
 import pytest
 
-from parsec_tpu.ops.stencil import (_MAX_VMEM_ROW, stencil1d_pallas,
+from parsec_tpu.ops.stencil import (_LANE_TILE, stencil1d_pallas,
                                     stencil1d_xla)
 
 
@@ -31,27 +31,36 @@ def test_xla_matches_oracle(R, n):
 @pytest.mark.parametrize("R", [1, 4])
 @pytest.mark.parametrize("shape", [(256,), (4, 256), (3, 1000)])
 def test_pallas_matches_xla(R, shape):
-    """Interpret mode off-TPU: same numerics as the XLA loop."""
+    """Interpret mode: same numerics as the XLA loop."""
     rng = np.random.default_rng(R)
     w = rng.standard_normal(2 * R + 1)
     p = rng.standard_normal(shape[:-1] + (shape[-1] + 2 * R,)).astype(
         np.float32)
-    got = np.asarray(stencil1d_pallas(p, w))
+    got = np.asarray(stencil1d_pallas(p, w, interpret=True))
     want = np.asarray(stencil1d_xla(p, w))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     assert got.shape == shape
 
 
-def test_pallas_large_row_falls_back():
-    """Rows beyond the VMEM budget take the XLA path (same numerics)."""
-    R = 1
-    w = np.array([0.25, 0.5, 0.25])
-    n = _MAX_VMEM_ROW + 8
-    p = np.linspace(0, 1, n + 2 * R).astype(np.float32)
-    got = np.asarray(stencil1d_pallas(p, w))
+def test_pallas_row_spanning_lane_tiles():
+    """A row longer than one lane tile: the taps that straddle a tile
+    boundary read the right neighbour's head, the last tile is ragged."""
+    R = 4
+    w = np.random.default_rng(1).standard_normal(2 * R + 1)
+    n = 2 * _LANE_TILE + 72
+    p = np.random.default_rng(2).standard_normal(n + 2 * R).astype(
+        np.float32)
+    got = np.asarray(stencil1d_pallas(p, w, interpret=True))
     assert got.shape == (n,)
-    np.testing.assert_allclose(got[:64], _oracle(p, w)[:64], rtol=1e-4,
-                               atol=1e-5)
+    np.testing.assert_allclose(got, _oracle(p, w), rtol=1e-5, atol=1e-5)
+
+
+def test_pallas_never_interprets_by_itself():
+    """Off-TPU a call without ``interpret=True`` goes to Mosaic and fails:
+    the kernel does not pick a mode from the backend."""
+    with pytest.raises(ValueError, match="interpret mode"):
+        np.asarray(stencil1d_pallas(np.ones(130, np.float32),
+                                    np.array([0.25, 0.5, 0.25])))
 
 
 def test_dtype_roundtrip():
@@ -60,12 +69,12 @@ def test_dtype_roundtrip():
     w = np.array([0.2, 0.6, 0.2])
     p32 = np.ones(66, np.float32)
     assert np.asarray(stencil1d_xla(p32, w)).dtype == np.float32
-    got = np.asarray(stencil1d_pallas(p32, w))
+    got = np.asarray(stencil1d_pallas(p32, w, interpret=True))
     assert got.dtype == np.float32
     p64 = np.linspace(0, 1, 66)
-    np.testing.assert_allclose(np.asarray(stencil1d_pallas(p64, w)),
-                               _oracle(p64.astype(np.float32), w),
-                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(stencil1d_pallas(p64, w, interpret=True)),
+        _oracle(p64.astype(np.float32), w), rtol=1e-5, atol=1e-5)
 
 
 def test_pallas_three_dim_batch():
@@ -73,7 +82,7 @@ def test_pallas_three_dim_batch():
     w = np.array([0.25, 0.5, 0.25])
     p = np.random.default_rng(0).standard_normal((2, 3, 130)).astype(
         np.float32)
-    got = np.asarray(stencil1d_pallas(p, w))
+    got = np.asarray(stencil1d_pallas(p, w, interpret=True))
     want = np.asarray(stencil1d_xla(p, w))
     assert got.shape == (2, 3, 128)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
