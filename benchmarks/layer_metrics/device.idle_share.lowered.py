@@ -1,0 +1,1 @@
+from trace_reduce import idle_share as read  # noqa: F401

@@ -1,0 +1,1 @@
+from trace_reduce import roofline_share as read  # noqa: F401
