@@ -29,13 +29,14 @@ SURVEY §2.5, §3.5) redesigned around XLA's execution model:
 from __future__ import annotations
 
 import threading
+import time
 from collections import OrderedDict, deque
 from typing import Any, Callable
 
 from ..core.params import params as _params
 from ..data.data import (COHERENCY_EXCLUSIVE, COHERENCY_INVALID,
                          COHERENCY_OWNED, COHERENCY_SHARED, DataCopy)
-from ..prof import pins
+from ..prof import pins, spans
 from ..prof.pins import PinsEvent
 from ..runtime.task import HOOK_RETURN_ASYNC
 from .device import Device, note_xla_calls, registry
@@ -92,6 +93,32 @@ def _fire_spill(data: Any, nbytes: int) -> None:
             pass
     if dead:
         _spill_hooks[:] = [r for r in _spill_hooks if r() is not None]
+
+
+class _Wall:
+    """One phase of the device module, instrumented once: its host wall
+    (``TPUDevice.t_<wall>``, always on, read as deltas by the benchmark and
+    ``debug_state``) and, while the phase plane is on, its span, both from
+    one pair of clock readings."""
+
+    __slots__ = ("dev", "attr", "span", "t0")
+
+    def __init__(self, dev: "TPUDevice", attr: str, name: str) -> None:
+        self.dev = dev
+        self.attr = attr
+        self.span = spans.phase(name) if spans.phase_on else None
+
+    def __enter__(self) -> None:
+        if self.span is None:
+            self.t0 = time.perf_counter_ns()
+        else:
+            self.t0 = self.span.__enter__().t0
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter_ns() - self.t0
+        if self.span is not None:
+            self.span.close(dt, *exc)
+        setattr(self.dev, self.attr, getattr(self.dev, self.attr) + dt / 1e9)
 
 
 class TPUDeviceTask:
@@ -161,6 +188,7 @@ class TPUDevice(Device):
         self.t_dispatch = 0.0
         self.t_complete = 0.0
         self.t_drain = 0.0
+        self.t_writeback = 0.0   # flush_cache, its drain included
         self.t_manager = 0.0   # total wall inside the manager drain loop
         # stage-in tile-cache effectiveness, per (task, flow) reference —
         # the hit-rate gauge the metrics snapshotter samples
@@ -245,44 +273,41 @@ class TPUDevice(Device):
         victim's transfer is *started* asynchronously, then the host
         copies materialize — by which point the first transfers have
         ridden under the batch still executing."""
-        import time as _time
-        t0 = _time.perf_counter()
-        victims = []
-        while True:
-            with self._lru_lock:
-                if not self._evict_q:
-                    break
-                c = self._evict_q.popleft()
-                self._evict_bytes -= _copy_nbytes(c)
-                if self._mem_lru.get(c.original) is c:
-                    continue    # resurrected by a later stage_in
-            if c.coherency != COHERENCY_INVALID:
-                start = getattr(c.value, "copy_to_host_async", None)
-                if start is not None:
-                    try:
-                        start()
-                    except Exception:
-                        pass    # transfer falls back to the sync read below
-                victims.append(c)
-        i = 0
-        if victims:
-            pins.fire(PinsEvent.DEVICE_EVICT, None, len(victims))
-        try:
-            while i < len(victims):
-                self._writeback(victims[i])
-                i += 1
-                self.deferred_evictions += 1
-        except BaseException:
-            # a failed writeback must leave the unwritten victims
-            # reachable: failure recovery salvages from _evict_q, and a
-            # dirty copy outside it would be silently dropped
-            with self._lru_lock:
-                for c in victims[i:]:
-                    self._evict_bytes += _copy_nbytes(c)
-                    self._evict_q.append(c)
-            raise
-        finally:
-            self.t_drain += _time.perf_counter() - t0
+        with _Wall(self, "t_drain", "devmod.drain"):
+            victims = []
+            while True:
+                with self._lru_lock:
+                    if not self._evict_q:
+                        break
+                    c = self._evict_q.popleft()
+                    self._evict_bytes -= _copy_nbytes(c)
+                    if self._mem_lru.get(c.original) is c:
+                        continue    # resurrected by a later stage_in
+                if c.coherency != COHERENCY_INVALID:
+                    start = getattr(c.value, "copy_to_host_async", None)
+                    if start is not None:
+                        try:
+                            start()
+                        except Exception:
+                            pass    # the sync read below transfers it
+                    victims.append(c)
+            i = 0
+            if victims:
+                pins.fire(PinsEvent.DEVICE_EVICT, None, len(victims))
+            try:
+                while i < len(victims):
+                    self._writeback(victims[i])
+                    i += 1
+                    self.deferred_evictions += 1
+            except BaseException:
+                # a failed writeback must leave the unwritten victims
+                # reachable: failure recovery salvages from _evict_q, and a
+                # dirty copy outside it would be silently dropped
+                with self._lru_lock:
+                    for c in victims[i:]:
+                        self._evict_bytes += _copy_nbytes(c)
+                        self._evict_q.append(c)
+                raise
 
     def _writeback(self, copy: DataCopy) -> None:
         """Push a dirty device copy back to the host copy, then drop it."""
@@ -313,12 +338,14 @@ class TPUDevice(Device):
         happens OUTSIDE the LRU lock: spill hooks may copy page bytes and
         push AMs (kv_tiers peer spill), and concurrent stage-ins must not
         serialize behind that I/O."""
-        self._drain_evictions()   # pending w2r victims are not in the LRU
-        with self._lru_lock:
-            victims = [self._mem_lru.pop(k) for k in list(self._mem_lru)]
-            self._mem_bytes = 0
-        for c in victims:
-            self._writeback(c)
+        spans.phase_refresh()
+        with _Wall(self, "t_writeback", "devmod.writeback"):
+            self._drain_evictions()   # pending w2r victims: not in the LRU
+            with self._lru_lock:
+                victims = [self._mem_lru.pop(k) for k in list(self._mem_lru)]
+                self._mem_bytes = 0
+            for c in victims:
+                self._writeback(c)
 
     # ----------------------------------------------------------- stage-in
     def stage_in(self, task: Any) -> None:
@@ -438,42 +465,40 @@ class TPUDevice(Device):
             todo.append((d, host, host.version, host.value))
         if not todo:
             return 0
-        import time as _time
-        t0 = _time.perf_counter()
-        values = jax.device_put([v for _, _, _, v in todo],
-                                self.jax_device)
-        nb_total = 0
-        staged = 0
-        for (d, host, snap_ver, _sv), value in zip(todo, values):
-            with d._lock:
-                dev = d.device_copies.get(self.device_index)
-                if dev is not None and (
-                        dev.coherency in (COHERENCY_OWNED,
-                                          COHERENCY_EXCLUSIVE)
-                        or (dev.version >= snap_ver
-                            and dev.coherency != COHERENCY_INVALID)):
-                    # a dispatch staged or wrote it meanwhile: a dirty
-                    # device copy runs AHEAD of host and must never be
-                    # clobbered with the (older) snapshot bytes
-                    continue
-                if dev is None:
-                    dev = DataCopy(d, self.device_index, value=value,
-                                   dtt=host.dtt)
-                    d.device_copies[self.device_index] = dev
-                else:
-                    dev.value = value
-                # a host write-back that landed AFTER the snapshot makes
-                # this copy stale at birth: tagging it with snap_ver (not
-                # the live host version) makes the next stage_in see the
-                # miss and re-stage current bytes
-                dev.version = snap_ver
-                dev.coherency = COHERENCY_SHARED
-            nb = getattr(_sv, "nbytes", 0)
-            self.bytes_in += nb
-            nb_total += nb
-            staged += 1
-            self._cache_insert(dev, nb)
-        self.t_stage_in += _time.perf_counter() - t0
+        with _Wall(self, "t_stage_in", "devmod.prefetch"):
+            values = jax.device_put([v for _, _, _, v in todo],
+                                    self.jax_device)
+            nb_total = 0
+            staged = 0
+            for (d, host, snap_ver, _sv), value in zip(todo, values):
+                with d._lock:
+                    dev = d.device_copies.get(self.device_index)
+                    if dev is not None and (
+                            dev.coherency in (COHERENCY_OWNED,
+                                              COHERENCY_EXCLUSIVE)
+                            or (dev.version >= snap_ver
+                                and dev.coherency != COHERENCY_INVALID)):
+                        # a dispatch staged or wrote it meanwhile: a dirty
+                        # device copy runs AHEAD of host and must never be
+                        # clobbered with the (older) snapshot bytes
+                        continue
+                    if dev is None:
+                        dev = DataCopy(d, self.device_index, value=value,
+                                       dtt=host.dtt)
+                        d.device_copies[self.device_index] = dev
+                    else:
+                        dev.value = value
+                    # a host write-back that landed AFTER the snapshot makes
+                    # this copy stale at birth: tagging it with snap_ver (not
+                    # the live host version) makes the next stage_in see the
+                    # miss and re-stage current bytes
+                    dev.version = snap_ver
+                    dev.coherency = COHERENCY_SHARED
+                nb = getattr(_sv, "nbytes", 0)
+                self.bytes_in += nb
+                nb_total += nb
+                staged += 1
+                self._cache_insert(dev, nb)
         if nb_total:
             pins.fire(PinsEvent.DEVICE_STAGE_IN, None, int(nb_total))
         return staged
@@ -482,7 +507,6 @@ class TPUDevice(Device):
     def kernel_scheduler(self, es: Any, task: Any, submit: Callable) -> int:
         """``parsec_device_kernel_scheduler``: enqueue; first thread in
         becomes the manager and drains the device (device_gpu.c:2457-2473)."""
-        import time as _time
         dtask = TPUDeviceTask(es, task, submit)
         pins.fire(PinsEvent.DEVICE_ENQUEUE, es, task)
         with self._mutex_lock:
@@ -491,18 +515,25 @@ class TPUDevice(Device):
                 return HOOK_RETURN_ASYNC  # a manager is already in charge
             self._managing = True
         # we are the manager
-        _mgr0 = _time.perf_counter()
+        with spans.phase("devmod.manage"):
+            return self._manage()
+
+    def _manage(self) -> int:
+        """One managership: drain ``_pending`` batch by batch."""
+        _mgr0 = time.perf_counter()
         try:
             while True:
                 with self._mutex_lock:
                     if not self._pending:
                         self._managing = False
-                        self.t_manager += _time.perf_counter() - _mgr0
+                        self.t_manager += time.perf_counter() - _mgr0
                         return HOOK_RETURN_ASYNC
                     batch = self._take_batch_locked()
+                spans.phase_refresh()
                 try:
                     if _params.get("device_tpu_batch"):
-                        self._flood_from_scheduler(batch)
+                        with spans.phase("sched.flood"):
+                            self._flood_from_scheduler(batch)
                     self._prefetch_upcoming()
                     self._run_batch(batch)
                     self._drain_evictions()   # w2r: D2H post-dispatch
@@ -516,7 +547,7 @@ class TPUDevice(Device):
             # managership so the error path never strands queued tasks
             with self._mutex_lock:
                 self._managing = False
-                self.t_manager += _time.perf_counter() - _mgr0
+                self.t_manager += time.perf_counter() - _mgr0
             raise
 
     def _recover_failed_batch(self, batch: list[TPUDeviceTask],
@@ -606,12 +637,10 @@ class TPUDevice(Device):
         with self._mutex_lock:
             upcoming = [d for d in list(self._pending)[:depth]
                         if d.stage_in is None]
-        import time as _time
-        t0 = _time.perf_counter()
-        self.stage_in_many([d.task for d in upcoming])
         # prefetch transfers count toward the stage-in wall: the bench's
         # achieved-H2D-rate attribution divides bytes_in by this timer
-        self.t_stage_in += _time.perf_counter() - t0
+        with _Wall(self, "t_stage_in", "devmod.prefetch"):
+            self.stage_in_many([d.task for d in upcoming])
 
     def _flood_from_scheduler(self, batch: list[TPUDeviceTask]) -> None:
         """Pull additional ready same-class tasks straight from the
@@ -666,37 +695,37 @@ class TPUDevice(Device):
 
     # ------------------------------------------------------------ pipeline
     def _run_batch(self, batch: list[TPUDeviceTask]) -> None:
-        import time as _time
-        from ..runtime.scheduling import complete_execution
+        from ..runtime import scheduling
         pins.fire(PinsEvent.DEVICE_BATCH_BEGIN, None, len(batch))
-        t0 = _time.perf_counter()
-        # stage-in phase (stream 0 analog): user-hooked tasks stage
-        # individually, everything else moves in one batched device_put
-        hooked = [d for d in batch if d.stage_in is not None]
-        for dtask in hooked:
-            dtask.stage_in(self, dtask.task)
-        self.stage_in_many([d.task for d in batch
-                            if d.stage_in is None])
-        t1 = _time.perf_counter()
-        self.t_stage_in += t1 - t0
-        if len(batch) > 1 and self._run_vmapped(batch):
-            pass              # one XLA call serviced the whole batch
-        else:
-            for dtask in batch:   # exec phase (exec streams analog)
-                out = dtask.submit(dtask.es, dtask.task, self)
-                self.xla_calls += 1
-                note_xla_calls(1)
-                self._note_inflight(out)
-                self.executed_tasks += 1
-                self._mark_written(dtask.task)
-        t2 = _time.perf_counter()
-        self.t_dispatch += t2 - t1
-        for dtask in batch:   # completion (epilog analog)
-            if dtask.stage_out is not None:
-                dtask.stage_out(self, dtask.task)
-            self.release_task(dtask.task)
-            complete_execution(dtask.es, dtask.task)
-        self.t_complete += _time.perf_counter() - t2
+        with _Wall(self, "t_stage_in", "devmod.stage_in"):
+            # stage-in phase (stream 0 analog): user-hooked tasks stage
+            # individually, everything else moves in one batched device_put
+            hooked = [d for d in batch if d.stage_in is not None]
+            for dtask in hooked:
+                dtask.stage_in(self, dtask.task)
+            self.stage_in_many([d.task for d in batch
+                                if d.stage_in is None])
+        with _Wall(self, "t_dispatch", "devmod.dispatch"):
+            if len(batch) > 1 and self._run_vmapped(batch):
+                pass              # one XLA call serviced the whole batch
+            else:
+                for dtask in batch:   # exec phase (exec streams analog)
+                    out = dtask.submit(dtask.es, dtask.task, self)
+                    self.xla_calls += 1
+                    note_xla_calls(1)
+                    self._note_inflight(out)
+                    self.executed_tasks += 1
+                    self._mark_written(dtask.task)
+        with _Wall(self, "t_complete", "devmod.complete"):
+            # per task, the plane adds to a counter (sched.release) and
+            # opens no span
+            complete = scheduling.complete_execution_timed \
+                if spans.phase_on else scheduling.complete_execution
+            for dtask in batch:   # completion (epilog analog)
+                if dtask.stage_out is not None:
+                    dtask.stage_out(self, dtask.task)
+                self.release_task(dtask.task)
+                complete(dtask.es, dtask.task)
         pins.fire(PinsEvent.DEVICE_BATCH_END, None, len(batch))
 
     def _mark_written(self, task: Any) -> None:
@@ -774,13 +803,19 @@ class TPUDevice(Device):
             vmapped = jax.vmap(tr.apply)
 
             def fused(*flat, _n=nflows, _b=Bp):
-                stacked = [jnp.stack(flat[i * _b:(i + 1) * _b])
-                           for i in range(_n)]
-                out = vmapped(*stacked)
+                with jax.named_scope("stack"):
+                    stacked = [jnp.stack(flat[i * _b:(i + 1) * _b])
+                               for i in range(_n)]
+                with jax.named_scope("body"):
+                    out = vmapped(*stacked)
                 outs = out if isinstance(out, (tuple, list)) else (out,)
                 # per-task slices returned directly: no unbind call
-                return tuple(tuple(col) for col in outs)
+                with jax.named_scope("unstack"):
+                    return tuple(tuple(col) for col in outs)
 
+            # the program's name on the trace's "XLA Modules" line:
+            # device time splits by task class (jit_fused_gemm, ...)
+            fused.__name__ = f"fused_{dyld}"
             fn = self._vmap_cache[key] = jax.jit(fused)
         flat = [v for vs in cols
                 for v in (vs + [vs[0]] * (Bp - B))]   # lane-0 padding
@@ -819,7 +854,8 @@ class TPUDevice(Device):
         and is re-raised — a failed kernel must not pass silently."""
         import jax
         try:
-            jax.block_until_ready(out)
+            with spans.phase("devmod.inflight_wait"):
+                jax.block_until_ready(out)
         except Exception:
             from ..core.output import warning
             self.enabled = False
@@ -828,6 +864,7 @@ class TPUDevice(Device):
             raise
 
     def sync(self) -> None:
+        spans.phase_refresh()
         while self._inflight:
             self._confirm(self._inflight.popleft())
 
@@ -847,7 +884,8 @@ class TPUDevice(Device):
                  "stage_in_s": round(self.t_stage_in, 3),
                  "dispatch_s": round(self.t_dispatch, 3),
                  "complete_s": round(self.t_complete, 3),
-                 "drain_s": round(self.t_drain, 3)}
+                 "drain_s": round(self.t_drain, 3),
+                 "writeback_s": round(self.t_writeback, 3)}
         if self._mutex_lock.acquire(timeout=0.2):
             try:
                 state["pending_tasks"] = len(self._pending)

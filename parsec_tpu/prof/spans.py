@@ -43,10 +43,16 @@ fragment's meta — docs/OBSERVABILITY.md has the byte layout), and comm
 spans carry ``flow``/``flow_side`` args (``act:<src>:<seq>``,
 ``get:<requester>:<get_id>``) that :mod:`~parsec_tpu.prof.tracemerge`
 stitches into Chrome flow arrows across rank boundaries.
+
+The **phase plane** further down (ISSUE 27) is the one part of this module
+that reaches the profiler's clock: coarse ``TraceAnnotation`` spans inside
+``Context`` and the device module, with exact self times per name
+(:func:`phase`, :func:`phase_add`, :func:`phase_totals`).
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
@@ -267,6 +273,118 @@ def ensure_installed() -> SpanRecorder | None:
     if recorder is None and _params.get("prof_spans"):
         install()
     return recorder
+
+
+# ---------------------------------------------------------------------------
+# The phase plane: who owns each host second of a solve
+# ---------------------------------------------------------------------------
+#
+# Coarse spans (one per batch or coarser) inside Context and the device
+# module, written as ``jax.profiler.TraceAnnotation``s so they land on the
+# profiler's clock beside the device planes, and accumulated as exact self
+# times in one process-wide table.  docs/OBSERVABILITY.md lists the names.
+#
+# On while a profiler session is active or ``prof_spans`` is set; the flag
+# is refreshed at a few coarse sites (Context init / add_taskpool / fini,
+# once per device batch, sync, flush_cache), never per task.  Off, a site
+# is ``phase()``'s load of the flag and a branch: no object is made, no
+# clock is read.
+
+phase_on = False
+
+_phase_table: dict[str, list[int]] = {}   # name -> [self, inclusive, count]
+_phase_lock = threading.Lock()
+_phase_tls = threading.local()
+_phase_off = contextlib.nullcontext()
+_jax_profiler: Any = None       # jax.profiler, bound when the plane comes on
+_session_active: Any = None     # TraceMe.is_enabled, bound at first refresh
+
+
+def phase_refresh() -> None:
+    """Re-read whether the plane is on (~1 us: one call into the profiler,
+    one parameter read)."""
+    global phase_on, _session_active, _jax_profiler
+    if _session_active is None:
+        from jaxlib._profiler import TraceMe
+        _session_active = TraceMe.is_enabled
+    on = _session_active() or bool(_params.get("prof_spans"))
+    if on and _jax_profiler is None:
+        import jax.profiler
+        _jax_profiler = jax.profiler
+    phase_on = on
+
+
+def _phase_account(name: str, self_ns: int, incl_ns: int) -> None:
+    with _phase_lock:
+        row = _phase_table.get(name)
+        if row is None:
+            row = _phase_table[name] = [0, 0, 0]
+        row[0] += self_ns
+        row[1] += incl_ns
+        row[2] += 1
+
+
+class _Phase:
+    """One open span: a TraceAnnotation on the calling thread's host line
+    and a frame on the thread's stack, so that what its children cover
+    comes off its self time."""
+
+    __slots__ = ("name", "note", "t0", "child")
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.note = _jax_profiler.TraceAnnotation(name)
+        self.child = 0
+
+    def __enter__(self) -> "_Phase":
+        try:
+            stack = _phase_tls.stack
+        except AttributeError:
+            stack = _phase_tls.stack = []
+        stack.append(self)
+        self.note.__enter__()
+        self.t0 = _now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close(_now() - self.t0, *exc)
+
+    def close(self, dt: int, *exc) -> None:
+        """End the span at a duration its caller measured from ``t0``: a
+        site that keeps a wall of its own reads one clock for both."""
+        self.note.__exit__(*exc)
+        stack = _phase_tls.stack
+        stack.pop()
+        if stack:
+            stack[-1].child += dt
+        _phase_account(self.name, dt - self.child, dt)
+
+
+def phase(name: str) -> Any:
+    """``with spans.phase("devmod.dispatch"): ...`` -- a span of the phase
+    plane while it is on, the shared no-op otherwise."""
+    return _Phase(name) if phase_on else _phase_off
+
+
+def phase_add(name: str, ns: int) -> None:
+    """Counter-only form for per-task work: ``ns`` of ``name`` inside the
+    open span, with no annotation.  The caller tests ``phase_on`` before it
+    reads a clock."""
+    stack = getattr(_phase_tls, "stack", None)
+    if stack:
+        stack[-1].child += ns
+    _phase_account(name, ns, ns)
+
+
+def phase_totals() -> dict[str, tuple[int, int, int]]:
+    """A copy of the table: name -> (self ns, inclusive ns, count)."""
+    with _phase_lock:
+        return {k: tuple(v) for k, v in _phase_table.items()}
+
+
+def phase_reset() -> None:
+    with _phase_lock:
+        _phase_table.clear()
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,7 @@ from ..core.params import params as _params
 from ..core.backoff import Backoff
 from ..core.mca import repository
 from ..device.device import cpu_device as _cpu_device
-from ..prof import pins
+from ..prof import pins, spans
 from ..prof.pins import PinsEvent
 from .deps import DependencyTracking
 from .scheduling import (ExecutionStream, VirtualProcess, schedule_tasks,
@@ -92,146 +92,148 @@ class Context:
         may run on — an in-process rank bound to its own chip passes one
         (``run_multirank(transport="device")``).  ``None``: every
         registered accelerator."""
-        from ..sched import ensure_registered as _sched_ensure
-        _sched_ensure()
-        from ..device import registry as device_registry
-        # the always-on flight recorder hooks pins.fire before any worker
-        # can emit an event (prof_flightrec_size=0 opts out)
-        from ..prof import flight_recorder as _flightrec
-        _flightrec.ensure_installed()
-        # request-scoped span recorder (prof_spans=1): installed before
-        # any worker runs, so a traced pool's first task is never missed
-        from ..prof import spans as _spans
-        _spans.ensure_installed()
-        # persisted tuning vector (parsec_tpu/tune, ``tune_db=1``): the
-        # ambient ``context`` consult applies a stored knob vector NOW —
-        # before the core-count read and the scheduler query below
-        # resolve the params it may set (env/cli pins always win)
-        try:
-            from ..tune import apply_ambient
-            self.tuned_knobs = apply_ambient("context")
-        except Exception:               # noqa: BLE001 — a corrupt tuning
-            self.tuned_knobs = None     # DB must never fail a start
-        if nb_cores is None:
-            nb_cores = _params.get("runtime_num_cores")
-        self.nb_cores = nb_cores
-        self.nb_ranks = nb_ranks
-        self.my_rank = my_rank
-        self.started = False
-        self._shutdown = False
-        self._lock = threading.RLock()
-        self._cond = threading.Condition(self._lock)
-        self._active_taskpools: list[Taskpool] = []
-        self.deps = DependencyTracking()
-        self.taskpool_list: list[Taskpool] = []
-        self.comm_engine: Any = None
-        # rank-agreed taskpool ids for the wire protocol: ranks enqueue
-        # taskpools in the same order, so the per-context sequence agrees
-        # (the parsec_taskpool_reserve_id / sync_ids analog, parsec.c:2038).
-        # The id is a monotonic counter, NOT len(taskpool_list): with live
-        # enqueue a long-lived context retires terminated pools from the
-        # list, and a length-derived id would recycle and collide.
-        self._tp_by_comm_id: dict[int, Taskpool] = {}
-        self._next_comm_id = 0
-        # serializes whole add_taskpool calls: concurrent client threads
-        # submitting into a RUNNING context (the serving shape) must see
-        # an atomic id-reserve + termdet-arm + startup-schedule sequence —
-        # RLock because compound pools re-enter from completion callbacks
-        self._submit_lock = threading.RLock()
-        self._failure_listeners: list[Callable[[BaseException], None]] = []
-        self._worker_error: BaseException | None = None
-        # whether the recorded failure has been raised to a caller —
-        # fini() re-raises a failure nobody has seen yet (a silently
-        # swallowed worker death would report clean success)
-        self._error_surfaced = False
+        spans.phase_refresh()
+        with spans.phase("ctx.init"):
+            from ..sched import ensure_registered as _sched_ensure
+            _sched_ensure()
+            from ..device import registry as device_registry
+            # the always-on flight recorder hooks pins.fire before any worker
+            # can emit an event (prof_flightrec_size=0 opts out)
+            from ..prof import flight_recorder as _flightrec
+            _flightrec.ensure_installed()
+            # request-scoped span recorder (prof_spans=1): installed before
+            # any worker runs, so a traced pool's first task is never missed
+            spans.ensure_installed()
+            # persisted tuning vector (parsec_tpu/tune, ``tune_db=1``): the
+            # ambient ``context`` consult applies a stored knob vector NOW —
+            # before the core-count read and the scheduler query below
+            # resolve the params it may set (env/cli pins always win)
+            try:
+                from ..tune import apply_ambient
+                self.tuned_knobs = apply_ambient("context")
+            except Exception:               # noqa: BLE001 — a corrupt tuning
+                self.tuned_knobs = None     # DB must never fail a start
+            if nb_cores is None:
+                nb_cores = _params.get("runtime_num_cores")
+            self.nb_cores = nb_cores
+            self.nb_ranks = nb_ranks
+            self.my_rank = my_rank
+            self.started = False
+            self._shutdown = False
+            self._lock = threading.RLock()
+            self._cond = threading.Condition(self._lock)
+            self._active_taskpools: list[Taskpool] = []
+            self.deps = DependencyTracking()
+            self.taskpool_list: list[Taskpool] = []
+            self.comm_engine: Any = None
+            # rank-agreed taskpool ids for the wire protocol: ranks enqueue
+            # taskpools in the same order, so the per-context sequence agrees
+            # (parsec_taskpool_reserve_id / sync_ids analog, parsec.c:2038).
+            # The id is a monotonic counter, NOT len(taskpool_list): with live
+            # enqueue a long-lived context retires terminated pools from the
+            # list, and a length-derived id would recycle and collide.
+            self._tp_by_comm_id: dict[int, Taskpool] = {}
+            self._next_comm_id = 0
+            # serializes whole add_taskpool calls: concurrent client threads
+            # submitting into a RUNNING context (the serving shape) must see
+            # an atomic id-reserve + termdet-arm + startup-schedule sequence:
+            # RLock because compound pools re-enter from completion callbacks
+            self._submit_lock = threading.RLock()
+            self._failure_listeners: list[Callable[[BaseException], None]] = []
+            self._worker_error: BaseException | None = None
+            # whether the recorded failure has been raised to a caller —
+            # fini() re-raises a failure nobody has seen yet (a silently
+            # swallowed worker death would report clean success)
+            self._error_surfaced = False
 
-        # devices: the device-module init of ``parsec_init``.  The
-        # registry is process-global and every accelerator JAX shows
-        # registers in it once; the compile cache is placed first, before
-        # anything this process jits
-        from ..device.compile_cache import ensure_compile_cache
-        from ..device.tpu import init_tpu_devices
-        ensure_compile_cache()
-        accel = init_tpu_devices()
-        self.devices = device_registry
-        self._device_mask = None if accelerators is None else frozenset(
-            d.device_index for d in accel if d.jax_device in accelerators)
+            # devices: the device-module init of ``parsec_init``.  The
+            # registry is process-global and every accelerator JAX shows
+            # registers in it once; the compile cache is placed first, before
+            # anything this process jits
+            from ..device.compile_cache import ensure_compile_cache
+            from ..device.tpu import init_tpu_devices
+            ensure_compile_cache()
+            accel = init_tpu_devices()
+            self.devices = device_registry
+            self._device_mask = None if accelerators is None else frozenset(
+                d.device_index for d in accel if d.jax_device in accelerators)
 
-        # virtual processes + streams, per the vpmap spec (vpmap.py)
-        from .vpmap import nb_vps, parse_vpmap
-        nworkers = max(nb_cores, 0)
-        nstreams = max(nworkers, 1)
-        assignment = parse_vpmap(_params.get("runtime_vpmap"), nstreams,
-                                 _params.get("runtime_nb_vp"))
-        self.virtual_processes: list[VirtualProcess] = []
-        streams: list[ExecutionStream] = []
-        for v in range(nb_vps(assignment)):
-            vp = VirtualProcess(v, self)
-            self.virtual_processes.append(vp)
-        for i in range(nstreams):
-            vp = self.virtual_processes[assignment[i]]
-            es = ExecutionStream(i if nworkers else -1, vp, self)
-            vp.execution_streams.append(es)
-            streams.append(es)
-        self.streams = streams
-        # es used by external (non-worker) threads to submit/progress
-        self._submit_es = streams[0] if nworkers == 0 else \
-            ExecutionStream(-1, self.virtual_processes[0], self)
+            # virtual processes + streams, per the vpmap spec (vpmap.py)
+            from .vpmap import nb_vps, parse_vpmap
+            nworkers = max(nb_cores, 0)
+            nstreams = max(nworkers, 1)
+            assignment = parse_vpmap(_params.get("runtime_vpmap"), nstreams,
+                                     _params.get("runtime_nb_vp"))
+            self.virtual_processes: list[VirtualProcess] = []
+            streams: list[ExecutionStream] = []
+            for v in range(nb_vps(assignment)):
+                vp = VirtualProcess(v, self)
+                self.virtual_processes.append(vp)
+            for i in range(nstreams):
+                vp = self.virtual_processes[assignment[i]]
+                es = ExecutionStream(i if nworkers else -1, vp, self)
+                vp.execution_streams.append(es)
+                streams.append(es)
+            self.streams = streams
+            # es used by external (non-worker) threads to submit/progress
+            self._submit_es = streams[0] if nworkers == 0 else \
+                ExecutionStream(-1, self.virtual_processes[0], self)
 
-        # scheduler via MCA (explicit arg > MCA param > priority query)
-        comp = repository.query("sched", context=self, requested=scheduler)
-        self.scheduler = comp.open(self)
-        self.scheduler.install(self)
-        for es in streams:
-            self.scheduler.flow_init(es)
-
-        # live properties (dictionary.c role): the context publishes its
-        # hot gauges; ``props_stream`` additionally tails them to a JSON
-        # file an external observer reads mid-run (aggregator_visu role).
-        # The namespace de-collides when several contexts of one rank are
-        # live at once, and the getters hold the context only weakly — a
-        # context that never reaches fini() must not be kept alive (or
-        # have its registrations clobbered/stolen) by the global registry.
-        import weakref
-        from ..prof.counters import properties, sde
-        base = f"rank{my_rank}"
-        ns = base
-        i = 1
-        while properties.has(ns, "sched_pending"):
-            ns = f"{base}#{i}"
-            i += 1
-        self._props_ns = ns
-        self._props_stop: Callable[[], None] | None = None
-        self._snap_started = False
-        self.last_stall_report: dict | None = None
-        ref = weakref.ref(self)
-
-        def gauge(fn: Callable[["Context"], Any]) -> Callable[[], Any]:
-            def get():
-                c = ref()
-                return fn(c) if c is not None else 0
-            return get
-
-        properties.register(ns, "sched_pending",
-                            gauge(lambda c: c.scheduler.pending_tasks(c)))
-        properties.register(ns, "active_taskpools",
-                            gauge(lambda c: len(c._active_taskpools)))
-        properties.register(ns, "nb_tasks",
-                            gauge(lambda c: sum(
-                                tp.tdm.nb_tasks
-                                for tp in c._active_taskpools
-                                if tp.tdm is not None)))
-        properties.register(ns, "sde", sde.snapshot)
-
-        # worker threads
-        self._threads: list[threading.Thread] = []
-        self._start_barrier = threading.Event()
-        if nworkers > 0:
+            # scheduler via MCA (explicit arg > MCA param > priority query)
+            comp = repository.query("sched", context=self, requested=scheduler)
+            self.scheduler = comp.open(self)
+            self.scheduler.install(self)
             for es in streams:
-                t = threading.Thread(target=self._worker_main, args=(es,),
-                                     name=f"parsec-es{es.th_id}", daemon=True)
-                self._threads.append(t)
-                t.start()
+                self.scheduler.flow_init(es)
+
+            # live properties (dictionary.c role): the context publishes its
+            # hot gauges; ``props_stream`` additionally tails them to a JSON
+            # file an external observer reads mid-run (aggregator_visu role).
+            # The namespace de-collides when several contexts of one rank are
+            # live at once, and the getters hold the context only weakly — a
+            # context that never reaches fini() must not be kept alive (or
+            # have its registrations clobbered/stolen) by the global registry.
+            import weakref
+            from ..prof.counters import properties, sde
+            base = f"rank{my_rank}"
+            ns = base
+            i = 1
+            while properties.has(ns, "sched_pending"):
+                ns = f"{base}#{i}"
+                i += 1
+            self._props_ns = ns
+            self._props_stop: Callable[[], None] | None = None
+            self._snap_started = False
+            self.last_stall_report: dict | None = None
+            ref = weakref.ref(self)
+
+            def gauge(fn: Callable[["Context"], Any]) -> Callable[[], Any]:
+                def get():
+                    c = ref()
+                    return fn(c) if c is not None else 0
+                return get
+
+            properties.register(ns, "sched_pending",
+                                gauge(lambda c: c.scheduler.pending_tasks(c)))
+            properties.register(ns, "active_taskpools",
+                                gauge(lambda c: len(c._active_taskpools)))
+            properties.register(ns, "nb_tasks",
+                                gauge(lambda c: sum(
+                                    tp.tdm.nb_tasks
+                                    for tp in c._active_taskpools
+                                    if tp.tdm is not None)))
+            properties.register(ns, "sde", sde.snapshot)
+
+            # worker threads
+            self._threads: list[threading.Thread] = []
+            self._start_barrier = threading.Event()
+            if nworkers > 0:
+                for es in streams:
+                    t = threading.Thread(
+                        target=self._worker_main, args=(es,),
+                        name=f"parsec-es{es.th_id}", daemon=True)
+                    self._threads.append(t)
+                    t.start()
 
     # ------------------------------------------------------------------ API
     def accelerators(self) -> list:
@@ -261,8 +263,10 @@ class Context:
         termination detector and NO comm id, so it never participates in
         the wire protocol and ranks may enqueue different numbers of them
         without desynchronizing the rank-agreed taskpool id sequence."""
-        with self._submit_lock:
-            self._add_taskpool_locked(tp, local_only)
+        spans.phase_refresh()
+        with spans.phase("ctx.add_taskpool"):
+            with self._submit_lock:
+                self._add_taskpool_locked(tp, local_only)
 
     def _add_taskpool_locked(self, tp: Taskpool,
                              local_only: bool) -> None:  # lint: holds(_submit_lock)
@@ -452,6 +456,7 @@ class Context:
         added for the timed-out case).  On expiry the stall
         dump fires (via :meth:`wait`) and teardown falls through
         abort-style."""
+        spans.phase_refresh()
         if self._worker_error is None and not self.test():
             try:
                 if not self.started:
@@ -464,16 +469,17 @@ class Context:
                 if self.last_stall_report is None:
                     self._stall_dump(
                         f"fini drain timed out (timeout={timeout}s)")
-        with self._lock:
-            self._shutdown = True
-            self._cond.notify_all()
-        self._start_barrier.set()
-        for t in self._threads:
-            t.join(timeout=5)
-        self.scheduler.remove(self)
-        if self.comm_engine is not None:
-            self.comm_engine.fini()
-        self._props_teardown()
+        with spans.phase("ctx.fini"):
+            with self._lock:
+                self._shutdown = True
+                self._cond.notify_all()
+            self._start_barrier.set()
+            for t in self._threads:
+                t.join(timeout=5)
+            self.scheduler.remove(self)
+            if self.comm_engine is not None:
+                self.comm_engine.fini()
+            self._props_teardown()
         if self._worker_error is not None and not self._error_surfaced:
             self._error_surfaced = True
             raise RuntimeError(
@@ -601,6 +607,13 @@ class Context:
                     if not ok:
                         raise ContextWaitTimeout(
                             "context wait timed out; " + self._live_desc())
+        with spans.phase("ctx.progress"):
+            self._drive_inline(predicate, deadline)
+
+    def _drive_inline(self, predicate: Callable[[], bool],
+                      deadline: float | None) -> None:
+        """The hot loop on the calling thread (master-thread funneled
+        mode), until ``predicate`` holds."""
         self._run_compiled_dags(deadline=deadline)
         es = self._submit_es
         es.owner_ident = threading.get_ident()

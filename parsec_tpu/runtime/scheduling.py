@@ -14,12 +14,13 @@ path (§3.5).
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any
 
 from ..core.params import params as _params
 from ..data.reshape import reshape_for_edge, reshape_for_writeback
 from ..device.device import cpu_device as _cpu_device
-from ..prof import pins
+from ..prof import pins, spans
 from ..prof.pins import PinsEvent
 from .task import (DEV_CPU, HOOK_RETURN_AGAIN, HOOK_RETURN_ASYNC,
                    HOOK_RETURN_DISABLE, HOOK_RETURN_DONE, HOOK_RETURN_ERROR,
@@ -332,6 +333,19 @@ def complete_execution(es: ExecutionStream, task: Task) -> None:
     if h is not None:
         h(es, task)
     tp.tdm.taskpool_addto_nb_tasks(-1)
+
+
+def complete_execution_timed(es: ExecutionStream, task: Task) -> None:
+    """:func:`complete_execution` under the phase plane's ``sched.release``
+    counter: what a task costs in dependency release, ``schedule_tasks`` and
+    repo consumption.  A device manager's completion loop calls this in
+    place of the plain function while ``spans.phase_on``; per task it reads
+    the clock twice and opens no span."""
+    t0 = time.perf_counter_ns()
+    try:
+        complete_execution(es, task)
+    finally:
+        spans.phase_add("sched.release", time.perf_counter_ns() - t0)
 
 
 def release_deps(es: ExecutionStream, task: Task) -> None:

@@ -280,35 +280,74 @@ def test_llm_prefix_cache_ttft_speedup():
     assert r["llm_prefix_sweep"]["0.0"]["prefix_hits"] == 0, r
 
 
-# ISSUE-10 tracing budget (docs/OBSERVABILITY.md overhead table):
-# disabled = the existing PINS one-branch cost, so the dynamic dispatch
-# number must stay within 10% of the PR-2 overhead baseline gate;
-# enabled = ≤1µs/task budget, gated at 10x headroom plus the noise
-# floor of differencing two ~40µs dynamic-dispatch medians (measured
-# ±4µs idle, up to ~2x that on a loaded CI box)
-TRACING_DISABLED_RATIO_MAX = 1.10
-TRACING_ENABLED_DELTA_US_MAX = 20.0
-SPAN_RECORD_NS_MAX = 5000.0
-HIST_RECORD_NS_MAX = 10000.0
+# ISSUE-10 tracing budget (docs/OBSERVABILITY.md overhead table), held
+# since ISSUE 27 by what repeats exactly: which PINS slots the span
+# recorder occupies, how many spans a traced pool leaves, and that the
+# phase plane builds nothing while off.  The clock readings of
+# ``microbench.bench_tracing`` (dispatch us a task off and on, ns a span
+# or histogram record) swing with the six parallel workers of tier-1
+# (ROADMAP D10); they are printed for the log, not asserted.
 
 
-def test_tracing_overhead_within_budget():
-    """The ISSUE-10 observability gates: with the span recorder
-    UNINSTALLED (the shipped default) the dynamic dispatch path costs
-    what it cost at the PR-2 baseline (within the 10% ratio the issue
-    pins — tracing added NO new hot-path site, only the existing PINS
-    branch); INSTALLED with every pool traced, the per-task delta stays
-    inside the ≤1µs budget line held at headroom.  Span and histogram
-    record costs are gated directly so a regression names the layer."""
-    r = microbench.bench_tracing(smoke=True)
-    assert r["tracing_dispatch_off_us"] <= \
-        DYNAMIC_DISPATCH_US_MAX * TRACING_DISABLED_RATIO_MAX, r
-    assert r["tracing_dispatch_delta_us"] <= \
-        TRACING_ENABLED_DELTA_US_MAX, r
-    assert r["span_record_ns"] <= SPAN_RECORD_NS_MAX, r
-    assert r["hist_record_ns"] <= HIST_RECORD_NS_MAX, r
-    # the enabled run really recorded: traced pools span every task
-    assert r["tracing_spans_recorded"] > 0, r
+def test_tracing_overhead_within_budget(monkeypatch):
+    """The observability gates.  With the span recorder UNINSTALLED (the
+    shipped default) its six task-span PINS slots hold no chain of its
+    own: tracing added no hot-path site, only the existing PINS branch.
+    INSTALLED, a traced pool of n tasks records exactly n ``exec`` and n
+    ``release`` spans.  The phase plane, off, builds no object."""
+    import parsec_tpu.runtime.dagrun  # noqa: F401 — runtime_dag_compile
+    from collections import Counter
+
+    from parsec_tpu.core.params import params
+    from parsec_tpu.prof import pins, spans
+    from parsec_tpu.prof.pins import PinsEvent
+    from parsec_tpu.runtime import Context
+
+    task_span_events = (
+        PinsEvent.EXEC_BEGIN, PinsEvent.EXEC_END,
+        PinsEvent.RELEASE_DEPS_BEGIN, PinsEvent.RELEASE_DEPS_END,
+        PinsEvent.SCHEDULE_BEGIN, PinsEvent.SCHEDULE_END)
+
+    def recorder_chains():
+        return [cb for ev in task_span_events
+                for cb in pins._chains.get(int(ev), ())
+                if isinstance(getattr(cb, "__self__", None),
+                              spans._TaskSpans)]
+
+    def built(*a):
+        raise AssertionError("the phase plane built a span while off")
+
+    prev = spans.recorder
+    if prev is not None:
+        spans.uninstall()
+    assert spans._task_spans is None and not recorder_chains()
+    spans.phase_refresh()
+    assert not spans.phase_on
+    assert spans.phase("ctx.init") is spans.phase("ctx.fini")
+    table = spans.phase_totals()
+    monkeypatch.setattr(spans, "_Phase", built)
+    nt, depth = 20, 25
+    saved = params.get("runtime_dag_compile")
+    params.set("runtime_dag_compile", False)    # the dynamic path
+    rec = spans.install()
+    try:
+        assert len(recorder_chains()) == len(task_span_events)
+        tp = microbench._ep_pool(nt, depth).build()
+        tp._trace = spans.new_trace()
+        ctx = Context(nb_cores=0)
+        ctx.add_taskpool(tp)
+        ctx.wait(timeout=600)
+        ctx.fini()
+        names = Counter(s[0] for s in rec.by_trace(tp._trace.trace_id))
+    finally:
+        params.set("runtime_dag_compile", saved)
+        spans.uninstall()
+        if prev is not None:
+            spans.install(recorder_obj=prev)
+    assert names["exec"] == names["release"] == nt * depth, names
+    assert spans.phase_totals() == table
+    print("bench_tracing (clock readings, not asserted):",
+          microbench.bench_tracing(smoke=True))
 
 
 def test_lowering_cache_warm_compile_is_near_zero():
