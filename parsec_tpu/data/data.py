@@ -40,7 +40,7 @@ class DataCopy:
 
     __slots__ = ("original", "device_index", "coherency", "readers", "version",
                  "value", "dtt", "flags", "arena_chunk", "reshaped",
-                 "wb_mark")
+                 "wb_mark", "pushed")
 
     def __init__(self, original: "Data", device_index: int,
                  value: Any = None, dtt: TileType | None = None) -> None:
@@ -54,6 +54,9 @@ class DataCopy:
         self.flags = 0
         self.arena_chunk = None  # owning arena, for recycling
         self.reshaped = None     # dtt-key -> shared repack future (reshape.py)
+        # weakref to the device array whose D2H a push-out started
+        # (device/tpu.py:pushout); a later writer's new array is not it
+        self.pushed = None
 
     def __repr__(self) -> str:
         return (f"<DataCopy key={self.original.key} dev={self.device_index} "

@@ -109,6 +109,12 @@ class Device:
     def flush_cache(self) -> None:
         pass
 
+    def pushout(self, copy: Any) -> None:
+        """``release_deps`` walked an active output dep to a collection on
+        a flow whose copy lives on this device: the tile is final.  An
+        accelerator starts its transfer to the host here; the host's own
+        device has nothing to move."""
+
     def stats_reset(self) -> dict[str, float]:
         s = self.stats()
         self.executed_tasks = 0

@@ -62,6 +62,12 @@ def _copy_nbytes(copy: DataCopy) -> int:
     return getattr(copy.value, "nbytes", 0) if copy.value is not None else 0
 
 
+def _pushed_out(copy: DataCopy) -> bool:
+    """A push-out started the transfer of the very array ``copy`` holds now
+    (a later writer's new ``copy.value`` is another object)."""
+    return copy.pushed is not None and copy.pushed() is copy.value
+
+
 # --------------------------------------------------------------------------
 # tier spill hooks (ISSUE 11): the KV tier map (data_dist/kv_tiers.py)
 # subscribes to device evictions so HBM -> host write-backs of its pages
@@ -189,6 +195,13 @@ class TPUDevice(Device):
         self.t_complete = 0.0
         self.t_drain = 0.0
         self.t_writeback = 0.0   # flush_cache, its drain included
+        # how often the write-back's transfer was under way before it was
+        # read: transfers started at a memory edge, dirty tiles _writeback
+        # brought back, and of those the ones a push-out had started on
+        # the very array read
+        self.pushouts = 0
+        self.writebacks = 0
+        self.writebacks_early = 0
         self.t_manager = 0.0   # total wall inside the manager drain loop
         # stage-in tile-cache effectiveness, per (task, flow) reference —
         # the hit-rate gauge the metrics snapshotter samples
@@ -284,12 +297,7 @@ class TPUDevice(Device):
                     if self._mem_lru.get(c.original) is c:
                         continue    # resurrected by a later stage_in
                 if c.coherency != COHERENCY_INVALID:
-                    start = getattr(c.value, "copy_to_host_async", None)
-                    if start is not None:
-                        try:
-                            start()
-                        except Exception:
-                            pass    # the sync read below transfers it
+                    self._start_d2h(c)
                     victims.append(c)
             i = 0
             if victims:
@@ -309,6 +317,40 @@ class TPUDevice(Device):
                         self._evict_q.append(c)
                 raise
 
+    def _start_d2h(self, copy: DataCopy) -> bool:
+        """Start the device-to-host transfer of a dirty copy's value and
+        return at once; False where a push-out already started it on this
+        very array, or nothing could be started (a clean copy, a value
+        without ``copy_to_host_async``, a start that raised: the
+        synchronous read in :meth:`_writeback` transfers those).  The
+        transfer is cached on the array object, so it can only ever serve
+        a read of that array: a later writer's new ``copy.value`` has none."""
+        if copy.coherency not in (COHERENCY_OWNED, COHERENCY_EXCLUSIVE) \
+                or _pushed_out(copy):
+            return False
+        start = getattr(copy.value, "copy_to_host_async", None)
+        if start is None:
+            return False
+        try:
+            start()
+        except Exception:
+            return False
+        return True
+
+    def pushout(self, copy: DataCopy) -> None:
+        """The graph says this written tile is final (an active output dep
+        to a collection, walked by ``release_deps``): start its transfer
+        now, so that it rides under the rest of the solve.  Nothing else
+        changes: the copy stays dirty, in the LRU and readable by the
+        task's successors; the host copy, the coherency transition and
+        ``bytes_out`` wait for :meth:`_writeback` at the flush or a drain."""
+        t0 = time.perf_counter_ns() if spans.phase_on else 0
+        if self._start_d2h(copy):
+            copy.pushed = _weakref.ref(copy.value)
+            self.pushouts += 1
+        if t0:
+            spans.phase_add("devmod.pushout", time.perf_counter_ns() - t0)
+
     def _writeback(self, copy: DataCopy) -> None:
         """Push a dirty device copy back to the host copy, then drop it."""
         import numpy as np
@@ -316,6 +358,8 @@ class TPUDevice(Device):
         if copy.coherency in (COHERENCY_OWNED, COHERENCY_EXCLUSIVE):
             host = d.get_copy(0)
             value = np.asarray(copy.value)
+            self.writebacks += 1
+            self.writebacks_early += _pushed_out(copy)
             if host is None:
                 host = DataCopy(d, 0, value=value, dtt=copy.dtt)
                 d.attach_copy(host)
@@ -328,6 +372,7 @@ class TPUDevice(Device):
             self.bytes_out += value.nbytes
         d.detach_copy(self.device_index)
         copy.coherency = COHERENCY_INVALID
+        copy.pushed = None
         if _spill_hooks:
             # the datum is host-resident-only now: tier maps account it
             _fire_spill(d, _copy_nbytes(copy))
@@ -337,7 +382,10 @@ class TPUDevice(Device):
         taskpool; the data_flush analog for device residency).  Write-back
         happens OUTSIDE the LRU lock: spill hooks may copy page bytes and
         push AMs (kv_tiers peer spill), and concurrent stage-ins must not
-        serialize behind that I/O."""
+        serialize behind that I/O.  Two passes, as the drain does it:
+        every dirty tile whose transfer no push-out started gets it started,
+        then the host copies materialize; on return every one of them is a
+        numpy array at the tile's newest version."""
         spans.phase_refresh()
         with _Wall(self, "t_writeback", "devmod.writeback"):
             self._drain_evictions()   # pending w2r victims: not in the LRU
@@ -345,7 +393,12 @@ class TPUDevice(Device):
                 victims = [self._mem_lru.pop(k) for k in list(self._mem_lru)]
                 self._mem_bytes = 0
             for c in victims:
+                self._start_d2h(c)
+            for c in victims:
                 self._writeback(c)
+            # the cache's last references go here, inside the wall: freeing
+            # the device buffers is part of what the flush costs
+            c = victims = None
 
     # ----------------------------------------------------------- stage-in
     def stage_in(self, task: Any) -> None:
@@ -865,8 +918,11 @@ class TPUDevice(Device):
 
     def sync(self) -> None:
         spans.phase_refresh()
-        while self._inflight:
-            self._confirm(self._inflight.popleft())
+        # self time: dropping the confirmed dispatches, which hold the last
+        # references to the intermediate versions of every RW tile
+        with spans.phase("devmod.sync"):
+            while self._inflight:
+                self._confirm(self._inflight.popleft())
 
     # -------------------------------------------------------- diagnostics
     def debug_state(self) -> dict:
@@ -881,6 +937,8 @@ class TPUDevice(Device):
                  "cache_hits": self.cache_hits,
                  "cache_misses": self.cache_misses,
                  "bytes_in": self.bytes_in, "bytes_out": self.bytes_out,
+                 "pushouts": self.pushouts, "writebacks": self.writebacks,
+                 "writebacks_early": self.writebacks_early,
                  "stage_in_s": round(self.t_stage_in, 3),
                  "dispatch_s": round(self.t_dispatch, 3),
                  "complete_s": round(self.t_complete, 3),

@@ -366,14 +366,25 @@ def phase(name: str) -> Any:
     return _Phase(name) if phase_on else _phase_off
 
 
-def phase_add(name: str, ns: int) -> None:
+def phase_add(name: str, ns: int, covered: int = 0) -> None:
     """Counter-only form for per-task work: ``ns`` of ``name`` inside the
     open span, with no annotation.  The caller tests ``phase_on`` before it
-    reads a clock."""
+    reads a clock.  ``covered``: the part of ``ns`` that spans and counters
+    inside this one already own, as the difference of two
+    :func:`phase_covered` readings; it comes off this row's self time."""
     stack = getattr(_phase_tls, "stack", None)
     if stack:
-        stack[-1].child += ns
-    _phase_account(name, ns, ns)
+        stack[-1].child += ns - covered
+    _phase_account(name, ns - covered, ns)
+
+
+def phase_covered() -> int:
+    """Nanoseconds of the calling thread's open span that its children own
+    so far (0 outside any span).  A counter that encloses other counters
+    reads it before and after, and hands the difference to
+    :func:`phase_add`."""
+    stack = getattr(_phase_tls, "stack", None)
+    return stack[-1].child if stack else 0
 
 
 def phase_totals() -> dict[str, tuple[int, int, int]]:

@@ -342,10 +342,13 @@ def complete_execution_timed(es: ExecutionStream, task: Task) -> None:
     place of the plain function while ``spans.phase_on``; per task it reads
     the clock twice and opens no span."""
     t0 = time.perf_counter_ns()
+    inner = spans.phase_covered()
     try:
         complete_execution(es, task)
     finally:
-        spans.phase_add("sched.release", time.perf_counter_ns() - t0)
+        # what counters inside the release own (devmod.pushout) is theirs
+        spans.phase_add("sched.release", time.perf_counter_ns() - t0,
+                        spans.phase_covered() - inner)
 
 
 def release_deps(es: ExecutionStream, task: Task) -> None:
@@ -436,6 +439,12 @@ def release_deps(es: ExecutionStream, task: Task) -> None:
 def _writeback(task: Task, flow, dep, out_copy) -> None:
     if out_copy is None or dep.data_ref is None:
         return
+    if out_copy.device_index != 0:
+        # the memory edge of a tile that lives on an accelerator: this
+        # version is final, so its device starts the transfer home now
+        # (jdf2c's pushout on a flow that writes to a collection)
+        task.taskpool.context.devices.get(out_copy.device_index).pushout(
+            out_copy)
     dc, key = dep.data_ref(task.locals)
     out_copy = reshape_for_writeback(out_copy, dep, dc, key)
     apply_writeback_to_home(dc, key, out_copy,

@@ -296,3 +296,140 @@ def test_failed_dispatch_demotes_to_cpu(accel_device):
     assert accel_device.enabled is False
     for i in range(3):
         assert float(coll.data_of(i).newest_copy().value[0]) == 7.0
+
+
+# --------------------------------------------------------------------------
+# push-out at the memory edge (ISSUE 28): the D2H of a written tile starts
+# when release_deps walks its active output dep to a collection
+# --------------------------------------------------------------------------
+
+def _gemm_case(devices):
+    rng = np.random.default_rng(31)
+    a, b, c, A, B, C = _mk_abc(64, 64, 64, 16, rng)
+    tiles = [C.data_of(m, n) for m in range(4) for n in range(4)]
+    return (tiled_gemm_ptg(A, B, C, devices=devices), tiles,
+            lambda: (C.to_dense(), c + a @ b))
+
+
+def _cholesky_case(devices):
+    from parsec_tpu.models.cholesky import make_spd, tiled_cholesky_ptg
+    a = make_spd(64)
+    A = SymTwoDimBlockCyclic.from_dense("A", a, 16, 16)
+    tiles = [A.data_of(m, k) for m in range(4) for k in range(m + 1)]
+    return (tiled_cholesky_ptg(A, devices=devices), tiles,
+            lambda: (np.tril(A.to_dense()),
+                     np.linalg.cholesky(a.astype(np.float64))))
+
+
+def _on_host_at_newest(datum):
+    """The benchmark's ``host_tile`` rule: a valid numpy host copy that no
+    other copy is ahead of."""
+    from parsec_tpu.data.data import COHERENCY_INVALID
+    host = datum.get_copy(0)
+    return (host is not None and host.coherency != COHERENCY_INVALID
+            and isinstance(host.value, np.ndarray)
+            and datum.newest_copy().version <= host.version)
+
+
+def _chain_on_one_tile(nsteps, edge_open):
+    """T(0) -> ... -> T(nsteps-1) add 1 to the tile F(0) on the device;
+    T(i) has a memory edge wherever ``edge_open(i)``."""
+    from parsec_tpu import ptg
+    from parsec_tpu.data.data import TileType
+    from parsec_tpu.data_dist.collection import DictCollection
+    from parsec_tpu.device.kernels import register_kernel
+
+    def inc(es, task, device):
+        v = task.data[0]
+        v.value = v.value + 1
+        v.version += 1
+        return v.value
+
+    register_kernel("pushout_inc", "tpu", inc)
+    coll = DictCollection("F", dtt=TileType((4,), np.float32),
+                          init_fn=lambda *k: np.zeros(4, np.float32))
+    p = ptg.PTGBuilder("rewrite", F=coll, N=nsteps)
+    t = p.task("T", i=ptg.span(0, lambda g, l: g.N - 1))
+    f = t.flow("V", ptg.RW)
+    f.input(data=("F", lambda g, l: (0,)), guard=lambda g, l: l.i == 0)
+    f.input(pred=("T", "V", lambda g, l: {"i": l.i - 1}),
+            guard=lambda g, l: l.i > 0)
+    f.output(succ=("T", "V", lambda g, l: {"i": l.i + 1}),
+             guard=lambda g, l: l.i < g.N - 1)
+    f.output(data=("F", lambda g, l: (0,)),
+             guard=lambda g, l: edge_open(l.i))
+    t.body(device="tpu", dyld="pushout_inc")
+    return p.build(), coll.data_of(0)
+
+
+class TestPushout:
+    @pytest.mark.parametrize("case", [_gemm_case, _cholesky_case])
+    def test_result_tiles_leave_at_their_memory_edge(self, accel_device,
+                                                     case):
+        dev = accel_device
+        pool, tiles, dense = case("tpu")
+        ctx = Context(nb_cores=0)
+        ctx.add_taskpool(pool)
+        ctx.wait(timeout=120)
+        # every result tile's transfer is under way before any flush, and
+        # nothing else has changed: the tiles are still dirty on the device
+        assert dev.pushouts == len(tiles)
+        assert dev.writebacks == 0 and dev.bytes_out == 0
+        assert not any(_on_host_at_newest(d) for d in tiles)
+        dev.sync()
+        dev.flush_cache()
+        ctx.fini()
+        assert dev.writebacks_early == dev.writebacks == len(tiles)
+        assert all(_on_host_at_newest(d) for d in tiles)
+        got, expect = dense()
+        np.testing.assert_allclose(got, expect, rtol=1e-3, atol=1e-4)
+        state = dev.debug_state()
+        assert (state["pushouts"], state["writebacks"],
+                state["writebacks_early"]) == (len(tiles),) * 3
+
+    def test_overtaken_pushout_is_not_read(self, accel_device):
+        """Every step pushes the tile out and the next writes it again:
+        the flush ends at the last value, through the one transfer that
+        was started on the array it reads."""
+        dev = accel_device
+        pool, datum = _chain_on_one_tile(5, lambda i: True)
+        ctx = Context(nb_cores=0)
+        ctx.add_taskpool(pool)
+        ctx.wait(timeout=60)
+        assert dev.pushouts == 5
+        dev.sync()
+        dev.flush_cache()
+        ctx.fini()
+        assert _on_host_at_newest(datum)
+        np.testing.assert_array_equal(datum.get_copy(0).value,
+                                      np.full(4, 5, np.float32))
+        assert dev.writebacks == dev.writebacks_early == 1
+
+    def test_closed_memory_edge_starts_nothing(self, accel_device):
+        """The guard of the memory edge is false on every task: no
+        push-out; the flush starts the transfer itself and the tile still
+        comes back."""
+        dev = accel_device
+        pool, datum = _chain_on_one_tile(3, lambda i: False)
+        ctx = Context(nb_cores=0)
+        ctx.add_taskpool(pool)
+        ctx.wait(timeout=60)
+        assert dev.pushouts == 0
+        dev.sync()
+        dev.flush_cache()
+        ctx.fini()
+        assert (dev.writebacks, dev.writebacks_early) == (1, 0)
+        np.testing.assert_array_equal(datum.get_copy(0).value,
+                                      np.full(4, 3, np.float32))
+
+    def test_cpu_only_context_starts_nothing(self, accel_device):
+        """Tasks that run their CPU incarnation have host copies at the
+        memory edge: the accelerator beside them sees no push-out."""
+        pool, tiles, dense = _gemm_case("cpu")
+        ctx = Context(nb_cores=0)
+        ctx.add_taskpool(pool)
+        ctx.wait(timeout=60)
+        ctx.fini()
+        assert accel_device.pushouts == 0 and accel_device.writebacks == 0
+        got, expect = dense()
+        np.testing.assert_allclose(got, expect, rtol=1e-3, atol=1e-4)

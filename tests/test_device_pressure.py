@@ -31,11 +31,13 @@ def dev(accel_device):
     return accel_device    # shared conftest fixture, local name
 
 
-def _mk_abc(n, mb, seed):
+def _mk_abc(n, mb, seed, k=None):
+    """C (n x n) += A (n x k) . B (k x n), k = n unless given."""
     from parsec_tpu.data_dist.matrix import TiledMatrix
+    k = n if k is None else k
     rng = np.random.default_rng(seed)
-    a = rng.standard_normal((n, n)).astype(np.float32)
-    b = rng.standard_normal((n, n)).astype(np.float32)
+    a = rng.standard_normal((n, k)).astype(np.float32)
+    b = rng.standard_normal((k, n)).astype(np.float32)
     c = rng.standard_normal((n, n)).astype(np.float32)
     return (a, b, c, TiledMatrix.from_dense("A", a, mb, mb),
             TiledMatrix.from_dense("B", b, mb, mb),
@@ -208,3 +210,136 @@ def test_device_failure_during_stage_in_demotes(dev, monkeypatch, param):
     assert dev.enabled is False
     np.testing.assert_allclose(C.to_dense(), c + a @ b, rtol=1e-3,
                                atol=1e-4)
+
+
+# --------------------------------------------------------------------------
+# the write-back in two passes, and pushed-out tiles under eviction and
+# salvage (ISSUE 28)
+# --------------------------------------------------------------------------
+
+class _FakeValue:
+    """A device value that records, in one shared log, when its transfer
+    was started and when it was read."""
+
+    nbytes = 16
+
+    def __init__(self, log, tag, start="ok"):
+        self.log, self.tag = log, tag
+        if start == "ok":
+            self.copy_to_host_async = lambda: log.append(("start", tag))
+        elif start == "raises":
+            self.copy_to_host_async = self._broken
+        # start == "absent": no such attribute at all
+
+    def _broken(self):
+        self.log.append(("start", self.tag))
+        raise RuntimeError("transfer engine down")
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("read", self.tag))
+        return np.full(4, self.tag, np.float32)
+
+
+def _dirty_resident(dev, log, tag, start="ok"):
+    """One datum whose only current version is a dirty fake value in
+    ``dev``'s LRU."""
+    from parsec_tpu.data.data import COHERENCY_OWNED, DataCopy, data_create
+    datum = data_create(np.zeros(4, np.float32), key=("fake", tag))
+    dc = DataCopy(datum, dev.device_index, value=_FakeValue(log, tag, start))
+    dc.coherency = COHERENCY_OWNED
+    dc.version = 2
+    datum.attach_copy(dc)
+    datum.owner_device = dev.device_index
+    dev._cache_insert(dc, _FakeValue.nbytes)
+    return datum
+
+
+def test_flush_starts_every_transfer_before_the_first_read(dev):
+    log = []
+    datums = [_dirty_resident(dev, log, tag) for tag in range(1, 6)]
+    # one of them was pushed out already: the flush does not start it again
+    pushed = datums[2].get_copy(dev.device_index)
+    dev.pushout(pushed)
+    assert log == [("start", 3)] and dev.pushouts == 1
+    dev.flush_cache()
+    starts = [i for i, (what, _) in enumerate(log) if what == "start"]
+    reads = [i for i, (what, _) in enumerate(log) if what == "read"]
+    assert len(starts) == 5 and len(reads) == 5
+    assert max(starts) < min(reads), log
+    assert (dev.writebacks, dev.writebacks_early) == (5, 1)
+    for tag, d in enumerate(datums, 1):
+        host = d.get_copy(0)
+        assert isinstance(host.value, np.ndarray) and host.version == 2
+        np.testing.assert_array_equal(host.value, np.full(4, tag))
+        assert d.get_copy(dev.device_index) is None
+
+
+@pytest.mark.parametrize("start", ["absent", "raises"])
+@pytest.mark.parametrize("via", ["flush", "pushout_then_flush", "drain"])
+def test_a_value_whose_transfer_cannot_start_still_comes_back(dev, start,
+                                                              via):
+    log = []
+    good = _dirty_resident(dev, log, 1)
+    odd = _dirty_resident(dev, log, 2, start)
+    if via == "pushout_then_flush":
+        dev.pushout(odd.get_copy(dev.device_index))
+        assert dev.pushouts == 0
+    if via == "drain":
+        with dev._lru_lock:
+            while dev._mem_lru:
+                dev._evict_one_locked()
+        dev._drain_evictions()
+        assert dev.deferred_evictions == 2
+    else:
+        dev.flush_cache()
+    for tag, d in ((1, good), (2, odd)):
+        np.testing.assert_array_equal(d.get_copy(0).value, np.full(4, tag))
+    assert (dev.writebacks, dev.writebacks_early) == (2, 0)
+    assert not dev._mem_lru and not dev._evict_q and dev._evict_bytes == 0
+
+
+def test_pushed_out_tiles_survive_eviction_under_pressure(dev):
+    """KT = 1: every task's C tile is final, so under a 3-tile budget the
+    tiles that reach the w2r queue are pushed-out ones; the drain reads
+    them through the transfer the push-out started."""
+    a, b, c, A, B, C = _mk_abc(64, 16, 25, k=16)
+    dev._mem_budget = 3 * 16 * 16 * 4
+    ctx = Context(nb_cores=0)
+    ctx.add_taskpool(tiled_gemm_ptg(A, B, C, devices="tpu"))
+    ctx.wait(timeout=120)
+    assert dev.pushouts == 16
+    assert dev.deferred_evictions > 0 and dev.writebacks_early > 0
+    dev.sync()
+    dev.flush_cache()
+    ctx.fini()
+    np.testing.assert_allclose(C.to_dense(), c + a @ b, rtol=1e-3, atol=1e-4)
+    assert dev.writebacks_early == dev.writebacks == 16
+    assert dev._mem_bytes == 0 and dev._evict_bytes == 0
+
+
+@pytest.mark.parametrize("budget_tiles", [None, 3])
+def test_dispatch_failure_salvages_pushed_out_tiles(dev, param, budget_tiles):
+    """The device fails with pushed-out dirty tiles in the LRU (and, under
+    a tight budget, in ``_evict_q``): the salvage reads them like any other
+    dirty tile and the CPU incarnations finish the product."""
+    a, b, c, A, B, C = _mk_abc(64, 16, 26, k=16)
+    if budget_tiles is not None:
+        dev._mem_budget = budget_tiles * 16 * 16 * 4
+    param("device_tpu_batch_max", 4)
+    calls = {"n": 0}
+
+    def hook(batch):
+        calls["n"] += 1
+        if calls["n"] > 2:
+            raise ConnectionResetError("device reset mid-batch")
+
+    dev._dispatch_hook = hook
+    ctx = Context(nb_cores=0)
+    ctx.add_taskpool(tiled_gemm_ptg(A, B, C, devices="auto"))
+    ctx.wait(timeout=120)
+    dev.sync()
+    ctx.fini()
+    assert calls["n"] > 2 and dev.enabled is False
+    assert dev.pushouts == dev.executed_tasks == 8
+    assert dev.writebacks_early == 8
+    np.testing.assert_allclose(C.to_dense(), c + a @ b, rtol=1e-3, atol=1e-4)

@@ -23,8 +23,9 @@ NT = N // NB
 DOCUMENTED = {
     "ctx.init", "ctx.add_taskpool", "ctx.progress", "ctx.fini",
     "devmod.manage", "sched.flood", "devmod.prefetch", "devmod.stage_in",
-    "devmod.dispatch", "devmod.inflight_wait", "devmod.complete",
-    "sched.release", "devmod.drain", "devmod.writeback"}
+    "devmod.dispatch", "devmod.inflight_wait", "devmod.sync",
+    "devmod.complete", "sched.release", "devmod.pushout", "devmod.drain",
+    "devmod.writeback"}
 WALLS = ("t_stage_in", "t_dispatch", "t_complete", "t_drain", "t_writeback")
 
 
@@ -35,7 +36,7 @@ def _gemm():
     A = TiledMatrix.from_dense("A", a, NB, NB)
     B = TiledMatrix.from_dense("B", a.T.copy(), NB, NB)
     C = TiledMatrix("C", N, N, NB, NB)
-    return tiled_gemm_ptg(A, B, C), NT ** 3, {"gemm"}
+    return tiled_gemm_ptg(A, B, C), NT ** 3, {"gemm"}, NT * NT
 
 
 def _cholesky():
@@ -48,7 +49,8 @@ def _cholesky():
         init_fn=lambda i, k, shape: np.ascontiguousarray(
             spd[i * NB:(i + 1) * NB, k * NB:(k + 1) * NB]))
     tasks = NT + NT * (NT - 1) + NT * (NT - 1) * (NT - 2) // 6
-    return tiled_cholesky_ptg(A), tasks, {"trsm_rlt", "syrk_ln", "gemm_nt"}
+    return (tiled_cholesky_ptg(A), tasks, {"trsm_rlt", "syrk_ln", "gemm_nt"},
+            NT * (NT + 1) // 2)
 
 
 PROBLEMS = {"gemm": _gemm, "cholesky": _cholesky}
@@ -103,7 +105,7 @@ class _Counted:
 @pytest.mark.parametrize("problem", PROBLEMS)
 def test_off_a_solve_builds_nothing_and_asks_once_per_batch(
         problem, one_accelerator, monkeypatch):
-    pool, tasks, _ = PROBLEMS[problem]()
+    pool, tasks, _, _ = PROBLEMS[problem]()
     spans.phase_refresh()       # binds the profiler's probe
     asked = []
     probe = spans._session_active
@@ -124,7 +126,7 @@ def test_off_a_solve_builds_nothing_and_asks_once_per_batch(
 @pytest.mark.parametrize("problem", PROBLEMS)
 def test_on_every_second_of_a_solve_has_a_documented_owner(
         problem, how, one_accelerator, param, tmp_path):
-    pool, tasks, classes = PROBLEMS[problem]()
+    pool, tasks, classes, result_tiles = PROBLEMS[problem]()
     if how == "prof_spans":
         param("prof_spans", True)
     else:
@@ -156,6 +158,10 @@ def test_on_every_second_of_a_solve_has_a_documented_owner(
     assert 0.9 * wall <= owned <= wall, (owned, wall)
     assert all(row[0] >= 0 for row in table.values()), table
     assert table["sched.release"][2] == tasks == delta["executed_tasks"]
+    # a counter per memory edge, inside the release and off its self time
+    assert table["devmod.pushout"][2] == result_tiles
+    assert table["sched.release"][1] - table["sched.release"][0] \
+        == table["devmod.pushout"][1]
     # one span a batch or a solve, none a task
     assert table["devmod.dispatch"][2] == delta["xla_calls"]
     assert table["ctx.init"][2] == table["devmod.writeback"][2] == 1
