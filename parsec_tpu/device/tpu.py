@@ -34,7 +34,7 @@ from collections import OrderedDict, deque
 from typing import Any, Callable
 
 from ..core.params import params as _params
-from ..data.data import (COHERENCY_EXCLUSIVE, COHERENCY_INVALID,
+from ..data.data import (ACCESS_WRITE, COHERENCY_EXCLUSIVE, COHERENCY_INVALID,
                          COHERENCY_OWNED, COHERENCY_SHARED, DataCopy)
 from ..prof import pins, spans
 from ..prof.pins import PinsEvent
@@ -42,9 +42,13 @@ from ..runtime.task import HOOK_RETURN_ASYNC
 from .device import Device, note_xla_calls, registry
 
 _params.register("device_tpu_memory_use", 90,
-                 "percent of per-device HBM the tile cache may use")
+                 "percent of per-device HBM the device module may hold: the "
+                 "tile cache's current copies and what only its unconfirmed "
+                 "dispatches keep alive (superseded versions, padding lanes)")
 _params.register("device_tpu_max_inflight", 32,
-                 "bound on enqueued-but-unconfirmed device tasks")
+                 "bound, by count, on enqueued-but-unconfirmed dispatches; "
+                 "the ring is cut shorter than this whenever the bytes it "
+                 "holds would take the module past device_tpu_memory_use")
 _params.register("device_tpu_batch", True,
                  "stack same-class pending tasks into one vmapped dispatch")
 _params.register("device_tpu_batch_max", 64,
@@ -166,8 +170,14 @@ class TPUDevice(Device):
         self._mem_lru: OrderedDict[Any, DataCopy] = OrderedDict()
         self._mem_bytes = 0
         self._mem_budget = self._hbm_budget()
-        # bounded in-flight window (poor-man's event ring)
-        self._inflight: deque[Any] = deque()
+        # bounded in-flight window (poor-man's event ring): per dispatch,
+        # what to wait on and the bytes that stay allocated until it has run
+        # and nothing else accounts for: the versions it superseded (the
+        # program still reads them) and its padding lanes.  Bounded by
+        # count (_max_inflight) and, with the LRU, by the one byte budget
+        # (_make_room)
+        self._inflight: deque[tuple] = deque()
+        self._held_bytes = 0
         self._max_inflight = _params.get("device_tpu_max_inflight")
         # deferred evictions (the w2r-task analog): victims leave the LRU
         # immediately but write back AFTER the batch's dispatches enqueue,
@@ -179,6 +189,12 @@ class TPUDevice(Device):
         self._evict_q: deque[DataCopy] = deque()
         self._evict_bytes = 0
         self.deferred_evictions = 0
+        self.evicted_bytes = 0      # the bytes behind deferred_evictions
+        self.evict_stuck = 0        # over budget with nothing evictable
+        # the byte budget at work: dispatches confirmed early because of
+        # it, and the most the ring ever held
+        self.pressure_confirms = 0
+        self.inflight_held_bytes_peak = 0
         # fused-dispatch cache ((dyld, padded B, signature) -> jitted fn)
         self._vmap_cache: dict[Any, Callable] = {}
         # fault-injection seam for the pressure harness: called with the
@@ -256,14 +272,36 @@ class TPUDevice(Device):
             self._mem_lru[key] = copy
             self._mem_lru.move_to_end(key)
             self._mem_bytes += nbytes
-            while self._mem_bytes > self._mem_budget and len(self._mem_lru) > 1:
-                self._evict_one_locked()
+        self._make_room(0)
 
-    def _evict_one_locked(self) -> None:
-        """Evict the least-recently-used unpinned tile.  The victim only
-        leaves the LRU here; its write-back is DEFERRED to the w2r queue
-        (``parsec_gpu_create_w2r_task``) drained between batches — the
-        manager never blocks on a D2H mid-pipeline."""
+    def _make_room(self, need: int) -> None:
+        """The one place the budget is held.  It covers everything the
+        module holds on the chip: the LRU's current copies, what only the
+        unconfirmed dispatches keep alive (``_held_bytes``) and ``need``,
+        the bytes the caller is about to allocate (a stage-in's misses, a
+        dispatch's stack and outputs; 0 after an insertion).  Past the
+        budget the oldest dispatches are confirmed and dropped first: that
+        frees the versions they superseded, for a wait that is near nothing
+        while the chip idles behind a slower host.  Only when the ring is
+        empty do tiles leave through the w2r queue."""
+        room = self._mem_budget - need
+        if self._mem_bytes + self._held_bytes <= room:
+            return
+        with spans.phase("devmod.pressure"):
+            while self._inflight \
+                    and self._mem_bytes + self._held_bytes > room:
+                self._confirm_oldest()
+                self.pressure_confirms += 1
+            with self._lru_lock:
+                while self._mem_bytes + self._held_bytes > room \
+                        and self._evict_one_locked():
+                    pass
+
+    def _evict_one_locked(self) -> bool:
+        """Evict the least-recently-used unpinned tile; False where there is
+        none.  The victim only leaves the LRU here; its write-back is
+        DEFERRED to the w2r queue (``parsec_gpu_create_w2r_task``) drained
+        between batches — the manager never blocks on a D2H mid-pipeline."""
         for k in list(self._mem_lru):
             c = self._mem_lru[k]
             if c.readers > 0:
@@ -273,8 +311,10 @@ class TPUDevice(Device):
             self._mem_bytes -= nb
             self._evict_bytes += nb
             self._evict_q.append(c)
-            return
-        # nothing evictable; let XLA's allocator cope
+            return True
+        # nothing evictable: XLA's allocator has to cope, and it is counted
+        self.evict_stuck += 1
+        return False
 
     def _drain_evictions(self) -> None:
         """Write back queued eviction victims (the w2r stage).  A victim
@@ -305,6 +345,7 @@ class TPUDevice(Device):
             try:
                 while i < len(victims):
                     self._writeback(victims[i])
+                    self.evicted_bytes += _copy_nbytes(victims[i])
                     i += 1
                     self.deferred_evictions += 1
             except BaseException:
@@ -451,6 +492,7 @@ class TPUDevice(Device):
         if not missing:
             return
         keys = list(missing)
+        self._make_room(sum(_copy_nbytes(c) for c in missing.values()))
         values = jax.device_put([missing[k].value for k in keys],
                                 self.jax_device)
         landed: dict[Any, DataCopy] = {}
@@ -634,7 +676,7 @@ class TPUDevice(Device):
         # may be dropped freely; an RW flow's prior value is an INPUT, so
         # it gets no exemption — and any other tile newer than its host
         # copy must salvage or we stop
-        from ..data.data import ACCESS_READ, ACCESS_WRITE
+        from ..data.data import ACCESS_READ
         recomputed: set[int] = set()
         for d in victims:
             for f in d.task.task_class.flows:
@@ -763,10 +805,15 @@ class TPUDevice(Device):
                 pass              # one XLA call serviced the whole batch
             else:
                 for dtask in batch:   # exec phase (exec streams analog)
+                    # the body replaces each written flow's value: what it
+                    # supersedes stays allocated until the program has run
+                    held = sum(_copy_nbytes(c) for c in
+                               self._written_copies(dtask.task))
+                    self._make_room(held)
                     out = dtask.submit(dtask.es, dtask.task, self)
                     self.xla_calls += 1
                     note_xla_calls(1)
-                    self._note_inflight(out)
+                    self._note_inflight(out, held)
                     self.executed_tasks += 1
                     self._mark_written(dtask.task)
         with _Wall(self, "t_complete", "devmod.complete"):
@@ -781,17 +828,21 @@ class TPUDevice(Device):
                 complete(dtask.es, dtask.task)
         pins.fire(PinsEvent.DEVICE_BATCH_END, None, len(batch))
 
-    def _mark_written(self, task: Any) -> None:
-        # written flows become dirty device copies (coherency epilog,
-        # cf. kernel_epilog versions->owner, device_gpu.c:2251)
-        from ..data.data import ACCESS_WRITE
+    def _written_copies(self, task: Any):
+        """The copies on this device that ``task``'s written flows hold."""
         for f in task.task_class.flows:
             if f.is_ctl or not (f.access & ACCESS_WRITE):
                 continue
             c = task.data[f.flow_index]
             if c is not None and c.device_index == self.device_index:
-                c.coherency = COHERENCY_OWNED
-                c.original.owner_device = self.device_index
+                yield c
+
+    def _mark_written(self, task: Any) -> None:
+        # written flows become dirty device copies (coherency epilog,
+        # cf. kernel_epilog versions->owner, device_gpu.c:2251)
+        for c in self._written_copies(task):
+            c.coherency = COHERENCY_OWNED
+            c.original.owner_device = self.device_index
 
     # ------------------------------------------------- vmapped batch dispatch
     def _run_vmapped(self, batch: list[TPUDeviceTask]) -> bool:
@@ -817,7 +868,6 @@ class TPUDevice(Device):
         """
         import jax
 
-        from ..data.data import ACCESS_WRITE
         from ..ptg.lowering import find_traceable
 
         tc = batch[0].task.task_class
@@ -872,13 +922,20 @@ class TPUDevice(Device):
             fn = self._vmap_cache[key] = jax.jit(fused)
         flat = [v for vs in cols
                 for v in (vs + [vs[0]] * (Bp - B))]   # lane-0 padding
+        # what the call allocates: the stacked operands while it runs, and
+        # Bp output slices a written flow, which supersede B current
+        # versions and pad Bp - B lanes: both stay until the call has run
+        # (a flow's tiles are of one shape: one nbytes a flow, not a tile)
+        nb = [c[0].nbytes for c in cols]
+        held = Bp * sum(nb[data_flows.index(w)] for w in written)
+        self._make_room(Bp * sum(nb) + held)
         if self._dispatch_hook is not None:
             self._dispatch_hook(batch)
         outs = fn(*flat)
         self.xla_calls += 1              # the whole batch, one enqueue
         note_xla_calls(1)
         assert len(outs) == len(written), (dyld, len(outs), len(written))
-        self._note_inflight(outs)
+        self._note_inflight(outs, held)
         for w, parts in zip(written, outs):
             for i, dtask in enumerate(batch):
                 c = dtask.task.data[w.flow_index]
@@ -890,15 +947,23 @@ class TPUDevice(Device):
         self.batched_dispatches += 1
         return True
 
-    def _note_inflight(self, out: Any) -> None:
+    def _note_inflight(self, out: Any, held: int = 0) -> None:
         """Bound the enqueue depth: block on the oldest dispatch once more
-        than ``max_inflight`` tasks are unconfirmed (event-ring analog)."""
+        than ``max_inflight`` are unconfirmed (event-ring analog).  ``held``:
+        the bytes that stay allocated until this dispatch has run."""
         if out is None:
             return
-        self._inflight.append(out)
+        self._inflight.append((out, held))
+        self._held_bytes += held
+        if self._held_bytes > self.inflight_held_bytes_peak:
+            self.inflight_held_bytes_peak = self._held_bytes
         while len(self._inflight) > self._max_inflight:
-            oldest = self._inflight.popleft()
-            self._confirm(oldest)
+            self._confirm_oldest()
+
+    def _confirm_oldest(self) -> None:
+        out, held = self._inflight.popleft()
+        self._held_bytes -= held
+        self._confirm(out)
 
     def _confirm(self, out: Any) -> None:
         """Wait for an enqueued dispatch; a device-side failure disables
@@ -922,7 +987,7 @@ class TPUDevice(Device):
         # references to the intermediate versions of every RW tile
         with spans.phase("devmod.sync"):
             while self._inflight:
-                self._confirm(self._inflight.popleft())
+                self._confirm_oldest()
 
     # -------------------------------------------------------- diagnostics
     def debug_state(self) -> dict:
@@ -934,6 +999,11 @@ class TPUDevice(Device):
                  "xla_calls": self.xla_calls,
                  "batched_dispatches": self.batched_dispatches,
                  "inflight_dispatches": len(self._inflight),
+                 "inflight_held_bytes": self._held_bytes,
+                 "inflight_held_bytes_peak": self.inflight_held_bytes_peak,
+                 "pressure_confirms": self.pressure_confirms,
+                 "evicted_bytes": self.evicted_bytes,
+                 "evict_stuck": self.evict_stuck,
                  "cache_hits": self.cache_hits,
                  "cache_misses": self.cache_misses,
                  "bytes_in": self.bytes_in, "bytes_out": self.bytes_out,

@@ -197,6 +197,15 @@ def test_truncated_enumeration_stays_clean():
     assert "truncated" in report.summary()
 
 
+def test_the_64x64_cholesky_dag_is_verified_whole():
+    """The DAG of ``potrf-64k`` (BENCHMARK.json): 64 + 4,032 + 4,032 + 41,664
+    = 45,760 tasks stay under ``analysis_max_tasks``, so the gate sees every
+    one and not a prefix."""
+    report = check_ptg(_cholesky(64))
+    assert report.ntasks == 45760 and not report.truncated
+    assert report.ok, report.findings
+
+
 def test_gate_mode_raises_typed_error():
     tp = _cholesky()
     next(f for f in tp.task_class("GEMM").flows

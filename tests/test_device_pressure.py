@@ -343,3 +343,126 @@ def test_dispatch_failure_salvages_pushed_out_tiles(dev, param, budget_tiles):
     assert dev.pushouts == dev.executed_tasks == 8
     assert dev.writebacks_early == 8
     np.testing.assert_allclose(C.to_dense(), c + a @ b, rtol=1e-3, atol=1e-4)
+
+
+# --------------------------------------------------------------------------
+# one byte budget for the LRU and the versions in flight (ISSUE 29)
+# --------------------------------------------------------------------------
+
+def _bench(name):
+    """A module of ``benchmarks/`` (the plain reference and its seeded data
+    import nothing of the program)."""
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    return __import__(name)
+
+
+def _cholesky_under_budget(dev, n, nb, seed, budget_tiles):
+    """One solve of the tiled Cholesky on seeded tiles under a budget of
+    ``budget_tiles``; the probe gap against the tile-wise reference, the most
+    the module held in LRU + in-flight bytes at any dispatch (in tiles), and
+    the result tiles the host holds."""
+    from parsec_tpu.data_dist.matrix import SymTwoDimBlockCyclic
+    from parsec_tpu.models.cholesky import tiled_cholesky_ptg
+    ref, reft, harness = _bench("reference"), _bench("reference_tiled"), \
+        _bench("harness")
+    tile = nb * nb * 4
+    tiles = reft.spd_tiles(seed, n, nb)
+    A = SymTwoDimBlockCyclic("A", n, n, nb, nb, dtype=np.float32,
+                             init_fn=lambda m, k, shape: tiles[m, k])
+    dev._mem_budget = budget_tiles * tile
+    peak = [0]
+    note = dev._note_inflight
+
+    def noted(out, held=0):
+        note(out, held)
+        peak[0] = max(peak[0], dev._mem_bytes + dev._held_bytes)
+
+    dev._note_inflight = noted
+    ctx = Context(nb_cores=0)
+    ctx.add_taskpool(tiled_cholesky_ptg(A, devices="tpu"))
+    ctx.wait(timeout=600)
+    dev.sync()
+    dev.flush_cache()
+    ctx.fini()
+    got = {k: harness.host_tile(A.data_of(*k)) for k in tiles}
+    assert all(v is not None for v in got.values()), "a tile did not come back"
+    X = ref.probes(seed, n)
+    gap = ref.gap(reft.potrf_got(got, X, nb), reft.sym_apply(tiles, X, nb))
+    assert dev._held_bytes == 0 and not dev._inflight
+    assert dev._mem_bytes == 0 and dev._evict_bytes == 0
+    return gap, peak[0] / tile, len(tiles)
+
+
+def test_the_64x64_cholesky_dag_stays_inside_a_budget_of_2600_tiles(dev):
+    """The DAG of ``potrf-64k`` (64 x 64 tiles, 45,760 tasks) at nb=64: the
+    triangle is 2,080 tiles, and unbounded the ring of 32 dispatches holds
+    up to 2,000 superseded versions and padding lanes beside it.  Under a
+    budget of 2,600 tiles the module confirms its oldest dispatches early
+    and never holds more; nothing has to be evicted."""
+    gap, peak_tiles, triangle = _cholesky_under_budget(dev, 4096, 64, 29, 2600)
+    assert dev.executed_tasks == 45760 and triangle == 2080
+    assert gap < 2e-6, gap
+    assert peak_tiles <= 2600, peak_tiles
+    # unbounded, the ring's peak reads 2,000 tiles on this graph
+    assert 0 < dev.inflight_held_bytes_peak < 2000 * 64 * 64 * 4
+    assert dev.pressure_confirms >= 1
+    assert dev.evicted_bytes == 0 and dev.evict_stuck == 0
+    assert dev.bytes_in == 2080 * 64 * 64 * 4      # nothing staged twice
+
+
+def test_half_the_triangle_evicts_and_restages_and_loses_nothing(dev):
+    """32 x 32 tiles (5,984 tasks) under a budget of half the triangle: the
+    LRU evicts through the w2r queue, tiles are staged again, every dirty
+    victim reaches the host and the factor is the reference's."""
+    tile = 64 * 64 * 4
+    gap, peak_tiles, triangle = _cholesky_under_budget(dev, 2048, 64, 31, 264)
+    assert dev.executed_tasks == 5984 and triangle == 528
+    assert gap < 2e-6, gap
+    assert peak_tiles <= 264, peak_tiles
+    assert dev.deferred_evictions > 0
+    assert dev.evicted_bytes == dev.deferred_evictions * tile
+    # what left early came back: staged bytes beyond the triangle
+    assert dev.bytes_in > triangle * tile
+    assert dev.bytes_in - triangle * tile <= dev.evicted_bytes
+    assert dev.pressure_confirms >= 1
+
+
+def test_over_budget_with_nothing_evictable_is_counted(dev):
+    """The allocator has to cope, and the module says so."""
+    log = []
+    _dirty_resident(dev, log, 1)
+    dev._mem_budget = 16
+    with dev._lru_lock:
+        dev._mem_lru[next(iter(dev._mem_lru))].readers = 1     # pinned
+    dev._make_room(16)
+    assert dev.evict_stuck == 1 and not dev._evict_q
+    assert dev.pressure_confirms == 0       # nothing in flight to confirm
+    with dev._lru_lock:
+        dev._mem_lru[next(iter(dev._mem_lru))].readers = 0
+    dev._make_room(16)
+    assert dev.evict_stuck == 1 and len(dev._evict_q) == 1
+    dev.flush_cache()
+
+
+def test_the_ring_is_bounded_by_count_when_the_budget_is_far(dev):
+    """At the stand-in's 16 GiB the byte bound never trips: a GEMM's ring
+    fills to ``device_tpu_max_inflight`` and no dispatch is confirmed for
+    pressure."""
+    a, b, c, A, B, C = _mk_abc(128, 16, 33)
+    ctx = Context(nb_cores=0)
+    ctx.add_taskpool(tiled_gemm_ptg(A, B, C, devices="tpu"))
+    ctx.wait(timeout=120)
+    assert len(dev._inflight) <= dev._max_inflight
+    assert dev._held_bytes == sum(held for _, held in dev._inflight)
+    assert dev.inflight_held_bytes_peak >= dev._held_bytes > 0
+    dev.sync()
+    dev.flush_cache()
+    ctx.fini()
+    np.testing.assert_allclose(C.to_dense(), c + a @ b, rtol=1e-3, atol=1e-4)
+    assert dev.pressure_confirms == 0 and dev.evict_stuck == 0
+    assert dev.evicted_bytes == 0 and dev._held_bytes == 0

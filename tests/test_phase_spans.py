@@ -25,7 +25,7 @@ DOCUMENTED = {
     "devmod.manage", "sched.flood", "devmod.prefetch", "devmod.stage_in",
     "devmod.dispatch", "devmod.inflight_wait", "devmod.sync",
     "devmod.complete", "sched.release", "devmod.pushout", "devmod.drain",
-    "devmod.writeback"}
+    "devmod.writeback", "devmod.pressure"}
 WALLS = ("t_stage_in", "t_dispatch", "t_complete", "t_drain", "t_writeback")
 
 
@@ -143,8 +143,12 @@ def test_on_every_second_of_a_solve_has_a_documented_owner(
             jax.profiler.stop_trace()
     table = spans.phase_totals()
     assert set(table) <= DOCUMENTED
-    # a solve that fits the cache evicts nothing: every other name shows
-    assert set(table) >= DOCUMENTED - {"devmod.inflight_wait"}, set(table)
+    # a solve that fits the budget waits for nothing and makes no room:
+    # every other name shows
+    assert set(table) >= DOCUMENTED - {"devmod.inflight_wait",
+                                       "devmod.pressure"}, set(table)
+    assert "devmod.pressure" not in table
+    assert dev.pressure_confirms == 0 and dev.evicted_bytes == 0
 
     # a wall and its spans are fed from one pair of clock readings
     for names, attr in ((("devmod.stage_in", "devmod.prefetch"), "t_stage_in"),
@@ -168,3 +172,31 @@ def test_on_every_second_of_a_solve_has_a_documented_owner(
     fused = {fn.__name__ for fn in dev._vmap_cache.values()}
     assert fused >= {f"fused_{c}" for c in classes}, fused
     assert all(name.startswith("fused_") for name in fused)
+
+
+def test_under_a_tight_budget_the_pressure_has_its_own_span(one_accelerator,
+                                                            param):
+    """``devmod.pressure`` opens only when the budget presses, under
+    ``devmod.manage``; its self time is the confirming and the queueing of
+    evictions, the wait stays ``devmod.inflight_wait``'s, and the solve's
+    seconds are owned as before."""
+    pool, tasks, _, _ = PROBLEMS["cholesky"]()
+    param("prof_spans", True)
+    Context(nb_cores=0).fini()         # registers the accelerator
+    (dev,) = [d for d in registry.devices if isinstance(d, TPUDevice)]
+    dev._mem_budget = 40 * NB * NB * 4     # the triangle is 36 tiles
+    spans.phase_reset()
+    try:
+        wall, delta, dev = _solve(pool)
+    finally:
+        param("prof_spans", False)
+        spans.uninstall()
+    table = spans.phase_totals()
+    assert set(table) <= DOCUMENTED and delta["executed_tasks"] == tasks
+    self_ns, inclusive_ns, count = table["devmod.pressure"]
+    assert count >= 1 and dev.pressure_confirms >= 1
+    assert 0 <= self_ns <= inclusive_ns
+    # the waits it caused are inside it and not its own
+    assert table["devmod.inflight_wait"][2] >= dev.pressure_confirms
+    owned = sum(row[0] for row in table.values()) / 1e9
+    assert 0.9 * wall <= owned <= wall, (owned, wall)

@@ -140,8 +140,11 @@ def test_dead_rank_streams_requeue_oracle_exact():
     oracle = MODEL.reference_generate(prompt, nmax)
 
     def frontend(srv, peers):
-        filler = srv.submit_stream([2, 4], max_new_tokens=4)   # rank 0
-        h = srv.submit_stream(prompt, max_new_tokens=nmax)     # rank 1
+        # a filler long enough to still load rank 0 when h is placed: under
+        # tier-1's six workers a 4-token one could finish between the two
+        # submits, and h then landed on rank 0 (ROADMAP D10)
+        filler = srv.submit_stream([2, 4], max_new_tokens=nmax)  # rank 0
+        h = srv.submit_stream(prompt, max_new_tokens=nmax)       # rank 1
         assert h.rank == 1
         # let rank 1 ship a few tokens, then it goes dark
         deadline = time.monotonic() + 60
