@@ -126,13 +126,14 @@ def bench_release_throughput(ntasks: int = 10000, reps: int = 3) -> dict:
 
 class _BenchTask:
     __slots__ = ("priority",)
+    task_class = None        # one class: one bucket of the ready queue
 
     def __init__(self) -> None:
         self.priority = 0
 
 
 def bench_steal_us(n: int = 200, reps: int = 50) -> dict:
-    """lfq local-pop vs steal latency on the sharded per-stream deques,
+    """lfq local-pop vs steal latency on the per-stream ready queues,
     driven through the real scheduler module (no Context needed)."""
     import parsec_tpu.sched  # noqa: F401 — registers components + params
     from parsec_tpu.sched.modules import LFQModule

@@ -1,8 +1,8 @@
 """runtimelint: AST concurrency + hygiene lint over the runtime's source.
 
-The hot paths of this runtime (``core/hbbuffer.py`` StealDeque,
-``runtime/context.py``, ``comm/socket_fabric.py``) deliberately run
-*unguarded* on documented GIL-atomicity and lock-discipline assumptions —
+The hot paths of this runtime (``core/hbbuffer.py`` ReadyQueue,
+``runtime/context.py``, ``comm/socket_fabric.py``) run on documented
+lock-discipline and, where *unguarded*, GIL-atomicity assumptions —
 the MPK bet: verify structure at compile/CI time, keep the serving path
 fast.  This lint turns the comments into checked contracts:
 

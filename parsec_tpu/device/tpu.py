@@ -218,6 +218,10 @@ class TPUDevice(Device):
         self.pushouts = 0
         self.writebacks = 0
         self.writebacks_early = 0
+        # the flood (_flood_from_scheduler): tasks it took out of the
+        # scheduler into a batch, and tasks it popped only to hand back
+        self.flood_selected = 0
+        self.flood_putbacks = 0
         self.t_manager = 0.0   # total wall inside the manager drain loop
         # stage-in tile-cache effectiveness, per (task, flow) reference —
         # the hit-rate gauge the metrics snapshotter samples
@@ -744,8 +748,10 @@ class TPUDevice(Device):
         The reference's manager accumulates batches passively because many
         workers enqueue concurrently (``device_gpu.c:2457-2473``); under the
         TPU module a single driving thread hands tasks over one at a time,
-        so the manager *actively* drains the scheduler of vmappable
-        same-class work (and puts anything else back).  Only classes with a
+        so the manager *actively* asks the scheduler for the ready tasks of
+        the batch's class (``SchedulerModule.select_class``: lfq pops that
+        class's bucket alone; a module without such a store selects and puts
+        back what is not the class).  Only classes with a
         traceable incarnation are worth flooding — everything else would
         fall back to the per-task path anyway.
         """
@@ -762,21 +768,18 @@ class TPUDevice(Device):
                      if c.device_type == self.type and c.dyld), None)
         if dyld is None or find_traceable(dyld) is None:
             return
-        maxb = _params.get("device_tpu_batch_max")
-        stash: list[tuple[Any, int]] = []
         sched = es.context.scheduler
-        while len(batch) < maxb:
-            t, distance = sched.select(es)
-            if t is None:
-                break
-            if t.task_class is tc and es.context.best_device(
-                    t, self.type) is self:
+        taken, put_back = sched.select_class(
+            es, tc, _params.get("device_tpu_batch_max") - len(batch))
+        for t, distance in taken:
+            if es.context.best_device(t, self.type) is self:
                 prepare_input(es, t)
                 batch.append(TPUDeviceTask(es, t, first.submit))
-            else:
-                stash.append((t, distance))
-        for t, distance in stash:
-            sched.schedule(es, [t], distance)
+                self.flood_selected += 1
+            else:       # another accelerator's: back where it came from
+                sched.schedule(es, [t], distance)
+                put_back += 1
+        self.flood_putbacks += put_back
 
     def _take_batch_locked(self) -> list[TPUDeviceTask]:
         batch = [self._pending.popleft()]
@@ -1009,6 +1012,8 @@ class TPUDevice(Device):
                  "bytes_in": self.bytes_in, "bytes_out": self.bytes_out,
                  "pushouts": self.pushouts, "writebacks": self.writebacks,
                  "writebacks_early": self.writebacks_early,
+                 "flood_selected": self.flood_selected,
+                 "flood_putbacks": self.flood_putbacks,
                  "stage_in_s": round(self.t_stage_in, 3),
                  "dispatch_s": round(self.t_dispatch, 3),
                  "complete_s": round(self.t_complete, 3),

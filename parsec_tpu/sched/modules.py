@@ -1,11 +1,12 @@
 """Scheduler implementations.
 
 Rebuild of the reference's scheduler zoo (``parsec/mca/sched/*``, SURVEY
-§2.4), all eleven: **lfq** (default) per-stream bounded buffers spilling to
-a per-VP overflow dequeue, with sibling stealing; **ap** global
-absolute-priority list; **spq** global priority+distance list (the tutorial
-scheduler, ``sched.h:87-169``); **gd** global dequeue; **ll/llp** per-stream
-LIFOs with stealing (± priority); **rnd** random; **ip** inverse priority;
+§2.4), all eleven: **lfq** (default) per-stream bounded priority heaps
+bucketed by task class, spilling to a per-VP FIFO bucketed the same way,
+with sibling stealing; **ap** global absolute-priority list; **spq** global
+priority+distance list (the tutorial scheduler, ``sched.h:87-169``); **gd**
+global dequeue; **ll/llp** per-stream LIFOs with stealing (± priority);
+**rnd** random; **ip** inverse priority;
 and the local-hierarchical family — **pbq** priority-based local queues with
 proximity-ordered stealing, **ltq** local tree queues whose steals migrate
 whole release-subtrees, **lhq** local hierarchical queues with an
@@ -23,7 +24,7 @@ from collections import deque
 from typing import Any, Sequence
 
 from ..core.params import params as _params
-from ..core.hbbuffer import HBBuffer, StealDeque
+from ..core.hbbuffer import HBBuffer, ReadyQueue
 from ..core.mca import Component, component
 # imported at module load (main thread): the topology affinity snapshot
 # must be taken before any worker binds itself to a single core
@@ -31,9 +32,10 @@ from ..core import topology as _topology
 from .api import SchedulerModule
 
 _params.register("sched_lfq_buffer_size", 256,
-                 "per-stream sharded-deque capacity for lfq (spills to the "
-                 "per-VP system queue beyond this; large enough that a "
-                 "release batch stays on the lock-free local path)")
+                 "per-stream ready-queue capacity for lfq: the window in "
+                 "which priorities order the tasks; a release beyond it "
+                 "spills to the per-VP system queue and keeps arrival order "
+                 "(which is what fills the device module's batches)")
 
 
 def _task_priority(t: Any) -> int:
@@ -62,47 +64,49 @@ def _stream_queue_depths(context: Any) -> dict[str, int]:
 # ---------------------------------------------------------------------------
 
 class _VPQueues:
-    def __init__(self) -> None:
-        self.system = deque()
+    def __init__(self, system: Any) -> None:
+        self.system = system
         self.lock = threading.Lock()
 
 
 class LFQModule(SchedulerModule):
-    """Sharded ready queues: the per-ES :class:`StealDeque` is the primary
-    push target — owner push/pop are GIL-atomic deque operations with no
-    lock, and a lock is taken only on steal, overflow spill, or the
-    priority-scan degradation (core/hbbuffer.py).  Cross-worker contention
-    on the common select→release path is therefore zero."""
+    """Ready queues keyed by task class: each stream owns a
+    :class:`ReadyQueue` (core/hbbuffer.py), the primary push target, bounded
+    by ``sched_lfq_buffer_size``; each VP a FIFO one for what spills past
+    that bound, external submissions and rescheduled tasks.  A select pops
+    the stream's own queue (newest first until a task carries a priority,
+    then best priority first), steals a sibling's oldest, then takes the
+    system queue's oldest; none of them scans, and each queue's lock is
+    contended only by thieves and remote pushers."""
 
     name = "lfq"
 
     def install(self, context: Any) -> None:
         for vp in context.virtual_processes:
-            vp.sched_private = _VPQueues()
+            vp.sched_private = _VPQueues(ReadyQueue(fifo=True))
         self._cap = _params.get("sched_lfq_buffer_size")
 
     def flow_init(self, es: Any) -> None:
-        vpq = es.virtual_process.sched_private
-
-        def overflow(items: list, distance: int) -> None:
-            with vpq.lock:
-                vpq.system.extend(items)
-
-        es.sched_private = StealDeque(self._cap, parent_push=overflow)
+        es.sched_private = ReadyQueue()
 
     def schedule(self, es: Any, tasks: Sequence[Any], distance: int = 0) -> None:
         sp = es.sched_private
+        system = es.virtual_process.sched_private.system
         if sp is None or distance > 0:
-            vpq = es.virtual_process.sched_private
-            with vpq.lock:
-                vpq.system.extend(tasks)
+            system.push_all(tasks)
             return
-        sp.push_all(tasks if type(tasks) is list else list(tasks), distance)
+        # advisory bound (concurrent pushers may briefly overshoot): the
+        # head of a release stays local, the tail spills in arrival order
+        room = max(self._cap - len(sp), 0)
+        if room < len(tasks):
+            system.push_all(tasks[room:])
+            tasks = tasks[:room]
+        sp.push_all(tasks)
 
     def select(self, es: Any) -> tuple[Any | None, int]:
         sp = es.sched_private
         if sp is not None:
-            t = sp.try_pop_best(priority=_task_priority)
+            t = sp.pop()
             if t is not None:
                 return t, 0
         # steal from sibling streams in the same VP (never across VPs)
@@ -112,11 +116,24 @@ class LFQModule(SchedulerModule):
             t = sib.sched_private.steal()
             if t is not None:
                 return t, 1
-        vpq = es.virtual_process.sched_private
-        with vpq.lock:
-            if vpq.system:
-                return vpq.system.popleft(), 99
-        return None, 0
+        t = es.virtual_process.sched_private.system.pop()
+        return (t, 99) if t is not None else (None, 0)
+
+    def select_class(self, es: Any, task_class: Any, want: int
+                     ) -> tuple[list[tuple[Any, int]], int]:
+        """The class's bucket of each queue ``select`` would reach, in its
+        order; no task of another class leaves its queue."""
+        vp = es.virtual_process
+        queues = [(es.sched_private, 0)]
+        queues += [(s.sched_private, 1) for s in vp.execution_streams
+                   if s is not es]
+        queues.append((vp.sched_private.system, 99))
+        taken: list[tuple[Any, int]] = []
+        for q, distance in queues:
+            if q is not None and len(taken) < want:
+                taken += [(t, distance) for t in
+                          q.pop_class(task_class, want - len(taken))]
+        return taken, 0
 
     def remove(self, context: Any) -> None:
         for vp in context.virtual_processes:
@@ -370,7 +387,7 @@ class PBQModule(SchedulerModule):
     def install(self, context: Any) -> None:
         self._order: dict[int, list] = {}   # id(es) -> cached steal order
         for vp in context.virtual_processes:
-            vp.sched_private = _VPQueues()
+            vp.sched_private = _VPQueues(deque())
             # reference queue_size = 4 * vp->nb_cores — per VP
             vp.sched_private.cap = max(4, 4 * len(vp.execution_streams))
 
