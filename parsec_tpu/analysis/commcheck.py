@@ -35,10 +35,10 @@ Three consumers:
                                   its payload class
    =============================  =======================================
 
-2. the ``comm_pattern`` block in ``runtime_report()`` plus the bench
-   cross-check: ``bench.py comm_ranks`` compares these predictions
-   against the measured ``SocketFabric.peer_stats()`` ledger (the
-   static-vs-dynamic agreement gate, ≤15 % rel — docs/ANALYSIS.md);
+2. the ``comm_pattern`` block in ``runtime_report()`` plus the wire
+   cross-check: :func:`predict_collective_traffic` against the measured
+   ``SocketFabric.peer_stats()`` ledger (the static-vs-dynamic
+   agreement gate, ≤15 % rel — docs/ANALYSIS.md);
 3. :func:`recommend_tree`, feeding ``comm/collectives.py`` and
    ``data_dist/redistribute.py`` a per-edge-class tree shape —
    ``comm_bcast_tree=auto`` resolves through the same rule
@@ -658,21 +658,22 @@ def recommend_tree(report: CommReport) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# the bench cross-check twin (bench.py comm_ranks + perf_smoke gate)
+# the static twin of the wire ledger (tests/test_perf_smoke.py's agreement gate)
 # ---------------------------------------------------------------------------
 
 
 def predict_collective_traffic(nranks: int,
                                payload_bytes: int | None = None) -> dict:
     """Static prediction of the exact pools ``_mp_collective_body`` runs
-    (one broadcast of ``comm_coll_bench_bytes`` + one 64-element
+    (one broadcast of ``MP_COLLECTIVE_BYTES`` + one 64-element
     reduction over ``nranks`` ranks): total cross-rank payload bytes,
     the root's egress, and the per-edge-class breakdown — what the
     measured ``peer_stats()`` ledger is compared against."""
-    from ..comm.collectives import bcast_taskpool, reduce_taskpool
+    from ..comm.collectives import (MP_COLLECTIVE_BYTES, bcast_taskpool,
+                                    reduce_taskpool)
     from ..data_dist.matrix import VectorTwoDimCyclic
     nbytes = int(payload_bytes if payload_bytes is not None
-                 else _params.get("comm_coll_bench_bytes"))
+                 else MP_COLLECTIVE_BYTES)
     mb = max(nbytes // 4, 1)
     V = VectorTwoDimCyclic("V", lm=mb * nranks, mb=mb, P=nranks)
     crb = check_comm(bcast_taskpool(V, n=nranks), nb_ranks=nranks)

@@ -225,12 +225,16 @@ def reduce_taskpool(V: Any, OUT: Any, *, op: str = "sum", root: int = 0,
 
 
 # ---------------------------------------------------------------------------
-# multiproc bodies (bench.py comm_ranks sweep + the 8-rank acceptance test)
+# multiproc body (the 8-rank acceptance test + commcheck's agreement gate)
 # ---------------------------------------------------------------------------
+
+# payload of the body's broadcast tile: far past comm_short_limit, so the
+# tree carries rendezvous GETs and root egress is counted in payloads
+MP_COLLECTIVE_BYTES = 4 << 20
 
 
 def _mp_collective_body(ctx, rank, nranks):
-    """One broadcast of a ``comm_coll_bench_bytes`` tile + one tree
+    """One broadcast of a ``MP_COLLECTIVE_BYTES`` tile + one tree
     reduction, timed; returns per-rank latency, payload digests, and the
     socket fabric's per-peer traffic ledger so the parent can assert root
     egress stays O(children(root))."""
@@ -239,8 +243,7 @@ def _mp_collective_body(ctx, rank, nranks):
 
     from ..data_dist.matrix import VectorTwoDimCyclic
 
-    nbytes = int(_params.get("comm_coll_bench_bytes"))
-    mb = max(nbytes // 4, 1)                       # float32 elements
+    mb = MP_COLLECTIVE_BYTES // 4                  # float32 elements
     V = VectorTwoDimCyclic(
         "V", lm=mb * nranks, mb=mb, P=nranks, myrank=rank,
         init_fn=lambda m, size: (
@@ -275,8 +278,3 @@ def _mp_collective_body(ctx, rank, nranks):
     return {"rank": rank, "digest": digest, "bcast_s": bcast_s,
             "reduce_s": reduce_s, "reduce0": red, "peer_stats": stats,
             "tree": _params.get("comm_bcast_tree")}
-
-
-_params.register("comm_coll_bench_bytes", 4 << 20,
-                 "payload size of the comm_ranks collective sweep tile "
-                 "(also the 8-rank acceptance broadcast)")

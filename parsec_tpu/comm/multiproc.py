@@ -109,8 +109,7 @@ def _run_multiproc(nranks: int, target: str, timeout: float,
     from ..core.params import params as _p
     for name in ("comm_wire_binary", "comm_get_frag_bytes",
                  "comm_get_window", "comm_socket_buf_bytes",
-                 "comm_codec_pickle_fallback", "comm_bcast_tree",
-                 "comm_coll_bench_bytes"):
+                 "comm_codec_pickle_fallback", "comm_bcast_tree"):
         env.setdefault(f"PARSEC_MCA_{name}", str(_p.get(name)))
     # forward the autotuner consult knobs the same way: every rank of a
     # fabric must agree on WHETHER (and from which store) a persisted

@@ -30,7 +30,7 @@ u2) followed by a kind-specific body:
   ``recv_into``\\ s it directly — socket → destination tile, zero copies.
 
 ``comm_wire_binary=False`` falls back to the legacy length-prefixed-pickle
-framing (the measured baseline of ``microbench.bench_comm``); both ends of
+framing, which pickles every payload byte; both ends of
 a fabric must agree.  Topology: rank *i*
 listens on ``base_port + i``; outgoing connections are made lazily with
 connect-retry (peers boot in any order).  The host list defaults to

@@ -27,8 +27,8 @@ from ..data.data import ACCESS_WRITE
 # per-task (or vmapped-batch) dispatches (device/tpu.py) AND the lowered
 # paths' whole-program / per-region invocations (ptg/lowering.py) — bumps
 # ONE counter, so "XLA calls per DAG" is a single comparable axis across
-# execution modes (microbench.bench_lowering; the MPK ≥5x dispatch-drop
-# acceptance gate reads it).  A plain int under a lock: this is per
+# execution modes (tests/test_lowering_regions.py holds the count per
+# emission and the region path's ≥5x drop against task-per-dispatch).  A plain int under a lock: this is per
 # dispatch (≥ µs of enqueue work), not per task.
 # ---------------------------------------------------------------------------
 

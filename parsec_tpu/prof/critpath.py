@@ -18,15 +18,16 @@ an accounting identity, not a heuristic.  On top of the sweep:
   (``comm.get:4mib``), each carrying ``overlap_lost_ms`` — the part of
   the fragment's flight time NOT hidden behind task execution, i.e. the
   time fragment-granular release (the T3 item) could win back;
-- **overlap efficiency**: ``|exec ∪ ∩ get ∪| / |get ∪|`` — directly
-  comparable to microbench's measured ``comm_overlap_efficiency``;
+- **overlap efficiency**: ``|exec ∪ ∩ get ∪| / |get ∪|`` — the share of
+  a GET's flight time spent inside task execution, which a consumer can
+  also accumulate inline (``tests/test_perf_smoke.py`` holds the two
+  readings of one run together);
 - **DAG critical path**: longest-cost chain over graphcheck's retained
   ``(class, key) -> successors`` graph, weighted by measured per-class
   exec means.
 
 Everything here is ANALYSIS-time: the module consumes existing spans
-and adds zero hot-path sites (the perf_smoke gate pins both that and
-replay latency).  Surfaces: this CLI (``python -m
+and adds zero hot-path sites (``tests/test_perf_smoke.py`` pins that).  Surfaces: this CLI (``python -m
 parsec_tpu.prof.critpath <chrome-trace-or-spans.json>``, with
 ``--self-test``), the ``critpath`` block in ``runtime_report()``, a
 :mod:`~parsec_tpu.prof.dashboard` panel, and cross-rank attribution

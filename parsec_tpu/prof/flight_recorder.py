@@ -24,8 +24,8 @@ survive a wedged run:
 - **Unified export** — :func:`export_run_report` merges ring events,
   the counter series, and the binary :mod:`profiling
   <parsec_tpu.prof.profiling>` streams into one Chrome trace + JSON
-  summary; :func:`runtime_report` is the compact per-stage block
-  ``bench.py`` embeds in every ``BENCH_*.json`` stage.
+  summary; :func:`runtime_report` is the compact block of the same
+  counters, cumulative since process start.
 
 See ``docs/OBSERVABILITY.md`` for the operator-facing guide.
 """
@@ -457,8 +457,7 @@ def stall_dump(context: Any = None, reason: str = "", last: int = 32,
 # ---------------------------------------------------------------------------
 
 def runtime_report(max_workers: int = 6) -> dict:
-    """Compact runtime self-measurement (cumulative since process start):
-    the block ``bench.py`` embeds in every stage of ``BENCH_*.json``.
+    """Compact runtime self-measurement (cumulative since process start).
 
     ``tasks_retired`` is the TOTAL (dynamic + compiled-DAG), matching the
     snapshotter's counter track so the two halves of one run report can
@@ -488,8 +487,8 @@ def runtime_report(max_workers: int = 6) -> dict:
     # answers "under WHICH configuration was this measured" — the
     # provenance the tuning DB and the perf ledger key on.  Defaults
     # are derivable from the code version, so omitting them keeps the
-    # report inside its compactness contract.  Nested, so note_result's
-    # scalar walk never mistakes a knob for a measurement.  Precedes
+    # report inside its compactness contract.  Nested, so a walk over the
+    # report's scalars never mistakes a knob for a measurement.  Precedes
     # the flightrec-disabled early return: a report always carries it.
     def _knobs():
         from ..core.params import params as _p

@@ -5,8 +5,7 @@ The latency side of the tracing layer (docs/OBSERVABILITY.md): a
 (``bounds[i] = lo * growth**i``), so
 
 - ``record`` is O(1) — one ``log``, one index, one increment — cheap
-  enough for per-token serving paths (gated in ``microbench
-  .bench_tracing``);
+  enough for per-token serving paths;
 - quantiles carry a **bounded relative error**: a reported quantile is
   the geometric midpoint of its bucket, so it is within a factor
   ``sqrt(growth)`` of the true empirical quantile (≈ ±9% at the default
@@ -15,8 +14,8 @@ The latency side of the tracing layer (docs/OBSERVABILITY.md): a
   commutative, so per-rank / per-stage histograms combine without loss
   (property-tested in tests/test_tracing.py);
 - ``to_dict``/``from_dict`` serialize the sparse bucket array, which is
-  what ``bench.py._note_partial`` flushes so a deadline death mid-stage
-  keeps the latency *distribution* collected so far, not just counters.
+  what a sharded server's ranks ship to rank 0 to be merged
+  (``serve/sharded.py``): the latency *distribution*, not just counters.
 
 :class:`SLOPlane` is the per-tenant metrics surface over it: named
 histograms keyed ``(tenant, metric)`` plus plain counters.  Every plane
@@ -223,12 +222,12 @@ class SLOPlane:
     def summary(self, quantiles: Iterable[float] = (0.5, 0.99)) -> dict:
         """``{tenant: {"<metric>_p50": v, "<metric>_p99": v,
         "<metric>_count": n, "<counter>": n}}`` — the block
-        ``RuntimeServer.metrics()`` and the bench emits surface."""
+        ``RuntimeServer.metrics()`` surfaces."""
         return _summarize(self.items(), list(self.counters().items()),
                           quantiles)
 
     def to_dict(self) -> dict:
-        """Serialized bucket arrays (the ``_note_partial`` flush form):
+        """Serialized bucket arrays:
         ``{tenant: {metric: hist.to_dict()}}`` plus ``_counters``."""
         return _serialize(self.items(), list(self.counters().items()))
 
@@ -239,10 +238,8 @@ class SLOPlane:
 
 
 def _serialize(items, counters) -> dict:
-    """The ONE statement of the serialized-plane shape — per-plane dumps
-    (``SLOPlane.to_dict``) and the bench partial flush
-    (:func:`serialized_planes`) must never diverge, or
-    ``LogHistogram.from_dict`` round-trips break for one of them."""
+    """The serialized-plane shape (``SLOPlane.to_dict``), which
+    ``LogHistogram.from_dict`` and ``serve/sharded.py``'s merge read."""
     out: dict[str, Any] = {}
     for (tenant, metric), h in items:
         out.setdefault(tenant, {})[metric] = h.to_dict()
@@ -291,10 +288,3 @@ def merged_summary(quantiles: Iterable[float] = (0.5, 0.99)) -> dict:
     items, counters = _merged()
     return _summarize(items, counters, quantiles)
 
-
-def serialized_planes() -> dict:
-    """Serialized bucket arrays across every live plane — what
-    ``bench.py._note_partial`` flushes mid-stage (empty dict when no
-    plane holds data)."""
-    items, counters = _merged()
-    return _serialize(items, counters)

@@ -6,27 +6,23 @@ signature**, the ``(jax version, backend, device kind)`` triple
 (:func:`parsec_tpu.ptg.lowering._backend_signature`), and an explicit
 **knob vector** — so a number is only ever compared against its own
 configuration class, never a different machine's or a different tile
-size's.  ``bench.py`` appends every stage's scalars and
-``microbench.run_all`` appends its result; the file accrues across runs
-(``$PARSEC_TPU_ARTIFACT_DIR/perfdb.jsonl`` by default) and becomes both
-the regression sentinel the bench trajectory lacked (r04/r05 died with
-the BENCH_* trend tracked by hand) and the objective-function substrate
-the ROADMAP's autotuning item needs.
+size's.  The autotuner (``tune/search.py``) appends one record per trial
+and reads a vector's history back to prune it; the file accrues across
+runs (``$PARSEC_TPU_ARTIFACT_DIR/perfdb.jsonl`` by default).
 
 Drift detection is an EWMA per key: :meth:`PerfDB.check` folds the
 key's history into an exponentially-weighted mean + variance and
 verdicts the new value ``ok`` / ``regressed`` / ``improved`` with a
 z-score.  The variance floor is relative (5% of the mean), so steady
 history does not manufacture infinite z-scores: a 5% wobble stays
-``ok`` while a 10x cliff is unmissable (the perf_smoke gate pins
-exactly that pair).  Direction comes from the metric name
+``ok`` while a 10x cliff is unmissable (``tests/test_perf_smoke.py``
+pins exactly that pair).  Direction comes from the metric name
 (:func:`better_of`): ``*_us``/``*_ms``/``*_s``/latency-like metrics
 regress UP, throughput-like metrics regress DOWN.
 
 ::
 
-    python -m parsec_tpu.prof.perfdb --ingest BENCH_r01.json ...
-    python -m parsec_tpu.prof.perfdb --history bench.comm
+    python -m parsec_tpu.prof.perfdb --history tune.<signature>
     python -m parsec_tpu.prof.perfdb --self-test
 
 MCA knobs: ``perfdb`` (0 disables every append), ``perfdb_path``
@@ -45,9 +41,9 @@ from typing import Iterable
 from ..core.params import params as _params
 
 _params.register("perfdb", True,
-                 "append bench/microbench perf scalars to the JSONL "
-                 "perf ledger and run the EWMA drift sentinel over "
-                 "them (0 = no ledger writes, no sentinel)")
+                 "append autotuner trials to the JSONL perf ledger and "
+                 "prune candidates by their EWMA history (0 = no "
+                 "ledger writes, no pruning)")
 _params.register("perfdb_path", "",
                  "perf ledger location (default: "
                  "$PARSEC_TPU_ARTIFACT_DIR/perfdb.jsonl, else "
@@ -115,9 +111,7 @@ def make_key(workload: str, metric: str, backend: list | None = None,
 
 class PerfDB:
     """One ledger file.  ``append`` writes a record; ``check`` verdicts
-    a value against the key's EWMA history; ``append_and_check`` does
-    both in the order a sentinel wants (check against history BEFORE
-    this run's own sample joins it)."""
+    a value against the key's EWMA history."""
 
     def __init__(self, path: str | None = None) -> None:
         self.path = path or default_path()
@@ -206,13 +200,6 @@ class PerfDB:
         return {"verdict": verdict, "z": round(z, 2), "n": n,
                 "ewma": round(m, 6)}
 
-    def append_and_check(self, key: str, value: float, *,
-                         unit: str | None = None, run: str | None = None,
-                         better: str | None = None) -> dict:
-        out = self.check(key, value, better=better)
-        self.append(key, value, unit=unit, run=run)
-        return out
-
     # -- trial provenance (the autotuner hook) ---------------------------
     def note_trial(self, workload: str, objective: str, value: float, *,
                    knobs: dict | None = None, meta: dict | None = None,
@@ -224,123 +211,13 @@ class PerfDB:
         key = make_key(workload, objective, backend=backend, knobs=knobs)
         return self.append(key, float(value), run="tune", meta=meta)
 
-    # -- bulk note (the bench / microbench hook) -------------------------
-    def note_result(self, workload: str, result: dict, *,
-                    knobs: dict | None = None, run: str | None = None,
-                    backend: list | None = None) -> list[dict]:
-        """Append every finite scalar of ``result`` under
-        ``workload``/metric keys and verdict each against its history.
-        Returns one entry per metric: {metric, key, value, verdict, z}.
-        Nested dicts are skipped (bench stages nest runtime_report /
-        sweeps; their scalars are not stage headlines) — except that a
-        ``partial`` block's scalars ARE walked: a deadline-dead stage's
-        flushed metrics still reach the ledger."""
-        out: list[dict] = []
-        be = backend if backend is not None else backend_signature()
-        items = list(result.items())
-        part = result.get("partial")
-        if isinstance(part, dict):
-            items += [(f"partial.{k}", v) for k, v in part.items()]
-        for metric, value in items:
-            if isinstance(value, bool) or not isinstance(value,
-                                                         (int, float)):
-                continue
-            if not math.isfinite(float(value)):
-                continue
-            if metric in ("ts",) or metric.startswith("_"):
-                continue
-            key = make_key(workload, metric, backend=be, knobs=knobs)
-            v = self.append_and_check(key, float(value), run=run)
-            out.append({"metric": metric, "workload": workload,
-                        "key": key, "value": float(value), **v})
-        return out
-
-
-# ---------------------------------------------------------------------------
-# backfill: import existing BENCH_* / MULTICHIP_* artifacts
-# ---------------------------------------------------------------------------
-
-def _scalars(d: dict) -> dict:
-    return {k: float(v) for k, v in d.items()
-            if isinstance(v, (int, float)) and not isinstance(v, bool)
-            and math.isfinite(float(v))}
-
-
-def ingest(paths: list[str], db: PerfDB | None = None) -> dict:
-    """Backfill the ledger from existing run artifacts so the sentinel
-    starts with r01-r05 history instead of a cold EWMA.
-
-    Accepts the repo-root artifact shapes: ``BENCH_r*.json`` (a wrapper
-    whose ``parsed`` field is the bench emit line — or the emit line
-    itself), and ``MULTICHIP_r*.json`` (ingested only when ``ok``).
-    The backend triple is the CURRENT process signature with the device
-    kind replaced by the artifact's recorded ``device_kind`` — a future
-    run on the same device class and jax build lands on the same keys,
-    which is the whole point of warming them."""
-    db = db or PerfDB()
-    imported = skipped = 0
-    for path in paths:
-        run = os.path.basename(path).rsplit(".", 1)[0]
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"[perfdb] {path}: unreadable ({e}) — skipped",
-                  file=sys.stderr)
-            skipped += 1
-            continue
-        line = doc.get("parsed") if isinstance(doc.get("parsed"), dict) \
-            else (doc if "metric" in doc else None)
-        if line is None:
-            if doc.get("ok") is False or doc.get("rc", 0) != 0:
-                print(f"[perfdb] {path}: failed run (rc="
-                      f"{doc.get('rc')}) — skipped", file=sys.stderr)
-                skipped += 1
-                continue
-            print(f"[perfdb] {path}: no parsed emit line — skipped",
-                  file=sys.stderr)
-            skipped += 1
-            continue
-        extra = line.get("extra") or {}
-        be = backend_signature()
-        kind = extra.get("device_kind")
-        if kind:
-            be = be[:2] + [kind]
-        n = 0
-        # the headline metric
-        if isinstance(line.get("value"), (int, float)):
-            db.append(make_key("bench.gemm",
-                               line.get("metric", "headline"),
-                               backend=be,
-                               knobs={"n": extra.get("n"),
-                                      "nb": extra.get("nb")}),
-                      float(line["value"]), unit=line.get("unit"),
-                      run=run)
-            n += 1
-        # flat extra scalars ride as workload "bench"; nested stage
-        # namespaces (overhead/comm/serve/llm/...) as "bench.<ns>" —
-        # the same workload names the live bench append uses
-        for k, v in _scalars(extra).items():
-            db.append(make_key("bench", k, backend=be), v, run=run)
-            n += 1
-        for ns, sub in extra.items():
-            if isinstance(sub, dict) and ns != "runtime_reports":
-                for k, v in _scalars(sub).items():
-                    db.append(make_key(f"bench.{ns}", k, backend=be),
-                              v, run=run)
-                    n += 1
-        print(f"[perfdb] {path}: {n} scalars ingested as run {run!r}")
-        imported += 1
-    return {"files": imported, "skipped": skipped,
-            "records": len(db.records()), "path": db.path}
-
 
 # ---------------------------------------------------------------------------
 # self-test (scripts/check.sh gate)
 # ---------------------------------------------------------------------------
 
 def self_test() -> int:
-    """The sentinel round-trip the perf_smoke gate also pins: steady
+    """The sentinel round-trip ``tests/test_perf_smoke.py`` also pins: steady
     history + 5% noise stays ok; a 10x cliff is flagged in BOTH
     directions; histories accrue across PerfDB instances (two
     'invocations' of one file)."""
@@ -369,15 +246,7 @@ def self_test() -> int:
         # cold keys warm silently
         k_new = make_key("selftest", "fresh_metric")
         assert db2.check(k_new, 5.0)["verdict"] == "warming"
-        # note_result walks scalars (partial included) and skips nests
-        notes = db2.note_result("selftest.stage",
-                                {"gflops": 3.0, "runtime_report": {"x": 1},
-                                 "partial": {"compile_s": 2.0},
-                                 "label": "str-skipped"})
-        assert {e["metric"] for e in notes} == \
-            {"gflops", "partial.compile_s"}, notes
-        n0 = len(db2.records())     # 16 loop appends + 2 note_result
-        assert n0 == 16 + 2, n0
+        assert len(db2.records()) == 16
     print("perfdb self-test: ok (EWMA sentinel: 5% noise ok, 10x cliff "
           "flagged both directions, cross-instance accrual)")
     return 0
@@ -409,16 +278,6 @@ def main(argv: list[str] | None = None) -> int:
             m, sd, n = PerfDB._ewma(vals)
             print(f"{workload}/{metric}: n={n} ewma={m:.4g} sd={sd:.3g} "
                   f"last={vals[-1]:.4g}")
-        return 0
-    if "--ingest" in argv:
-        argv.remove("--ingest")
-        if not argv:
-            print(__doc__, file=sys.stderr)
-            return 2
-        stats = ingest(argv, PerfDB(path))
-        print(f"perfdb: {stats['files']} artifacts ingested "
-              f"({stats['skipped']} skipped) -> {stats['path']} "
-              f"({stats['records']} records)")
         return 0
     print(__doc__, file=sys.stderr)
     return 2

@@ -170,7 +170,7 @@ class _TaskSpans:
             pins.unregister(ev, cb)
 
     # every callback body is tuned for the enabled-cost budget (≤1µs/
-    # task target, bench_tracing measures it): default-arg bindings for
+    # task target): default-arg bindings for
     # the clock and the record method, try/except thread-local fast
     # paths, and string args instead of per-span dicts
 
@@ -247,8 +247,8 @@ def install(max_spans: int | None = None,
             recorder_obj: SpanRecorder | None = None) -> SpanRecorder:
     """Install the span recorder + the PINS task-span chains.
     ``recorder_obj`` re-installs an EXISTING recorder (spans and
-    capacity preserved) — how bench_tracing restores a user-installed
-    recorder after its disabled-path measurement."""
+    capacity preserved) — how a measurement that needs the recorder off
+    hands a user-installed one back afterwards."""
     global recorder, _task_spans
     if recorder is not None:
         return recorder

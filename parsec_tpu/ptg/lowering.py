@@ -1700,8 +1700,8 @@ class RegionLoweredTaskpool:
         ``lowering_region_max_tasks`` is what bounds the worst single
         compile.  Cache hits are free and never shed — a warm process
         compiles nothing.  ``note(**kw)`` receives one progress record
-        per region (the bench harness forwards these to ``_note_partial``
-        so a deadline death names which region was compiling)."""
+        per region (so a caller under a deadline can name the region that
+        was compiling when it died)."""
         import jax
         ensure_compile_cache()
         if budget_s is None:

@@ -464,7 +464,7 @@ class Context:
                 self._drive_until(self.test, timeout)
             except ContextWaitTimeout:
                 # tear down abort-style below; dump only if a timed-out
-                # wait() didn't already (bench's finally re-enters with
+                # wait() didn't already (a caller's finally re-enters with
                 # the expired deadline — one diagnosis per stall, not two)
                 if self.last_stall_report is None:
                     self._stall_dump(

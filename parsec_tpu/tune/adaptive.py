@@ -21,9 +21,9 @@ A converged controller writes its value back to the tuning DB
 (``ambient:tenant:<t>``), where the next server's per-tenant consult
 starts from it.
 
-MCA knob: ``tune_adaptive`` (default OFF — the k sweep in microbench
-and any explicit ``llm_steps_per_pool`` setting must stay authoritative
-unless the operator opts in).
+MCA knob: ``tune_adaptive`` (default OFF — an explicit
+``llm_steps_per_pool`` setting, a test's k sweep among them, must stay
+authoritative unless the operator opts in).
 """
 
 from __future__ import annotations

@@ -43,7 +43,7 @@ else
     python -m parsec_tpu.prof.critpath --self-test
 
     echo "== perfdb (perf ledger + regression sentinel: EWMA verdicts," \
-         "note_result walk, backfill ingest) =="
+         "cross-instance accrual) =="
     python -m parsec_tpu.prof.perfdb --self-test
     python -m pytest tests/test_critpath.py tests/test_perf_smoke.py -q \
         -k "perfdb or critpath" -p no:cacheprovider
@@ -53,16 +53,12 @@ else
          "consult) =="
     python -m parsec_tpu.tune --self-test
     python -m pytest tests/test_tune.py -q -p no:cacheprovider
-    python -m pytest tests/test_perf_smoke.py -q -k tune \
-        -p no:cacheprovider
 
-    echo "== tracing overhead gate (disabled span path within 10% of" \
-         "the overhead baseline; allocation-free; enabled <=1us budget" \
-         "at headroom) =="
-    python -m pytest tests/test_perf_smoke.py -q -k tracing \
-        -p no:cacheprovider
+    echo "== tracing overhead gate (uninstalled, the recorder holds no" \
+         "PINS chain and the phase plane builds nothing; installed, n" \
+         "tasks leave n exec and n release spans; allocation-free) =="
     python -m pytest tests/test_tracing.py -q \
-        -k "allocation_free" -p no:cacheprovider
+        -k "tracing_overhead or allocation_free" -p no:cacheprovider
 
     echo "== prefix-cache trie unit tests (radix tree vs the brute-force" \
          "LCP oracle + LRU/byte-budget eviction + CoW pin semantics) =="
@@ -83,15 +79,16 @@ else
     python -m pytest tests/test_serve_sharded.py -q \
         -k "oracle_equal_and_metrics_merge" -p no:cacheprovider
 
-    echo "== llm microbench (smoke: tokens/s through the serving stack," \
-         "swept over llm_steps_per_pool — superpool amortization) =="
-    python -c 'import json, microbench; \
-print(json.dumps(microbench.bench_llm(smoke=True)))'
+    echo "== llm decode superpools (submits per token swept over" \
+         "llm_steps_per_pool and streams, tokens oracle-equal) =="
+    python -m pytest tests/test_llm.py -q -k "superpool_pays" \
+        -p no:cacheprovider
 
-    echo "== lowering microbench (XLA calls per DAG: dispatch/region/" \
-         "wavefront/chain + compile seconds) =="
-    python -c 'import json, microbench; \
-print(json.dumps(microbench.bench_lowering(smoke=True)))'
+    echo "== lowering (XLA calls per DAG by emission: chain/wavefront/" \
+         "unrolled/regions/scan; the region path's drop against" \
+         "task-per-dispatch; a warm plan compiles nothing) =="
+    python -m pytest tests/test_lowering.py tests/test_lowering_regions.py \
+        -q -k "xla_call" -p no:cacheprovider
 fi
 
 echo "check.sh: all stages green"

@@ -71,3 +71,24 @@ def param():
     yield set_
     for name, value in saved.items():
         params.set(name, value)
+
+
+_compile_requests = {"n": None}      # None until the listener is registered
+
+
+@pytest.fixture
+def compile_requests():
+    """A callable giving the XLA compile requests this process has made so
+    far (a persistent-cache hit is a request too), counted on
+    ``jax.monitoring`` as the benchmark's ``CompileMeter`` counts them: a
+    warm path is one that makes none."""
+    if _compile_requests["n"] is None:
+        import jax.monitoring as mon
+
+        def on_duration(event, _secs, **_kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                _compile_requests["n"] += 1
+
+        _compile_requests["n"] = 0
+        mon.register_event_duration_secs_listener(on_duration)
+    return lambda: _compile_requests["n"]

@@ -15,7 +15,8 @@ import pytest
 
 from parsec_tpu.analysis import check_ptg
 from parsec_tpu.comm import run_multirank, run_multiproc
-from parsec_tpu.comm.collectives import (bcast_taskpool, reduce_op,
+from parsec_tpu.comm.collectives import (MP_COLLECTIVE_BYTES,
+                                         bcast_taskpool, reduce_op,
                                          reduce_taskpool,
                                          register_reduce_op)
 from parsec_tpu.core.params import params
@@ -145,7 +146,7 @@ def test_reduce_multirank_matches_numpy(nranks):
 
 def test_bcast_8rank_multiproc_root_egress_logn():
     nranks = 8
-    payload = int(params.get("comm_coll_bench_bytes"))     # 4 MiB
+    payload = MP_COLLECTIVE_BYTES                          # 4 MiB
     res = run_multiproc(
         nranks, "parsec_tpu.comm.collectives:_mp_collective_body",
         timeout=300, nb_cores=1)
@@ -204,7 +205,7 @@ def test_bcast_4rank_auto_tree_root_egress_bounded():
     payloads, vs star's worst-case 3; the wire must never carry the
     literal "auto" (every rank's resolved tree is concrete)."""
     nranks = 4
-    payload = int(params.get("comm_coll_bench_bytes"))     # 4 MiB
+    payload = MP_COLLECTIVE_BYTES                          # 4 MiB
     saved = params.get("comm_bcast_tree")
     params.set("comm_bcast_tree", "auto")
     try:

@@ -76,6 +76,26 @@ class TestVectorPath:
         run_pool(ep_pool(trace=trace), nb_cores=2)
         assert len(trace) == 40
 
+    def test_the_dispatch_shape_never_asks_the_scheduler(self, monkeypatch):
+        """2,000 tasks of the EP shape (50 lanes x 40) take the compiled-DAG
+        path whole: the pool carries its compiled DAG, every task runs once,
+        and the scheduler module is neither asked for a task nor handed
+        one."""
+        trace = []
+        tp = ep_pool(50, 40, trace)
+        ctx = Context(nb_cores=0)
+        asked = []
+        mod = type(ctx.scheduler)
+        for name in ("select", "schedule"):
+            monkeypatch.setattr(mod, name, lambda *a, _n=name, **kw:
+                                asked.append(_n))
+        ctx.add_taskpool(tp)
+        assert tp._compiled_dag is not None
+        ctx.wait(timeout=60)
+        ctx.fini()
+        assert len(trace) == 2000 == len(set(trace))
+        assert asked == []
+
     def test_matches_dynamic(self, dynamic_only):
         trace = []
         run_pool(ep_pool(trace=trace), nb_cores=0)

@@ -406,6 +406,8 @@ def test_the_64x64_cholesky_dag_stays_inside_a_budget_of_2600_tiles(dev):
     and never holds more; nothing has to be evicted."""
     gap, peak_tiles, triangle = _cholesky_under_budget(dev, 4096, 64, 29, 2600)
     assert dev.executed_tasks == 45760 and triangle == 2080
+    # the budget leaves the batches alone: the chip's count, call for call
+    assert dev.xla_calls == 1868
     assert gap < 2e-6, gap
     assert peak_tiles <= 2600, peak_tiles
     # unbounded, the ring's peak reads 2,000 tiles on this graph

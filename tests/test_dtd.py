@@ -215,3 +215,47 @@ def test_priority_hint(ctx):
     t = tp.insert_task(inc, (a, INOUT), priority=7)
     tp.wait()
     assert t.priority == 7 and t.completed
+
+
+def test_dtd_gemm_runs_on_the_device_module_and_reads_back_synchronously(
+        accel_device):
+    """The second front end through the same ``TPUDevice`` (ROADMAP A7): 64
+    GEMM tasks inserted at run time go to the accelerator in 4 fused calls,
+    48 input tiles are staged once, and since DTD's ``PUSHOUT`` is not
+    wired no result tile is pushed out early: the flush reads all 16 back."""
+    import parsec_tpu.ops.gemm  # noqa: F401 — registers the "gemm" kernels
+    NT, nb = 4, 8
+    rng = np.random.default_rng(5)
+
+    def tiles(make):
+        return [[make() for _ in range(NT)] for _ in range(NT)]
+
+    A = tiles(lambda: rng.standard_normal((nb, nb), dtype=np.float32))
+    B = tiles(lambda: rng.standard_normal((nb, nb), dtype=np.float32))
+    C = tiles(lambda: np.zeros((nb, nb), np.float32))
+
+    def gemm(a, b, c):          # the CPU incarnation, not taken here
+        c += a @ b
+
+    dev = accel_device
+    ctx = Context(nb_cores=0)
+    tp = DTDTaskpool()
+    ctx.add_taskpool(tp)
+    for m in range(NT):
+        for n in range(NT):
+            for k in range(NT):
+                tp.insert_task(gemm, (A[m][k], INPUT), (B[k][n], INPUT),
+                               (C[m][n], INOUT), tpu_kernel="gemm")
+    tp.wait()
+    dev.sync()
+    dev.flush_cache()
+    for m, n in ((0, 0), (1, 2), (3, 3)):
+        got = np.asarray(tp.tile_of_array(C[m][n]).data.newest_copy().value)
+        np.testing.assert_allclose(
+            got, sum(A[m][k] @ B[k][n] for k in range(NT)),
+            rtol=1e-3, atol=1e-4)
+    ctx.fini()
+    assert (dev.executed_tasks, dev.xla_calls) == (NT ** 3, 4)
+    assert dev.bytes_in == 3 * NT * NT * nb * nb * 4
+    assert (dev.pushouts, dev.writebacks_early) == (0, 0)
+    assert dev.writebacks == NT * NT and dev.bytes_out == NT * NT * nb * nb * 4

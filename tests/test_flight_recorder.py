@@ -258,7 +258,7 @@ def test_wait_timeout_raises_typed_and_dumps(tmp_path, param, capsys):
 def test_fini_bounded_drain_aborts_instead_of_hanging(tmp_path, param):
     """fini(timeout=...) on a wedged pool falls through to abort-style
     teardown within the bound instead of blocking forever (ADVICE r5:
-    bench.py's 'finally: ctx.fini()' hung in exactly this case)."""
+    a caller's 'finally: ctx.fini()' hung in exactly this case)."""
     param("runtime_dag_compile", False)
     param("prof_flightrec_dir", str(tmp_path))
     ev = threading.Event()
@@ -372,6 +372,12 @@ def test_export_run_report_roundtrip_chrome(tmp_path, param):
 
 
 def test_runtime_report_is_json_serializable_and_compact():
+    # the report merges the SLO planes of every LIVE server; servers an
+    # earlier test of this process drained hang in cyclic garbage until the
+    # collector runs, and their tenants would be counted (8.9 kB after the
+    # LLM files with the collector off)
+    import gc
+    gc.collect()
     rep = runtime_report()
     s = json.dumps(rep)
     assert len(s) < 4096

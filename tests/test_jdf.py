@@ -76,7 +76,7 @@ END
 
 
 def test_ep_jdf_ctl_only():
-    """The scheduler microbenchmark shape (tests/runtime/scheduling/ep.jdf):
+    """The scheduler micro-benchmark shape (tests/runtime/scheduling/ep.jdf):
     CTL-only DAG, NT independent depth-DEPTH chains."""
     V = VectorTwoDimCyclic("V", lm=4, mb=1, P=1,
                            init_fn=lambda m, size: np.zeros(size))

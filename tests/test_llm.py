@@ -388,6 +388,28 @@ def test_stream_generation_matches_reference_token_for_token():
         assert stats["kv"]["physical_pages"] == 0
 
 
+@pytest.mark.parametrize("streams,k,submits", [(1, 8, 1), (4, 8, 4),
+                                               (4, 1, 32)])
+def test_a_superpool_pays_one_submit_for_k_tokens(param, streams, k,
+                                                  submits):
+    """The ISSUE-9 amortization as a count: under one tenant a stream (the
+    shape where no cross-stream batching hides a submit) 8 tokens cost one
+    decode pool at ``llm_steps_per_pool`` = 8 and eight at 1, whatever the
+    number of streams; every token the oracle's."""
+    param("llm_steps_per_pool", k)
+    prompts = [[(7 * i + 3 * j) % MODEL.vocab for j in range(8)]
+               for i in range(streams)]
+    with RuntimeServer(nb_cores=2) as server:
+        tks = [server.submit_stream(p, max_new_tokens=8, tenant=f"tenant{i}")
+               for i, p in enumerate(prompts)]
+        for p, tk in zip(prompts, tks):
+            assert tk.result(timeout=300)["tokens"] == \
+                MODEL.reference_generate(p, 8)
+        llm = server.stats()["llm"]
+    assert llm["tokens_generated"] == 8 * streams
+    assert llm["decode_submits"] == submits     # 1/8 a token, or 1
+
+
 def test_streams_join_and_leave_midflight_continuous_batching():
     """A late stream joins while earlier ones decode; short streams
     retire without stalling the batch — and everyone still matches the

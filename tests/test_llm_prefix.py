@@ -225,6 +225,38 @@ def test_trie_streams_match_oracle_mixed_hit_lengths(param):
         assert t["prefix_hits"] == 3 and t["prefix_pages_reused"] == 5
 
 
+@pytest.mark.parametrize("shared_streams", [5, 0], ids=["0.9", "0.0"])
+def test_shared_prefix_traffic_skips_its_prefill(param, shared_streams):
+    """The ISSUE-11 prefix-cache gate as counts: of six streams, those
+    that share the donor's 16-page system prompt (five: 0.9 of the
+    traffic) fork its pages and prefill only their tails, so four fifths
+    of the wave's prefill tokens are skipped; with nothing shared there is
+    nothing to hit.  A dead trie (no donation, no match, or forks that
+    prefill anyway) fails both by name."""
+    param("llm_prefix_cache", True)
+    shared = [(5 * i + 11) % MODEL.vocab for i in range(16 * 16)]
+    prompts = [shared + [(i + j) % MODEL.vocab for j in range(8)]
+               if i < shared_streams else
+               [(7 * i + 3 * j + 1) % MODEL.vocab
+                for j in range(len(shared) + 8)] for i in range(6)]
+    with RuntimeServer(nb_cores=2) as server:
+        donor = server.submit_stream(shared + [3], max_new_tokens=1)
+        donor.result(timeout=300)           # retires: donates the prefix
+        llm0 = server.stats()["llm"]
+        tks = [server.submit_stream(p, max_new_tokens=2) for p in prompts]
+        for p, tk in zip(prompts, tks):
+            assert tk.result(timeout=300)["tokens"] == \
+                MODEL.reference_generate(p, 2)
+        llm1 = server.stats()["llm"]
+    total = llm1["prefill_tokens_total"] - llm0["prefill_tokens_total"]
+    skipped = llm1["prefill_tokens_skipped"] - llm0["prefill_tokens_skipped"]
+    hits = llm1["kv"]["prefix_hits"] - llm0["kv"]["prefix_hits"]
+    # a prompt's last token is the first decode step's, not a prefill's
+    assert total == 6 * (len(shared) + 8 - 1)
+    assert (hits, skipped) == (shared_streams, shared_streams * len(shared))
+    assert (skipped / total >= 0.8) == bool(shared_streams)
+
+
 def test_trie_disabled_by_default_keeps_pr9_behavior():
     """llm_prefix_cache defaults OFF: no trie, no retained pages — the
     PR-6/9 contract (every page recycles at stream retirement) holds."""

@@ -17,13 +17,6 @@ from parsec_tpu.serve import RuntimeServer
 
 MODEL = ToyLM()
 
-# interactive decode gets a 4x fair share over the batch tenant; the
-# p99 bound is ~100x the unloaded per-token latency (~5ms on 2 CPU
-# workers) — loose enough for CI noise, tight enough that a fairness
-# regression that parks decode behind a whole factorization (hundreds
-# of ms per pool) trips it
-DECODE_P99_S_MAX = 1.0
-
 
 def _cholesky_pool(n=96, nb=32):
     from parsec_tpu.data_dist.matrix import SymTwoDimBlockCyclic
@@ -57,11 +50,9 @@ def test_decode_streams_isolated_from_batch_cholesky_tenant():
         th = threading.Thread(target=batch_client, daemon=True)
         th.start()
         try:
-            per_token = []
             for p, tk in zip(prompts, streams):
                 r = tk.result(timeout=300)
                 assert r["tokens"] == MODEL.reference_generate(p, 12), p
-                per_token += r["per_token_s"]
         finally:
             done.set()
             th.join(timeout=300)
@@ -71,11 +62,6 @@ def test_decode_streams_isolated_from_batch_cholesky_tenant():
         stats = server.stats()
         disp = stats["fair_dispatched"]
         assert disp.get("chat", 0) > 0 and disp.get("batch", 0) > 0, disp
-        # decode latency stayed bounded while the batch job ran
-        per_token.sort()
-        p99 = per_token[min(int(len(per_token) * 0.99),
-                            len(per_token) - 1)]
-        assert p99 <= DECODE_P99_S_MAX, (p99, stats)
         # WFQ virtual time favored chat 4:1: its decode superpools
         # completed despite the saturating batch tenant.  One pool now
         # carries llm_steps_per_pool tokens for the whole tenant batch

@@ -11,7 +11,8 @@ Covers the ISSUE-8 acceptance criteria on CPU:
 - XLA dispatches per DAG drop >= 5x vs task-per-dispatch;
 - a compile budget the plan cannot afford sheds regions to the eager
   path (the stage completes — no rc-124 death), while a warm second
-  plan reports compile_s <= 0.01 via the process lowering cache.
+  plan asks XLA for no compile: every region is a hit in the process
+  lowering cache.
 """
 
 import json
@@ -154,19 +155,41 @@ def test_region_cholesky_matches_eager_runtime(n, nb, max_tasks):
     np.testing.assert_allclose(got, expect, rtol=1e-3, atol=1e-4)
 
 
-def test_region_cholesky_xla_call_drop_vs_task_per_dispatch():
-    """ISSUE-8 acceptance: on the 4-class DAG the region path must issue
-    >= 5x fewer XLA dispatches than task-per-dispatch (one call per task
-    — the dynamic-path lower bound without vmapped batching)."""
-    n, nb = 160, 32                       # nt=5 -> 35 tasks
+@pytest.mark.parametrize("n,nb,ntasks", [(160, 32, 35), (128, 32, 20)],
+                         ids=["nt5", "nt4"])
+def test_region_cholesky_xla_call_drop_vs_task_per_dispatch(
+        accel_device, param, compile_requests, n, nb, ntasks):
+    """ISSUE-8 acceptance, both sides counted on the one process-wide
+    ledger: with vmapped batching off the dynamic device path makes one
+    XLA call a task, the region path at least 5x fewer, and a second,
+    structurally identical plan asks XLA for no compile."""
+    from parsec_tpu.device.device import xla_calls_total
     a = make_spd(n)
-    A = SymTwoDimBlockCyclic.from_dense("A", a.copy(), nb, nb)
-    plan = lower_regions(tiled_cholesky_ptg(A))
+
+    def chol(devices="auto"):
+        A = SymTwoDimBlockCyclic.from_dense("A", a.copy(), nb, nb)
+        return tiled_cholesky_ptg(A, devices=devices)
+
+    param("device_tpu_batch", False)
+    before = xla_calls_total()
+    with Context(nb_cores=0) as ctx:
+        ctx.add_taskpool(chol(devices="tpu"))
+        ctx.wait(timeout=120)
+        accel_device.sync()
+    dispatch_calls = xla_calls_total() - before
+    assert dispatch_calls == accel_device.executed_tasks == ntasks
+    plan = lower_regions(chol())
+    before = xla_calls_total()
     plan.execute()
     st = plan.stats()
-    assert st["ntasks"] == 35
-    assert st["xla_calls"] >= 1
-    assert st["ntasks"] / st["xla_calls"] >= 5.0, st
+    assert st["ntasks"] == ntasks
+    assert xla_calls_total() - before == st["xla_calls"] >= 1
+    assert dispatch_calls / st["xla_calls"] >= 5.0, st
+    requests = compile_requests()
+    warm = lower_regions(chol())
+    warm.compile()
+    assert warm.stats()["regions_compiled"] == st["regions_compiled"]
+    assert compile_requests() == requests
 
 
 def test_region_pool_passes_graphcheck():
@@ -231,21 +254,21 @@ def test_compile_budget_sheds_to_eager_and_still_completes():
     assert plan.stats()["eager_runs"] == len(data_regions)
 
 
-def test_compile_budget_warm_run_is_free():
-    """ISSUE-8 acceptance: a warm second run reports compile_s <= 0.01 —
-    cache hits are never shed, even under a budget no compile could fit."""
+def test_compile_budget_warm_run_is_free(compile_requests):
+    """ISSUE-8 acceptance: a warm second run compiles nothing — cache hits
+    are never shed, even under a budget no compile could fit."""
     _a, _A, plan = _fresh_chol_plan()
     plan.compile()                        # cold: pays trace + compile
     assert plan.stats()["regions_compiled"] > 0
     _a2, _A2, plan2 = _fresh_chol_plan()  # structurally identical
     notes = []
+    requests = compile_requests()
     st = plan2.compile(budget_s=1e-9,
                        note=lambda **kw: notes.append(kw))
     assert st["regions_eager"] == 0
     assert st["regions_compiled"] == plan.stats()["regions_compiled"]
-    assert st["compile_s"] <= 0.01, st
-    assert st["trace_s"] <= 0.01, st
-    assert all(n_.get("cached") for n_ in notes)
+    assert compile_requests() == requests
+    assert notes and all(n_.get("cached") for n_ in notes)
 
 
 def test_budget_staged_compile_is_ascending_and_sheds_monotonically():
@@ -508,10 +531,13 @@ def test_warm_cache_cli_llm_decode_k_workload(capsys):
     assert out["region"]["regions_eager"] == 0
 
 
-def test_warm_cache_traces_against_avals_without_executing():
+def test_warm_cache_traces_against_avals_without_executing(compile_requests):
     """warm_cache compiles AOT — collection tiles must stay untouched."""
     out = lowering.warm_cache("cholesky", n=96, nb=32, modes=("region",))
     assert out["region"]["regions_compiled"] >= 1
     # a second warm at the same geometry is a pure cache hit
+    requests = compile_requests()
     out2 = lowering.warm_cache("cholesky", n=96, nb=32, modes=("region",))
-    assert out2["region"]["compile_s"] <= 0.01, out2
+    assert out2["region"]["regions_compiled"] == \
+        out["region"]["regions_compiled"]
+    assert compile_requests() == requests

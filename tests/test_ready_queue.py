@@ -228,6 +228,24 @@ def the_default_select_class_serves_a_module_without_buckets(mod_cls):
 
 
 @case()
+def two_hundred_pushed_the_owner_pops_newest_the_thief_steals_oldest():
+    mod, _, (es0, es1) = _module(LFQModule, nstreams=2)
+    ts = [_T("ab"[i % 2], 0, i) for i in range(200)]
+    mod.schedule(es0, ts)
+    q = es0.sched_private
+    assert len(q) == 200 and len(es1.sched_private) == 0
+    assert [(t.name, d) for t, d in (mod.select(es0), mod.select(es1))] == [
+        (199, 0), (0, 1)]
+    # a class pop reads its own bucket and no other
+    others = q._buckets["a"]
+    before = list(others)
+    assert _names(mod.select_class(es0, "b", 3)[0]) == [197, 195, 193]
+    assert q._buckets["a"] is others and list(others) == before
+    assert len(q) == 195
+    assert _names(_drain(mod, es1))[:3] == [1, 2, 3]    # oldest first
+
+
+@case()
 def a_bare_queue_never_compares_tasks_and_counts_itself():
     q = ReadyQueue()
     ts = _tasks("a2 a2 b2 b2")
@@ -273,9 +291,11 @@ def _potrf(p, nb=8):
 
 
 def _solve_counted(dev, param, monkeypatch, sched, make, p):
-    """One solve under ``sched``; what the scheduler took in and handed out
-    and what reached the device by the hot loop."""
+    """One solve under ``sched`` on ``dev`` (one accelerator or several);
+    what the scheduler took in and handed out and what reached a device by
+    the hot loop."""
     from parsec_tpu.sched.api import SchedulerModule
+    devs = dev if isinstance(dev, tuple) else (dev,)
     param("sched", sched)
     tp, ntasks, result = make(p)
     ctx = Context(nb_cores=0)
@@ -298,22 +318,26 @@ def _solve_counted(dev, param, monkeypatch, sched, make, p):
     if sched == "lfq":      # the default's pops are its selects, counted above
         monkeypatch.setattr(mod, "select_class", counting(
             mod.select_class, "popped", lambda a, out: len(out[0])))
-    dev.kernel_scheduler = counting(
-        dev.kernel_scheduler, "hot", lambda a, out: 1)
+    for d in devs:
+        d.kernel_scheduler = counting(
+            d.kernel_scheduler, "hot", lambda a, out: 1)
     ctx.add_taskpool(tp)
     ctx.wait(timeout=600)
-    dev.sync()
-    dev.flush_cache()
+    for d in devs:
+        d.sync()
+        d.flush_cache()
     ctx.fini()
     got, expect = result()
     np.testing.assert_allclose(got, expect, rtol=1e-3, atol=1e-4)
-    assert dev.executed_tasks == ntasks     # each once: the result says so too
-    assert dev.flood_selected + n["hot"] == ntasks
+    # each once: the result says so too
+    assert sum(d.executed_tasks for d in devs) == ntasks
+    assert sum(d.flood_selected for d in devs) + n["hot"] == ntasks
     return n
 
 
 @pytest.mark.parametrize("make,p,ntasks,calls", [
-    (_potrf, 32, 5984, 308), (_gemm, 16, 4096, 64)], ids=["potrf32", "gemm16"])
+    (_potrf, 32, 5984, 308), (_gemm, 16, 4096, 64), (_potrf, 16, 816, 72)],
+    ids=["potrf32", "gemm16", "potrf16"])
 def test_the_flood_pops_one_task_for_each_it_runs(
         accel_device, param, monkeypatch, make, p, ntasks, calls):
     dev = accel_device
@@ -327,7 +351,9 @@ def test_the_flood_pops_one_task_for_each_it_runs(
     assert dev.xla_calls == calls
 
 
-@pytest.mark.parametrize("sched,p,ntasks", [("gd", 32, 5984), ("ap", 16, 816)])
+@pytest.mark.parametrize("sched,p,ntasks", [("gd", 32, 5984)] + [
+    (s, 16, 816) for s in ("ap", "spq", "ip", "rnd", "ll", "llp", "pbq",
+                           "ltq", "lhq")])
 def test_a_scheduler_without_buckets_still_floods_and_counts_its_put_backs(
         accel_device, param, monkeypatch, sched, p, ntasks):
     dev = accel_device
@@ -337,3 +363,93 @@ def test_a_scheduler_without_buckets_still_floods_and_counts_its_put_backs(
     # every put-back is one more push and one more pop of the same task
     assert n["popped"] == n["pushed"]
     assert n["popped"] - dev.flood_putbacks <= ntasks
+
+
+@pytest.mark.parametrize("make,p,tiles_in,tiles_out,hits,reads", [
+    (_gemm, 16, 3 * 256, 256, 8197, 3 * 4096),
+    (_potrf, 16, 136, 136, 2040, 16 + 2 * 120 + 2 * 120 + 3 * 560),
+    (_potrf, 32, 528, 528, 16368, 32 + 2 * 496 + 2 * 496 + 3 * 4960)],
+    ids=["gemm16", "potrf16", "potrf32"])
+def test_a_solve_stages_each_tile_once_and_pushes_each_result_out_once(
+        accel_device, device_registry, param, monkeypatch, make, p,
+        tiles_in, tiles_out, hits, reads):
+    """What a solve of a cell's graph moves (8 x 8 f32 tiles, 256 bytes
+    each): every input tile staged once (C's zeros too), the reads that hit
+    the device's cache, every result tile pushed out at its memory edge
+    and collected once by the flush, nothing evicted, no dispatch confirmed
+    early for room, and no task on the host CPU device."""
+    dev = accel_device
+    (host,) = [d for d in device_registry.devices if d.type == "cpu"]
+    host_tasks = host.executed_tasks
+    _solve_counted(dev, param, monkeypatch, "lfq", make, p)
+    assert dev.bytes_in == tiles_in * 256
+    # every read of a flow is one look-up in the device's cache
+    assert (dev.cache_hits, dev.cache_hits + dev.cache_misses) == (hits, reads)
+    assert dev.pushouts == dev.writebacks == dev.writebacks_early == tiles_out
+    assert dev.bytes_out == tiles_out * 256
+    assert (dev.evicted_bytes, dev.pressure_confirms, dev.evict_stuck) == (
+        0, 0, 0)
+    assert host.executed_tasks == host_tasks
+
+
+@pytest.mark.parametrize("make,p,completions,edges", [
+    (_gemm, 16, 3840, 3840), (_potrf, 16, 815, 2040),
+    (_potrf, 32, 5983, 16368)], ids=["gemm16", "potrf16", "potrf32"])
+def test_a_release_walks_each_edge_of_the_dag_once(
+        accel_device, param, monkeypatch, make, p, completions, edges):
+    """What ``sched.release`` does per cell graph (ROADMAP S2's yardstick):
+    every task with a successor is one ``release_many`` call, and the
+    records of all calls are the DAG's edges, each once (the flow reads that
+    no collection serves: the look-ups that hit in the test above)."""
+    from parsec_tpu.runtime.deps import DependencyTracking
+    n = {"calls": 0, "records": 0}
+    release_many = DependencyTracking.release_many
+
+    def counted(self, tp, records):
+        n["calls"] += 1
+        n["records"] += len(records)
+        return release_many(self, tp, records)
+
+    monkeypatch.setattr(DependencyTracking, "release_many", counted)
+    _solve_counted(accel_device, param, monkeypatch, "lfq", make, p)
+    assert (n["calls"], n["records"]) == (completions, edges)
+
+
+@pytest.mark.parametrize("make,p,ntasks,calls", [
+    (_potrf, 16, 816, 227), (_gemm, 8, 512, 64)], ids=["potrf16", "gemm8"])
+def test_a_batch_never_passes_device_tpu_batch_max(
+        accel_device, param, monkeypatch, make, p, ntasks, calls):
+    """Under a cap of 8 the regular graph runs in exactly ntasks / 8 calls
+    and the irregular one in the calls its arrival order gives."""
+    param("device_tpu_batch_max", 8)
+    _solve_counted(accel_device, param, monkeypatch, "lfq", make, p)
+    assert accel_device.executed_tasks == ntasks
+    assert accel_device.xla_calls == calls >= ntasks / 8
+
+
+def test_without_the_local_bound_the_flood_sees_strict_priority_order(
+        accel_device, param, monkeypatch):
+    """``sched_lfq_buffer_size`` keeps arrival order past the first 256
+    ready tasks, and that order fills the Cholesky's batches (PERF.md,
+    PR 30, finding 2): lifted, the 32-panel graph takes 311 calls where the
+    bounded queue takes 308."""
+    param("sched_lfq_buffer_size", 100000000)
+    _solve_counted(accel_device, param, monkeypatch, "lfq", _potrf, 32)
+    assert accel_device.executed_tasks == 5984
+    assert accel_device.flood_putbacks == 0
+    assert accel_device.xla_calls == 311
+
+
+def test_two_accelerators_hand_back_what_best_device_gives_the_other(
+        accel_device, device_registry, param, monkeypatch):
+    """One ``Context`` over two accelerators (ROADMAP A6): a flood pops its
+    class, keeps the tasks ``best_device`` gives its own chip and hands the
+    rest back, so both run their share and the put-backs are counted."""
+    import jax
+
+    from parsec_tpu.device.tpu import TPUDevice
+    devs = (accel_device, device_registry.add(TPUDevice(jax.devices()[1])))
+    n = _solve_counted(devs, param, monkeypatch, "lfq", _potrf, 16)
+    assert n["popped"] == n["pushed"]       # a put-back is a push and a pop
+    assert [d.executed_tasks for d in devs] == [479, 337]
+    assert [d.flood_putbacks for d in devs] == [272, 232]

@@ -147,3 +147,57 @@ def test_release_many_groups_take_one_path(param):
     assert store.releases == width     # every fan edge through the tier
     ctx.fini()
     assert len(log) == 2 * width
+
+
+def test_a_release_pays_one_lock_a_class_group_and_one_schedule_a_batch(
+        param, monkeypatch):
+    """2,000 tasks in 40 layers of 50, each releasing its two successors of
+    the one class: with ``runtime_dag_compile`` off every completion is one
+    ``release_many`` call, which takes the class array's lock once for both
+    records, and every batch that made something ready is one
+    ``schedule_tasks`` call."""
+    from parsec_tpu.runtime import scheduling
+    param("deps_storage", "index-array")
+    param("runtime_dag_compile", False)
+    layers, width = 40, 50
+    in_edges = {(d, n): [n, (n + 1) % width]
+                for d in range(1, layers) for n in range(width)}
+    log, lock = [], threading.Lock()
+    tp = _build_pool(in_edges, layers, width, log, lock)
+    ctx = Context(nb_cores=0)
+    n = {"release_many": 0, "records": 0, "ready_batches": 0, "locks": 0,
+         "schedule_calls": 0, "scheduled": 0}
+    release_many = ctx.deps.release_many
+    release_batch = ctx.deps._release_indexed_batch
+    schedule_tasks = scheduling.schedule_tasks
+
+    def counted_release(tp, records):
+        ready = release_many(tp, records)
+        n["release_many"] += 1
+        n["records"] += len(records)
+        n["ready_batches"] += bool(ready)
+        return ready
+
+    def counted_batch(*a):
+        n["locks"] += 1             # the class array's lock, once a call
+        return release_batch(*a)
+
+    def counted_schedule(es, tasks, distance=0):
+        n["schedule_calls"] += 1
+        n["scheduled"] += len(tasks)    # before one of them is kept hot
+        return schedule_tasks(es, tasks, distance)
+
+    monkeypatch.setattr(ctx.deps, "release_many", counted_release)
+    monkeypatch.setattr(ctx.deps, "_release_indexed_batch", counted_batch)
+    monkeypatch.setattr(scheduling, "schedule_tasks", counted_schedule)
+    ctx.add_taskpool(tp)
+    ctx.wait(timeout=120)
+    store = ctx.deps._index_store
+    ctx.fini()
+    assert len(log) == layers * width == len(set(log))
+    completions = (layers - 1) * width      # the last layer releases nothing
+    assert n["release_many"] == n["locks"] == completions
+    assert n["records"] == store.releases == 2 * completions
+    # the first layer is scheduled by the start-up, every other task here
+    assert n["schedule_calls"] == n["ready_batches"]
+    assert n["scheduled"] == completions

@@ -174,7 +174,7 @@ class TestBranchingAndGuards:
 
 class TestEP:
     """Embarrassingly-parallel CTL-only DAG (tests/runtime/scheduling/ep.jdf):
-    NT chains of DEPTH tasks — the dispatch-overhead microbenchmark."""
+    NT chains of DEPTH tasks — the dispatch-overhead shape."""
 
     def _build(self, NT, DEPTH, counter):
         p = ptg.PTGBuilder("ep", NT=NT, DEPTH=DEPTH)

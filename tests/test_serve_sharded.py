@@ -140,19 +140,21 @@ def test_dead_rank_streams_requeue_oracle_exact():
     oracle = MODEL.reference_generate(prompt, nmax)
 
     def frontend(srv, peers):
-        # a filler long enough to still load rank 0 when h is placed: under
-        # tier-1's six workers a 4-token one could finish between the two
-        # submits, and h then landed on rank 0 (ROADMAP D10)
+        # the precondition, stated and not raced for: rank 1 holds the
+        # stream, has shipped three of its twelve tokens and is dark.  Rank
+        # 1 ships nothing of its own from the start (a stream of twelve
+        # tokens can finish between two steps of a loaded frontend, and a
+        # finished stream is not requeued); the three tokens arrive through
+        # the real handler, as a delta from rank 1 would.
+        peers[1].zombie = True
         filler = srv.submit_stream([2, 4], max_new_tokens=nmax)  # rank 0
+        # nothing steps between the two submits: rank 0 carries the filler,
+        # so the least-loaded rule gives h to rank 1
         h = srv.submit_stream(prompt, max_new_tokens=nmax)       # rank 1
         assert h.rank == 1
-        # let rank 1 ship a few tokens, then it goes dark
-        deadline = time.monotonic() + 60
-        while len(h.tokens) < 3:
-            srv.step()
-            assert time.monotonic() < deadline, h.tokens
-            time.sleep(0.002)
-        peers[1].zombie = True
+        srv._handle(1, {"op": "TOKENS", "sid": h.sid, "base": 0,
+                        "toks": list(oracle[:3])})
+        assert h.tokens == oracle[:3] and not h.done()
         k = len(h.tokens)
         srv.fail_rank(1)
         assert h.rank == 0 and h.requeues == 1 and h.ranks == [1, 0]

@@ -221,22 +221,23 @@ def test_span_recorder_bounds_memory(installed_spans):
     assert rec.dropped > 0
 
 
-def test_bench_tracing_preserves_installed_recorder():
-    """bench_tracing's enabled/disabled measurement must hand back the
-    USER-INSTALLED recorder object — spans accumulated before the bench
-    and a custom capacity both survive."""
-    import sys
-    from pathlib import Path
-    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-    import microbench
-
+def test_reinstalling_hands_back_the_installed_recorder():
+    """The save/restore idiom of every test that needs the recorder off
+    for a reading: ``uninstall`` then ``install(recorder_obj=prev)`` hands
+    back the USER-INSTALLED object — spans accumulated before and a custom
+    capacity both survive."""
     rec = spans.install(max_spans=123)
     rec.record("keepme", 7, 0, 1)
     try:
-        microbench.bench_tracing(smoke=True)
+        prev = spans.recorder
+        spans.uninstall()
+        assert spans.recorder is None
+        spans.install().record("other", 8, 0, 1)    # a private recorder
+        spans.uninstall()
+        spans.install(recorder_obj=prev)
         assert spans.recorder is rec
         assert rec.max == 123
-        assert any(s[0] == "keepme" for s in rec.spans)
+        assert [s[0] for s in rec.spans] == ["keepme"]
     finally:
         spans.uninstall()
 
@@ -442,3 +443,69 @@ def test_two_rank_spans_stitch_across_ranks(tmp_path):
     traced = [e for e in evs
               if (e.get("args") or {}).get("trace") == "beef01"]
     assert {e["pid"] // 100 for e in traced} == {0, 1}
+
+
+# ISSUE-10 tracing budget (docs/OBSERVABILITY.md overhead table), held
+# since ISSUE 27 by what repeats exactly: which PINS slots the span
+# recorder occupies, how many spans a traced pool leaves, and that the
+# phase plane builds nothing while off.
+
+
+@pytest.mark.perf_smoke
+def test_tracing_overhead_within_budget(monkeypatch):
+    """The observability gates.  With the span recorder UNINSTALLED (the
+    shipped default) its six task-span PINS slots hold no chain of its
+    own: tracing added no hot-path site, only the existing PINS branch.
+    INSTALLED, a traced pool of n tasks records exactly n ``exec`` and n
+    ``release`` spans.  The phase plane, off, builds no object."""
+    import parsec_tpu.runtime.dagrun  # noqa: F401 — runtime_dag_compile
+    from collections import Counter
+
+    from parsec_tpu.core.params import params
+    from parsec_tpu.prof import pins
+    from parsec_tpu.prof.pins import PinsEvent
+    from parsec_tpu.runtime import Context
+
+    task_span_events = (
+        PinsEvent.EXEC_BEGIN, PinsEvent.EXEC_END,
+        PinsEvent.RELEASE_DEPS_BEGIN, PinsEvent.RELEASE_DEPS_END,
+        PinsEvent.SCHEDULE_BEGIN, PinsEvent.SCHEDULE_END)
+
+    def recorder_chains():
+        return [cb for ev in task_span_events
+                for cb in pins._chains.get(int(ev), ())
+                if isinstance(getattr(cb, "__self__", None),
+                              spans._TaskSpans)]
+
+    def built(*a):
+        raise AssertionError("the phase plane built a span while off")
+
+    prev = spans.recorder
+    if prev is not None:
+        spans.uninstall()
+    assert spans._task_spans is None and not recorder_chains()
+    spans.phase_refresh()
+    assert not spans.phase_on
+    assert spans.phase("ctx.init") is spans.phase("ctx.fini")
+    table = spans.phase_totals()
+    monkeypatch.setattr(spans, "_Phase", built)
+    nt, depth = 20, 25
+    saved = params.get("runtime_dag_compile")
+    params.set("runtime_dag_compile", False)    # the dynamic path
+    rec = spans.install()
+    try:
+        assert len(recorder_chains()) == len(task_span_events)
+        tp = _ctl_pool(depth=depth, lanes=nt)
+        tp._trace = spans.new_trace()
+        ctx = Context(nb_cores=0)
+        ctx.add_taskpool(tp)
+        ctx.wait(timeout=600)
+        ctx.fini()
+        names = Counter(s[0] for s in rec.by_trace(tp._trace.trace_id))
+    finally:
+        params.set("runtime_dag_compile", saved)
+        spans.uninstall()
+        if prev is not None:
+            spans.install(recorder_obj=prev)
+    assert names["exec"] == names["release"] == nt * depth, names
+    assert spans.phase_totals() == table
