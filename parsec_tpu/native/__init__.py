@@ -227,11 +227,17 @@ class NativeHeap(_Handle):
         return self._lib.pt_heap_size(self._h)
 
 
+ENTRY_MISSING = 2    # NativeDepTable.release without a mask, no entry yet
+
+
 class NativeDepTable(_Handle):
     """key64 -> {required, satisfied} with removal-on-ready.
 
-    ``release`` returns 1 when the key just became ready, 0 otherwise and
-    raises on a double-set bit (the PARSEC_DEBUG_PARANOID assert)."""
+    ``release`` returns 1 when the key just became ready and 0 otherwise;
+    called with ``required_mask`` 0 (the mask not evaluated yet) it updates
+    an existing entry but answers ``ENTRY_MISSING`` instead of creating
+    one.  It raises on a double-set bit and on a bit the entry's mask does
+    not hold (the PARSEC_DEBUG_PARANOID asserts)."""
 
     def __init__(self, nbuckets: int = 1 << 14) -> None:
         lib = load()
@@ -239,12 +245,14 @@ class NativeDepTable(_Handle):
                          "pt_deptable_free")
         self._release = lib.pt_deptable_release   # bound-method cache
 
-    def release(self, key64: int, bits: int, required_mask: int) -> bool:
+    def release(self, key64: int, bits: int, required_mask: int) -> int:
         rc = self._release(self._h, key64, bits, required_mask)
         if rc < 0:
             raise AssertionError(
-                f"dep key {key64:#x}: bits {bits:#x} satisfied twice")
-        return bool(rc)
+                f"dep key {key64:#x}: bits {bits:#x} "
+                + ("satisfied twice" if rc == -1 else
+                   "are not ones the task waits for"))
+        return rc
 
     def __len__(self) -> int:
         return self._lib.pt_deptable_count(self._h)

@@ -281,8 +281,14 @@ static inline size_t dep_bucket(DepTable* t, uint64_t key) {
 
 // Record satisfied bits for `key`; required_mask is idempotently installed
 // on first touch.  Returns 1 when the task just became ready (entry is
-// removed), 0 otherwise.  Asserting a bit twice aborts (the double-release
-// paranoia check, PARSEC_DEBUG_PARANOID analog) — returns -1 instead.
+// removed), 0 otherwise.  A caller that has not evaluated the mask yet
+// passes required_mask == 0 (no task waits for nothing): an existing entry
+// is updated as usual, a missing one is NOT created and the call returns 2,
+// so only the arrival that creates the entry pays for the mask.  Asserting
+// a bit twice aborts (the double-release paranoia check,
+// PARSEC_DEBUG_PARANOID analog) — returns -1 instead; a bit outside the
+// entry's required mask (a release that named the wrong input dep, which
+// would leave the task unready for ever) returns -2.
 int pt_deptable_release(void* h, uint64_t key, uint64_t bits,
                         uint64_t required_mask) {
     DepTable* t = (DepTable*)h;
@@ -292,6 +298,10 @@ int pt_deptable_release(void* h, uint64_t key, uint64_t bits,
     DepEntry* e = *slot;
     while (e && e->key != key) { slot = &e->next; e = e->next; }
     if (!e) {
+        if (!required_mask) {
+            t->locks[b].unlock();
+            return 2;                    // entry missing: come back with the mask
+        }
         t->flock.lock();
         e = t->free_head;
         if (e) t->free_head = e->next;
@@ -305,9 +315,10 @@ int pt_deptable_release(void* h, uint64_t key, uint64_t bits,
         slot = &t->buckets[b];
         t->count.fetch_add(1, std::memory_order_relaxed);
     }
-    if (e->satisfied & bits) {
+    if ((e->satisfied & bits) || (bits & ~e->required)) {
+        int rc = (e->satisfied & bits) ? -1 : -2;
         t->locks[b].unlock();
-        return -1;                       // double release
+        return rc;                       // double release / not awaited
     }
     e->satisfied |= bits;
     int ready = (e->satisfied == e->required);

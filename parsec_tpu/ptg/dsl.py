@@ -45,8 +45,13 @@ class _NS(SimpleNamespace):
         return getattr(self, k)
 
 
-def _ns(d: dict) -> _NS:
-    return _NS(**d)
+def _ns(d: Any) -> _NS:
+    """The namespace a ``fn(g, l)`` expression reads its locals from.  A
+    caller that evaluates several expressions of ONE task instance (the
+    release path: guards, ranges, keys, priority) builds it once through the
+    class's ``locals_view`` and hands the namespace itself to each wrapper,
+    which passes it through here untouched."""
+    return d if d.__class__ is _NS else _NS(**d)
 
 
 class _DictNS:
@@ -157,9 +162,10 @@ class TaskClassBuilder:
     def affinity(self, collection: Any, key_fn: Callable) -> "TaskClassBuilder":
         dc_get = self._ptg._dc_getter(collection)
 
+        g = self._ptg._g_view
+
         def aff(locals_: dict) -> tuple:
-            g, l = self._ptg._g_ns(), _ns(locals_)
-            return dc_get(), key_fn(g, l)
+            return dc_get(), key_fn(g, _ns(locals_))
 
         self._affinity = aff
         return self
@@ -170,8 +176,8 @@ class TaskClassBuilder:
         return fb
 
     def priority(self, fn: Callable) -> "TaskClassBuilder":
-        g_ns = self._ptg._g_ns
-        self._priority = lambda locals_: int(fn(g_ns(), _ns(locals_)))
+        g = self._ptg._g_view
+        self._priority = lambda locals_: int(fn(g, _ns(locals_)))
         return self
 
     def time_estimate(self, fn: Callable) -> "TaskClassBuilder":
@@ -182,15 +188,15 @@ class TaskClassBuilder:
     def make_key(self, fn: Callable) -> "TaskClassBuilder":
         """``make_key_fn``: custom task-key construction, ``fn(g, l) -> key``
         (any hashable; non-tuples are wrapped by the runtime)."""
-        g_ns = self._ptg._g_ns
-        self._make_key = lambda locals_: fn(g_ns(), _ns(locals_))
+        g = self._ptg._g_view
+        self._make_key = lambda locals_: fn(g, _ns(locals_))
         return self
 
     def find_deps(self, fn: Callable) -> "TaskClassBuilder":
         """``find_deps_fn``: custom dep-storage location,
         ``fn(taskpool, g, l) -> hashable identity``."""
-        g_ns = self._ptg._g_ns
-        self._find_deps = lambda tp, locals_: fn(tp, g_ns(), _ns(locals_))
+        g = self._ptg._g_view
+        self._find_deps = lambda tp, locals_: fn(tp, g, _ns(locals_))
         return self
 
     def hash_struct(self, key_hash: Callable | None = None,
@@ -212,8 +218,8 @@ class TaskClassBuilder:
     def simcost(self, fn: Callable) -> "TaskClassBuilder":
         """``SIMCOST``: simulated execution cost ``fn(g, l) -> float``; the
         pool then tracks ``largest_simulation_date`` (PARSEC_SIM model)."""
-        g_ns = self._ptg._g_ns
-        self._simcost = lambda locals_: fn(g_ns(), _ns(locals_))
+        g = self._ptg._g_view
+        self._simcost = lambda locals_: fn(g, _ns(locals_))
         return self
 
     def stage_hooks(self, stage_in: Callable | None = None,
@@ -277,13 +283,16 @@ class TaskClassBuilder:
                 guard: Callable | None, dtt: Any,
                 new: bool = False, null: bool = False,
                 ranged: bool = False, wire: Any = None) -> Dep:
-        g_ns = self._ptg._g_ns
+        # every wrapper takes a locals dict or the namespace a caller has
+        # already built of it (``_ns`` passes that through); the globals
+        # view is one live object for the builder's life
+        g = self._ptg._g_view
         gfn = None
         if guard is not None:
-            gfn = lambda locals_: guard(g_ns(), _ns(locals_))
+            gfn = lambda locals_: guard(g, _ns(locals_))
         wfn = wire
         if callable(wire):
-            wfn = lambda locals_: wire(g_ns(), _ns(locals_))
+            wfn = lambda locals_: wire(g, _ns(locals_))
         if new or null:
             # NEW: all targets None — resolve_data_inputs leaves the slot
             # empty and prepare_input allocates scratch of the flow type;
@@ -291,7 +300,7 @@ class TaskClassBuilder:
             return Dep(guard=gfn, dtt=dtt, null=null)
         if ref is not None:
             cls_name, flow_name, params_fn = ref
-            tparams = lambda locals_: params_fn(g_ns(), _ns(locals_))
+            tparams = lambda locals_: params_fn(g, _ns(locals_))
             return Dep(guard=gfn, target_class=cls_name,
                        target_flow=flow_name, target_params=tparams, dtt=dtt,
                        ranged=ranged, wire=wfn)
@@ -300,7 +309,7 @@ class TaskClassBuilder:
             dc_get = self._ptg._dc_getter(collection)
 
             def data_ref(locals_: dict) -> tuple:
-                key = key_fn(g_ns(), _ns(locals_))
+                key = key_fn(g, _ns(locals_))
                 if not isinstance(key, tuple):
                     key = (key,)
                 return dc_get(), key
@@ -356,8 +365,9 @@ class TaskClassBuilder:
         # dependent ranges re-evaluate in order.  Mutating the pool's
         # globals after execution starts is outside the contract anyway.
         g_ns = self._ptg._g_ns
+        g_view = self._ptg._g_view
         ranges = self.param_ranges
-        cache: dict = {"static": None}
+        cache: dict = {"tests": None}
 
         class _Poison:
             def __getattr__(self, k):
@@ -383,33 +393,41 @@ class TaskClassBuilder:
 
         tc.space_extents_fn = extents_fn
 
-        def in_space(locals_: dict) -> bool:
-            st = cache["static"]
-            if st is None:
-                try:
-                    g = g_ns()
-                    poison = _Poison()
-                    st = tuple(rngfn(g, poison)
-                               for rngfn in ranges.values())
-                except Exception:
-                    st = False
-                cache["static"] = st
-            if st is not False:
-                for pname, r in zip(ranges, st):
-                    v = locals_.get(pname)
-                    if v is None or v not in r:
-                        return False
-                return True
-            g = g_ns()
-            partial: dict = {}
-            for pname, rngfn in ranges.items():
-                v = locals_.get(pname)
-                if v is None or v not in rngfn(g, _ns(partial)):
+        def in_space(locals_: Any) -> bool:
+            # a dict, or the namespace the release path built of it once
+            if locals_.__class__ is _NS:
+                ns, d = locals_, locals_.__dict__
+            else:
+                ns, d = None, locals_
+            tests = cache["tests"]
+            if tests is None:
+                # per parameter: the range itself where it reads no local
+                # (captured once), else the function to ask again
+                poison = _Poison()
+                tests = []
+                for pname, rngfn in ranges.items():
+                    try:
+                        tests.append((pname, rngfn(g_view, poison), None))
+                    except Exception:
+                        tests.append((pname, None, rngfn))
+                tests = cache["tests"] = tuple(tests)
+            for pname, r, rngfn in tests:
+                v = d.get(pname)
+                if v is None:
                     return False
-                partial[pname] = v
+                if rngfn is not None:
+                    # a dependent range (a triangular space) reads the
+                    # parameters declared before its own, all of which
+                    # the one namespace of the locals holds
+                    if ns is None:
+                        ns = _NS(**d)
+                    r = rngfn(g_view, ns)
+                if v not in r:
+                    return False
             return True
 
         tc.in_space = in_space
+        tc.locals_view = _ns
         return tc
 
 

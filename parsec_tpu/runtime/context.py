@@ -28,8 +28,8 @@ from ..device.device import cpu_device as _cpu_device
 from ..prof import pins, spans
 from ..prof.pins import PinsEvent
 from .deps import DependencyTracking
-from .scheduling import (ExecutionStream, VirtualProcess, schedule_tasks,
-                         select_task, task_progress)
+from .scheduling import (ExecutionStream, VirtualProcess, release_totals,
+                         schedule_tasks, select_task, task_progress)
 from .taskpool import Taskpool
 
 _params.register("runtime_num_cores", 0,
@@ -124,6 +124,15 @@ class Context:
             self._cond = threading.Condition(self._lock)
             self._active_taskpools: list[Taskpool] = []
             self.deps = DependencyTracking()
+            # always on, like the device module's counters: the edges
+            # release_deps handed to local successors, and those of them
+            # that went through a resolved release plan (scheduling.py:
+            # _EdgePlan).  Teardown adds them to the process's totals
+            # (scheduling.release_totals).
+            self.release_edges = 0
+            self.release_edges_planned = 0
+            self._release_count_lock = threading.Lock()   # any stream adds
+            self._release_folded = (0, 0)
             self.taskpool_list: list[Taskpool] = []
             self.comm_engine: Any = None
             # rank-agreed taskpool ids for the wire protocol: ranks enqueue
@@ -506,6 +515,11 @@ class Context:
         self._props_teardown()
 
     def _props_teardown(self) -> None:
+        # the release counters join the process's totals, once each
+        now = (self.release_edges, self.release_edges_planned)
+        release_totals["edges"] += now[0] - self._release_folded[0]
+        release_totals["planned"] += now[1] - self._release_folded[1]
+        self._release_folded = now
         if self._props_stop is not None:
             self._props_stop()
             self._props_stop = None

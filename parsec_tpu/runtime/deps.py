@@ -19,6 +19,8 @@ from typing import Any
 
 from ..core.hash_table import ConcurrentHashTable
 from ..core.params import params as _params
+from ..native import ENTRY_MISSING
+from .scheduling import resolve_data_inputs
 from .task import Task, TaskClass
 
 _params.register(
@@ -79,6 +81,18 @@ def _tracker_key(taskpool: Any, tc: "TaskClass", locals_: dict,
     if tc.find_deps_fn is not None:
         return (taskpool.taskpool_id, tc.find_deps_fn(taskpool, locals_))
     return (taskpool.taskpool_id, tc.task_class_id, tkey)
+
+
+def _check_bit(tc: "TaskClass", where: Any, trk: "_DepTracker",
+               bit: int) -> None:
+    """The mask protocol's two invariants on an arrival: the dep is one the
+    task waits for (a release that names the wrong input dep would leave
+    the task unready for ever), and it arrives once."""
+    assert trk.required_mask & bit, \
+        f"dep {tc.name}{where} bit {bit} is not one the task waits for " \
+        f"(mask {trk.required_mask:#x})"
+    assert not (trk.satisfied_mask & bit), \
+        f"dep {tc.name}{where} bit {bit} satisfied twice"
 
 
 class _DepTracker:
@@ -206,35 +220,45 @@ class DependencyTracking:
         ``repo_ref`` is (repo_entry, src_flow_index) for usage accounting at
         completion (``jdf2c.c:7157`` consume-input-repos contract).
         """
+        view = tc.view_of(locals_)
+        bit = 0 if tc.counted else 1 << tc.dep_bit(flow_index, dep_index)
+        return self._release_one(taskpool, tc, locals_, view, flow_index,
+                                 bit, data_copy, repo_ref)
+
+    def _release_one(self, taskpool: Any, tc: TaskClass, locals_: dict,
+                     view: Any, flow_index: int, bit: int,
+                     data_copy: Any, repo_ref: Any) -> Task | None:
+        """:meth:`release_dep` for a caller that holds the successor's
+        locals view (``tc.locals_view``: what its guards read) and the dep's
+        mask bit already.  In every tier the required mask is evaluated
+        when the tracker is CREATED, on the task's first arrival."""
         tkey = tc.make_key(locals_)
         if tc.counted:
             # goal-counted mode (ranged input deps): arrivals decrement a
             # per-task counter instead of OR-ing bits — N arrivals may land
             # on ONE declared dep (the dependencies_goal protocol)
-            return self._release_counted(taskpool, tc, locals_, tkey,
+            return self._release_counted(taskpool, tc, locals_, view, tkey,
                                          flow_index, data_copy, repo_ref)
-        bit = 1 << tc.dep_bit(flow_index, dep_index)
         if self._indexed_eligible(tc):
             li = _IndexArrayStore.slot(tc.space_extents, tkey)
             if li is not None:
-                return self._release_indexed(taskpool, tc, locals_, li, bit,
-                                             flow_index, data_copy, repo_ref)
+                return self._release_indexed(taskpool, tc, locals_, view, li,
+                                             bit, flow_index, data_copy,
+                                             repo_ref)
         if self._native is not None and tc.find_deps_fn is None:
             # UD keys with non-int elements refuse to pack and fall through
             k64 = _pack_key64(taskpool.taskpool_id, tc.task_class_id, tkey)
             if k64 is not None:
-                return self._release_native(taskpool, tc, locals_, tkey, k64,
+                return self._release_native(taskpool, tc, locals_, view, k64,
                                             bit, flow_index, data_copy,
                                             repo_ref)
         key = _tracker_key(taskpool, tc, locals_, tkey)
         with self._table.locked(key):
             trk = self._table.get(key)
             if trk is None:
-                trk = _DepTracker(tc.input_dep_mask(locals_),
-                                  len(tc.flows))
+                trk = _DepTracker(tc.input_dep_mask(view), len(tc.flows))
                 self._table.insert(key, trk)
-            assert not (trk.satisfied_mask & bit), \
-                f"dep {tc.name}{key} bit {bit} satisfied twice"
+            _check_bit(tc, key, trk, bit)
             trk.satisfied_mask |= bit
             if data_copy is not None:
                 trk.inputs[flow_index] = data_copy
@@ -244,7 +268,7 @@ class DependencyTracking:
                 self._table.remove(key)
         if not ready:
             return None
-        return self._make_ready(taskpool, tc, locals_, trk.inputs,
+        return self._make_ready(taskpool, tc, locals_, view, trk.inputs,
                                 trk.repo_refs)
 
     def _indexed_eligible(self, tc: TaskClass) -> bool:
@@ -256,22 +280,30 @@ class DependencyTracking:
         aligned with the param-range extents (direct linearization could
         collide distinct tasks); oversized boxes fall to the hashed tier
         (:meth:`_IndexArrayStore.fits`)."""
+        memo = tc._indexed_memo     # per class: asked for every record
+        if memo is not None and memo[0] is self:
+            return memo[1]
         store = self._index_store
-        return (store is not None and not tc.counted
-                and tc.find_deps_fn is None and tc.make_key_fn is None
-                and tc.space_extents is not None
-                and store.fits(tc.space_extents))
+        ok = (store is not None and not tc.counted
+              and tc.find_deps_fn is None and tc.make_key_fn is None
+              and tc.space_extents is not None
+              and store.fits(tc.space_extents))
+        tc._indexed_memo = (self, ok)
+        return ok
 
     def release_many(self, taskpool: Any,
                      records: list[tuple]) -> list[Task]:
         """Batched release of one completing task's successor deps.
 
-        ``records`` is a list of ``(tc, locals_, flow_index, dep_index,
-        data_copy, repo_ref)`` tuples.  Records eligible for the dense
+        ``records`` is a list of ``(tc, locals_, view, flow_index, bit,
+        data_copy, repo_ref)`` tuples, :meth:`_release_one`'s arguments
+        (``view``: the successor's ``tc.locals_view`` of ``locals_``, built
+        once by the releaser; ``bit``: the dep's mask bit, 0 for a counted
+        class).  Records eligible for the dense
         index-array tier are grouped per task class and released under ONE
         lock acquisition per group (the batched-dep-release half of the
         critical-path fast path); everything else goes record-at-a-time
-        through :meth:`release_dep`.  Returns every task that became ready.
+        through :meth:`_release_one`.  Returns every task that became ready.
         """
         ready: list[Task] = []
         if self._index_store is not None and len(records) > 1:
@@ -293,9 +325,8 @@ class DependencyTracking:
                 ready.extend(self._release_indexed_batch(taskpool, tcs[cid],
                                                          grp))
             records = rest
-        for tc, locals_, fi, di, data_copy, repo_ref in records:
-            t = self.release_dep(taskpool, tc, locals_, fi, di, data_copy,
-                                 repo_ref)
+        for rec in records:
+            t = self._release_one(taskpool, *rec)
             if t is not None:
                 ready.append(t)
         return ready
@@ -316,27 +347,25 @@ class DependencyTracking:
             if cur is None or cur[1] is not arr:
                 return []    # purged between lookup and lock (abort race)
             store.releases += len(grp)
-            for (_, locals_, fi, di, data_copy, repo_ref), li in grp:
-                bit = 1 << tc.dep_bit(fi, di)
+            for (_, locals_, view, fi, bit, data_copy, repo_ref), li in grp:
                 trk = arr[li]
                 if trk is None:
-                    trk = arr[li] = _DepTracker(tc.input_dep_mask(locals_),
+                    trk = arr[li] = _DepTracker(tc.input_dep_mask(view),
                                                 len(tc.flows))
-                assert not (trk.satisfied_mask & bit), \
-                    f"dep {tc.name}[{li}] bit {bit} satisfied twice"
+                _check_bit(tc, [li], trk, bit)
                 trk.satisfied_mask |= bit
                 if data_copy is not None:
                     trk.inputs[fi] = data_copy
                     trk.repo_refs[fi] = repo_ref
                 if trk.satisfied_mask == trk.required_mask:
                     arr[li] = None
-                    done.append((locals_, trk))
-        return [self._make_ready(taskpool, tc, locals_, trk.inputs,
+                    done.append((locals_, view, trk))
+        return [self._make_ready(taskpool, tc, locals_, view, trk.inputs,
                                  trk.repo_refs)
-                for locals_, trk in done]
+                for locals_, view, trk in done]
 
     def _release_indexed(self, taskpool: Any, tc: TaskClass, locals_: dict,
-                         li: int, bit: int, flow_index: int,
+                         view: Any, li: int, bit: int, flow_index: int,
                          data_copy: Any, repo_ref: Any) -> Task | None:
         """The index-array variant's release: same mask protocol as the
         hashed tier, tracker slot found by direct indexing."""
@@ -356,10 +385,9 @@ class DependencyTracking:
             store.releases += 1
             trk = arr[li]
             if trk is None:
-                trk = arr[li] = _DepTracker(tc.input_dep_mask(locals_),
+                trk = arr[li] = _DepTracker(tc.input_dep_mask(view),
                                             len(tc.flows))
-            assert not (trk.satisfied_mask & bit), \
-                f"dep {tc.name}[{li}] bit {bit} satisfied twice"
+            _check_bit(tc, [li], trk, bit)
             trk.satisfied_mask |= bit
             if data_copy is not None:
                 trk.inputs[flow_index] = data_copy
@@ -369,18 +397,18 @@ class DependencyTracking:
                 arr[li] = None
         if not ready:
             return None
-        return self._make_ready(taskpool, tc, locals_, trk.inputs,
+        return self._make_ready(taskpool, tc, locals_, view, trk.inputs,
                                 trk.repo_refs)
 
     def _release_counted(self, taskpool: Any, tc: TaskClass, locals_: dict,
-                         tkey: tuple, flow_index: int, data_copy: Any,
-                         repo_ref: Any) -> Task | None:
+                         view: Any, tkey: tuple, flow_index: int,
+                         data_copy: Any, repo_ref: Any) -> Task | None:
         key = _tracker_key(taskpool, tc, locals_, tkey)
         with self._table.locked(key):
             trk = self._table.get(key)
             if trk is None:
                 trk = _DepTracker(0, len(tc.flows))
-                trk.goal = tc.input_dep_goal(locals_)
+                trk.goal = tc.input_dep_goal(view)
                 self._table.insert(key, trk)
             assert trk.goal > 0, \
                 f"dep {tc.name}{tkey}: more arrivals than the goal"
@@ -393,42 +421,58 @@ class DependencyTracking:
                 self._table.remove(key)
         if not ready:
             return None
-        return self._make_ready(taskpool, tc, locals_, trk.inputs,
+        return self._make_ready(taskpool, tc, locals_, view, trk.inputs,
                                 trk.repo_refs)
 
     def _release_native(self, taskpool: Any, tc: TaskClass, locals_: dict,
-                        tkey: tuple, k64: int, bit: int, flow_index: int,
+                        view: Any, k64: int, bit: int, flow_index: int,
                         data_copy: Any, repo_ref: Any) -> Task | None:
         # inputs are written BEFORE the native release: the releaser that
         # observes readiness sees every earlier writer's entry (GIL + the
         # table's internal lock order the accesses)
+        nf = len(tc.flows)
+        first = False
         if data_copy is not None:
             with self._inputs_lock:
                 lst = self._inputs.get(k64)
                 if lst is None:
-                    lst = self._inputs[k64] = [None] * (2 * len(tc.flows))
+                    lst = self._inputs[k64] = [None] * (2 * nf)
+                    first = True
                 lst[flow_index] = data_copy
-                lst[len(tc.flows) + flow_index] = repo_ref
-        if not self._native.release(k64, bit, tc.input_dep_mask(locals_)):
+                lst[nf + flow_index] = repo_ref
+        # the table keeps the required mask from the arrival that created
+        # the entry, so only that arrival evaluates the guards.  The first
+        # copy stashed for the task is that arrival (or follows arrivals
+        # that carried none: the mask it brings is then ignored); any other
+        # release goes without a mask and is answered ENTRY_MISSING where
+        # no entry exists yet, instead of creating one (two first arrivals
+        # racing each install the same mask: idempotent)
+        release = self._native.release
+        rc = release(k64, bit, tc.input_dep_mask(view) if first else 0)
+        if rc == ENTRY_MISSING:
+            rc = release(k64, bit, tc.input_dep_mask(view))
+        if not rc:
             return None
         with self._inputs_lock:
             lst = self._inputs.pop(k64, None)
         if lst is None:
-            nf = len(tc.flows)
-            return self._make_ready(taskpool, tc, locals_,
+            return self._make_ready(taskpool, tc, locals_, view,
                                     [None] * nf, [None] * nf)
-        nf = len(tc.flows)
-        return self._make_ready(taskpool, tc, locals_, lst[:nf], lst[nf:])
+        return self._make_ready(taskpool, tc, locals_, view, lst[:nf],
+                                lst[nf:])
 
     def _make_ready(self, taskpool: Any, tc: TaskClass, locals_: dict,
-                    inputs: list, repo_refs: list) -> Task:
-        prio = tc.priority(locals_) if tc.priority is not None else 0
+                    view: Any, inputs: list, repo_refs: list) -> Task:
+        """The task of a tracker whose last dep arrived; ``inputs`` and
+        ``repo_refs`` become the task's own lists (every caller hands over
+        a tracker's, which dies here, or fresh ones)."""
+        prio = tc.priority(view) if tc.priority is not None else 0
         task = Task(taskpool, tc, dict(locals_), priority=prio)
-        task.data = list(inputs)
-        task.repo_entries = list(repo_refs)
+        task.data = inputs
+        task.repo_entries = repo_refs
         task.status = "ready"
-        from .scheduling import resolve_data_inputs
-        resolve_data_inputs(task)   # snapshot collection reads at creation
+        # snapshot collection reads at creation
+        resolve_data_inputs(task, view)
         return task
 
     def purge_taskpool(self, taskpool_id: int) -> None:

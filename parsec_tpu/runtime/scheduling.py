@@ -18,7 +18,9 @@ import time
 from typing import Any
 
 from ..core.params import params as _params
-from ..data.reshape import reshape_for_edge, reshape_for_writeback
+from ..core.future import DataCopyFuture
+from ..data.reshape import (reshape_for_edge, reshape_for_writeback,
+                            resolve_copy)
 from ..device.device import cpu_device as _cpu_device
 from ..prof import pins, spans
 from ..prof.pins import PinsEvent
@@ -60,12 +62,15 @@ _wb_lock = threading.Lock()
 
 # concurrency contracts, enforced by analysis.runtimelint (docs/ANALYSIS.md):
 # PARSEC_SIM bookkeeping mutates only under the pool's _sim_lock; the
-# paranoid writeback mark only under the module-level _wb_lock.
+# paranoid writeback mark only under the module-level _wb_lock; the
+# context's release counters, which any stream adds to, under their own.
 # (es.next_task is single-owner by thread identity, not lock-protected.)
 _LOCK_PROTECTED = {
     "Taskpool._sim_ready": "_sim_lock",
     "Taskpool.largest_simulation_date": "_sim_lock",
     "DataCopy.wb_mark": "_wb_lock",
+    "Context.release_edges": "_release_count_lock",
+    "Context.release_edges_planned": "_release_count_lock",
 }
 
 
@@ -216,24 +221,30 @@ def task_progress(es: ExecutionStream, task: Task, distance: int) -> int:
 # data resolution
 # ---------------------------------------------------------------------------
 
-def resolve_data_inputs(task: Task) -> None:
+def resolve_data_inputs(task: Task, view: Any = None) -> None:
     """Bind flows read directly from a data collection to their current
     copies.  Called EAGERLY at task creation (startup enumeration / dep
     release): a ``<- A(k)`` read observes the collection state as of the
     moment the task came into existence — later writebacks to the same tile
     by unordered tasks must not leak in (ordering, when needed, must be a
-    flow edge)."""
+    flow edge).  ``view``: the class's ``locals_view`` of the task's locals
+    where the caller holds one already (the release path)."""
     tc = task.task_class
     if tc.prepare_input is not None:
         return  # custom lookup owns its semantics (DTD binds at insert)
+    data = task.data
     for f in tc.flows:
-        if f.is_ctl or task.data[f.flow_index] is not None:
+        if data[f.flow_index] is not None or f.is_ctl:
             continue
         for d in f.deps_in:
-            if d.target_class is None and d.active(task.locals):
+            if d.target_class is not None:
+                continue
+            if view is None:
+                view = tc.view_of(task.locals)
+            if d.active(view):
                 if d.data_ref is None:
                     break
-                dc, key = d.data_ref(task.locals)
+                dc, key = d.data_ref(view)
                 datum = dc.data_of(*key)
                 copy = datum.newest_copy()
                 if copy is None:
@@ -241,7 +252,7 @@ def resolve_data_inputs(task: Task) -> None:
                         f"{task}: flow {f.name} has no valid copy")
                 # typed collection read: lazy shared repack, resolved at
                 # prepare_input (parsec_reshape.c read-side path)
-                task.data[f.flow_index] = reshape_for_edge(copy, None, d)
+                data[f.flow_index] = reshape_for_edge(copy, None, d)
                 break
 
 
@@ -249,30 +260,32 @@ def prepare_input(es: ExecutionStream, task: Task) -> None:
     """Generic data lookup (cf. generated ``data_lookup``, ``jdf2c.c:44``):
     flows fed by predecessors already carry their copies (attached at dep
     release); data-collection reads were bound at creation
-    (:func:`resolve_data_inputs`, re-run here as a safety net); WRITE-only
-    flows allocate scratch."""
+    (:func:`resolve_data_inputs`, re-run here as a safety net for a task
+    with a slot still empty); WRITE-only flows allocate scratch."""
     tc = task.task_class
     if tc.prepare_input is not None:
         tc.prepare_input(es, task)
         return
-    resolve_data_inputs(task)
+    unbound = None in task.data
+    if unbound:
+        resolve_data_inputs(task)
     # materialize pending reshape futures: the first consumer to prepare
     # runs the conversion on its own thread (datacopy-future protocol)
-    from ..core.future import DataCopyFuture
-    from ..data.reshape import resolve_copy
-    for f in tc.flows:
-        v = task.data[f.flow_index]
+    data = task.data
+    for i, v in enumerate(data):
         if isinstance(v, DataCopyFuture):
-            task.data[f.flow_index] = resolve_copy(v)
-    for f in tc.flows:
-        if f.is_ctl or task.data[f.flow_index] is not None:
-            continue
-        if any(d.null and d.active(task.locals) for d in f.deps_in):
-            continue   # explicit NULL arrow: no data for these locals
-        if f.dtt is not None:
-            # WRITE-only / NEW flow: allocate scratch of the declared type
-            from ..data.data import scratch_copy
-            task.data[f.flow_index] = scratch_copy(f.dtt)
+            data[i] = resolve_copy(v)
+    if unbound:
+        for f in tc.flows:
+            if f.is_ctl or data[f.flow_index] is not None:
+                continue
+            if any(d.null and d.active(task.locals) for d in f.deps_in):
+                continue   # explicit NULL arrow: no data for these locals
+            if f.dtt is not None:
+                # WRITE-only / NEW flow: allocate scratch of the declared
+                # type
+                from ..data.data import scratch_copy
+                data[f.flow_index] = scratch_copy(f.dtt)
     if _params.get("debug_paranoid"):
         for f in tc.flows:
             if f.is_ctl or not (f.deps_in or f.dtt):
@@ -351,6 +364,75 @@ def complete_execution_timed(es: ExecutionStream, task: Task) -> None:
                         spans.phase_covered() - inner)
 
 
+# process totals of Context.release_edges / release_edges_planned over the
+# contexts that have been torn down (a benchmark's solves are a Context each)
+release_totals = {"edges": 0, "planned": 0}
+
+
+class _EdgePlan:
+    """One out-dep of a task class as :func:`release_deps` walks it.
+
+    Everything here is a function of (class, out-dep) and of the pool the
+    class runs in, so it is derived once, at the class's first release, and
+    not for every edge of every task: the successor ``TaskClass`` object,
+    its ``in_space`` and ``locals_view``, the index of the successor flow
+    and, of that flow's input deps, the ones this class feeds (``(mask bit,
+    guard)`` in declaration order: the first whose guard holds for the
+    successor's locals is the dep the edge satisfies).
+
+    ``planned`` says the edge needs nothing beyond that.  It is False, and
+    the walk derives per edge what the plan could not state ahead (the
+    successor's rank, its simulation date, the input dep by name, the
+    reshape), where the ``Dep`` or the classes show that something more can
+    happen on the edge: a pool on several ranks, a simulated pool, a counted
+    successor, a successor with its own tracker key, a type on either end.
+    """
+
+    __slots__ = ("flow", "dep", "guard", "ctl", "flow_index", "succ_tc",
+                 "in_space", "view", "succ_fi", "cands", "planned")
+
+
+def _plan_edge(tp: Any, tc: TaskClass, flow: Any, dep: Any) -> _EdgePlan:
+    ep = _EdgePlan()
+    ep.flow, ep.dep, ep.guard = flow, dep, dep.guard
+    ep.ctl, ep.flow_index = flow.is_ctl, flow.flow_index
+    ep.succ_tc = ep.in_space = ep.view = ep.succ_fi = None
+    ep.cands = ()
+    # rank-local, unsimulated pools resolve ahead; the others ask per edge
+    # where the successor (or the home tile) lives and when it was ready
+    ep.planned = tp.context.nb_ranks <= 1 and not tp.sim_enabled
+    if dep.target_class is None:
+        return ep               # a memory edge: the write-back
+    succ = ep.succ_tc = tp.task_class(dep.target_class)
+    ep.in_space, ep.view = succ.in_space, succ.locals_view
+    sflow = next((f for f in succ.flows if f.name == dep.target_flow), None)
+    feeds = [] if sflow is None or succ.counted else [
+        (di, d) for di, d in enumerate(sflow.deps_in)
+        if d.target_class == tc.name]
+    if feeds:
+        ep.succ_fi = sflow.flow_index
+        ep.cands = tuple((1 << succ.dep_bit(sflow.flow_index, di), d.guard)
+                         for di, d in feeds)
+    typed = dep.dtt is not None or any(d.dtt is not None for _, d in feeds)
+    if not feeds or typed or succ.find_deps_fn is not None \
+            or succ.make_key_fn is not None:
+        ep.planned = False
+    return ep
+
+
+def _plan_release(tp: Any, tc: TaskClass) -> tuple:
+    """The release plan of ``tc`` in ``tp``: one :class:`_EdgePlan` per
+    out-dep in the order ``TaskClass.iterate_successors`` visits them, kept
+    on the class.  A class that walks its own successors (DTD) has none:
+    ``release_deps`` plans the edges it visits as it visits them."""
+    own_walk = type(tc).iterate_successors is not TaskClass.iterate_successors \
+        or "iterate_successors" in vars(tc)
+    edges = None if own_walk else tuple(
+        _plan_edge(tp, tc, f, d) for f in tc.flows for d in f.deps_out)
+    tc._release_plan = plan = (tp, edges)
+    return plan
+
+
 def release_deps(es: ExecutionStream, task: Task) -> None:
     """Generic ``release_deps`` (cf. generated code, ``jdf2c.c:7185``, and the
     per-edge visitor ``parsec_release_dep_fct``, ``parsec.c:1759``): walk
@@ -358,10 +440,15 @@ def release_deps(es: ExecutionStream, task: Task) -> None:
     update dep trackers, collecting now-ready tasks; remote successors
     accumulate into a remote-deps set activated through the comm engine.
 
-    Successor releases are BATCHED: the visitor only accumulates release
+    The walk is a loop over the class's release plan (:class:`_EdgePlan`:
+    what jdf2c emits per class at compile time), with one view of the task's
+    locals for all of its guards and one of each successor's for its
+    ``in_space`` test, its guards, its mask and its priority.
+
+    Successor releases are BATCHED: the walk only accumulates release
     records; one :meth:`DependencyTracking.release_many
     <parsec_tpu.runtime.deps.DependencyTracking.release_many>` call after
-    the walk performs them grouped per class (one lock acquisition per
+    it performs them grouped per class (one lock acquisition per
     dense-tier group), and the resulting ready set is pushed to the
     scheduler in a single ``schedule_tasks`` call."""
     h = _hooks[_RELEASE_DEPS_BEGIN]
@@ -370,65 +457,111 @@ def release_deps(es: ExecutionStream, task: Task) -> None:
     tc = task.task_class
     tp = task.taskpool
     ctx = tp.context
+    plan = tc._release_plan
+    if plan is None or plan[0] is not tp:
+        plan = _plan_release(tp, tc)
+    edges = plan[1]
+    tv = tc.view_of(task.locals)
+    if edges is None:
+        # the class decides which edges are active: plan what it visits
+        edges = []
+
+        def visitor(t: Task, flow, dep) -> None:
+            ep = _plan_edge(tp, tc, flow, dep)
+            ep.guard = None
+            edges.append(ep)
+
+        tc.iterate_successors(task, visitor)
     entry = None
     nconsumers = 0
+    nplanned = 0
     pending: list[tuple] = []   # deferred successor-release records
     remote = None
-
-    def visitor(t: Task, flow, dep) -> None:
-        nonlocal entry, nconsumers, remote
-        out_copy = None if flow.is_ctl else t.data[flow.flow_index]
-        if dep.target_class is None:
-            home_rank = _rank_of_data(ctx, dep, t.locals)
-            if home_rank is not None and home_rank != ctx.my_rank:
-                # home tile lives on another rank: ship the final version
-                # (the remote write-back path of parsec_release_dep_fct)
-                remote = ctx.remote_dep_accumulate(remote, t, flow, dep,
-                                                   None, None, home_rank)
-                return
-            _writeback(t, flow, dep, out_copy)
-            return
-        succ_tc = tp.task_class(dep.target_class)
-        for succ_locals in dep.each_target(t.locals):
-            if succ_tc.in_space is not None \
-                    and not succ_tc.in_space(succ_locals):
+    for ep in edges:
+        g = ep.guard
+        if g is not None and not g(tv):
+            continue
+        flow, dep = ep.flow, ep.dep
+        out_copy = None if ep.ctl else task.data[ep.flow_index]
+        planned = ep.planned
+        succ_tc = ep.succ_tc
+        if succ_tc is None:
+            if not planned:
+                home_rank = _rank_of_data(ctx, dep, tv)
+                if home_rank is not None and home_rank != ctx.my_rank:
+                    # home tile lives on another rank: ship the final
+                    # version (the remote write-back path of
+                    # parsec_release_dep_fct)
+                    remote = ctx.remote_dep_accumulate(
+                        remote, task, flow, dep, None, None, home_rank)
+                    continue
+            _writeback(task, dep, out_copy, tv)
+            continue
+        targets = dep.target_params(tv)
+        if isinstance(targets, dict):
+            targets = (targets,)        # a range arrow gives a sequence
+        in_space, view = ep.in_space, ep.view
+        repo_ref = None
+        for succ_locals in targets:
+            sv = succ_locals if view is None else view(succ_locals)
+            if in_space is not None and not in_space(sv):
                 continue   # out-of-space edge: the generated bounds check
-            rank = _rank_of_task(ctx, succ_tc, succ_locals)
-            if rank is not None and rank != ctx.my_rank:
-                remote = ctx.remote_dep_accumulate(remote, t, flow, dep,
-                                                   succ_tc, succ_locals, rank)
-                continue
-            if tp.sim_enabled:
-                # PARSEC_SIM dates are rank-local (the reference's SIM mode
-                # is a shared-memory build): only successors that will
-                # execute here record a ready date — a remote entry would
-                # never be popped and the date would never ship anyway
-                skey = (succ_tc.name, succ_tc.make_key(succ_locals))
-                with tp._sim_lock:
-                    if t.sim_exec_date > tp._sim_ready.get(skey, 0.0):
-                        tp._sim_ready[skey] = t.sim_exec_date
-            fi, di = _find_input_dep(succ_tc, dep.target_flow, tc.name,
-                                     succ_locals)
-            repo_ref = None
+            if planned:
+                fi = ep.succ_fi
+                for bit, active in ep.cands:
+                    if active is None or active(sv):
+                        break
+                else:
+                    raise LookupError(
+                        f"{succ_tc.name}.{dep.target_flow}: no active "
+                        f"input dep from {tc.name}")
+                nplanned += 1
+            else:
+                rank = _rank_of_task(ctx, succ_tc, sv)
+                if rank is not None and rank != ctx.my_rank:
+                    remote = ctx.remote_dep_accumulate(
+                        remote, task, flow, dep, succ_tc, succ_locals, rank)
+                    continue
+                if tp.sim_enabled:
+                    # PARSEC_SIM dates are rank-local (the reference's SIM
+                    # mode is a shared-memory build): only successors that
+                    # will execute here record a ready date — a remote
+                    # entry would never be popped and the date would never
+                    # ship anyway
+                    skey = (succ_tc.name, succ_tc.make_key(succ_locals))
+                    with tp._sim_lock:
+                        if task.sim_exec_date > tp._sim_ready.get(skey, 0.0):
+                            tp._sim_ready[skey] = task.sim_exec_date
+                fi, di = _find_input_dep(succ_tc, dep.target_flow, tc.name,
+                                         sv)
+                bit = 0 if succ_tc.counted else 1 << succ_tc.dep_bit(fi, di)
             send = out_copy
             if out_copy is not None:
-                if entry is None:
-                    entry = tc.repo.lookup_and_create(t.key)
-                entry.set_output(flow.flow_index, out_copy)
-                repo_ref = (entry, flow.flow_index)
+                if repo_ref is None:
+                    if entry is None:
+                        entry = tc.repo.lookup_and_create(task.key)
+                    entry.set_output(ep.flow_index, out_copy)
+                    repo_ref = (entry, ep.flow_index)
                 nconsumers += 1
-                # typed edge: the consumer receives a lazy shared repack,
-                # not the producer's copy (read-side reshape)
-                send = reshape_for_edge(out_copy, dep,
-                                        succ_tc.flows[fi].deps_in[di])
-            pending.append((succ_tc, succ_locals, fi, di, send, repo_ref))
-
-    tc.iterate_successors(task, visitor)
+                if not planned:
+                    # typed edge: the consumer receives a lazy shared
+                    # repack, not the producer's copy (read-side reshape)
+                    send = reshape_for_edge(out_copy, dep,
+                                            succ_tc.flows[fi].deps_in[di])
+            pending.append((succ_tc, succ_locals, sv, fi, bit, send,
+                            repo_ref))
     if entry is not None:
         entry.addto_usage_limit(nconsumers)
     if remote is not None:
         ctx.remote_dep_activate(es, task, remote)
-    ready = ctx.deps.release_many(tp, pending) if pending else None
+    ready = None
+    if pending:
+        # always on, like the device module's counters: the edges released
+        # to local successors, and those of them a resolved plan carried
+        with ctx._release_count_lock:
+            ctx.release_edges += len(pending)
+            ctx.release_edges_planned += nplanned
+        ready = ctx.deps.release_many(tp, pending)
     h = _hooks[_RELEASE_DEPS_END]
     if h is not None:
         h(es, task)
@@ -436,7 +569,7 @@ def release_deps(es: ExecutionStream, task: Task) -> None:
         schedule_tasks(es, ready, 0)
 
 
-def _writeback(task: Task, flow, dep, out_copy) -> None:
+def _writeback(task: Task, dep, out_copy, view: Any) -> None:
     if out_copy is None or dep.data_ref is None:
         return
     if out_copy.device_index != 0:
@@ -445,7 +578,7 @@ def _writeback(task: Task, flow, dep, out_copy) -> None:
         # (jdf2c's pushout on a flow that writes to a collection)
         task.taskpool.context.devices.get(out_copy.device_index).pushout(
             out_copy)
-    dc, key = dep.data_ref(task.locals)
+    dc, key = dep.data_ref(view)
     out_copy = reshape_for_writeback(out_copy, dep, dc, key)
     apply_writeback_to_home(dc, key, out_copy,
                             owner=task.taskpool.taskpool_id)

@@ -224,6 +224,18 @@ class TaskClass:
         # release like the reference's generated bounds checks — C-syntax
         # JDFs lean on this (`(k < NT) ? T PING(k+1)` at k = NT-1)
         self.in_space: Callable[[dict], bool] | None = None
+        # what this class's locals-taking callables (guards, in_space,
+        # priority, target params, data refs) read an instance's locals
+        # through, when it is something a caller can build once per
+        # instance and hand to each of them in place of the dict: the PTG
+        # front-end sets its namespace constructor here.  None: the dict.
+        self.locals_view: Callable[[dict], Any] | None = None
+        # the release plan of this class's out-deps (runtime/scheduling.py:
+        # _plan_release), built at the class's first release in its pool
+        self._release_plan: Any = None
+        # (dependency tracker, whether it keeps this class's trackers in
+        # its dense index-array tier): DependencyTracking._indexed_eligible
+        self._indexed_memo: Any = None
         # static execution-space box ((lo, stop) per param) when every
         # range is locals-independent with unit step — enables the
         # index-array dep-storage variant (parsec_default_find_deps,
@@ -248,12 +260,17 @@ class TaskClass:
             self._keyget = lambda d: (g(d),)
         else:
             self._keyget = lambda d: ()
-        # precomputed (flow_index, dep_index) -> bit position (hot path)
+        # precomputed (flow_index, dep_index) -> bit position (hot path),
+        # and the input deps a predecessor task feeds with the mask bit of
+        # each: all that input_dep_mask has to look at
         self._dep_bits: dict[tuple[int, int], int] = {}
+        self._pred_in: list[tuple[int, Dep]] = []
         bit = 0
         for fi, f in enumerate(self.flows):
-            for di in range(len(f.deps_in)):
+            for di, d in enumerate(f.deps_in):
                 self._dep_bits[(fi, di)] = bit
+                if d.target_class is not None:
+                    self._pred_in.append((1 << bit, d))
                 bit += 1
 
     # -- keys ---------------------------------------------------------------
@@ -273,6 +290,13 @@ class TaskClass:
             return (UDKey(k, self.hash_struct),)
         return k
 
+    def view_of(self, locals_: dict) -> Any:
+        """What this class's locals-taking callables read ``locals_``
+        through (``locals_view``): built once by a caller that evaluates
+        several of them for one instance."""
+        view = self.locals_view
+        return locals_ if view is None else view(locals_)
+
     # -- dep structure ------------------------------------------------------
     @property
     def space_extents(self) -> tuple | None:
@@ -285,17 +309,15 @@ class TaskClass:
         """Bitmask of (flow_index, dep_index) input deps active for these
         locals — the per-task IN-dep mask (cf. ``parsec.c:1293``)."""
         mask = 0
-        bit = 0
-        for f in self.flows:
-            for d in f.deps_in:
-                if d.target_class is not None and d.active(locals_):
-                    # an active ranged dep whose range is EMPTY for these
-                    # locals expects zero arrivals: it must not gate
-                    # readiness (keeps the mask consistent with
-                    # input_dep_goal — the dependencies_goal protocol)
-                    if not d.ranged or d.each_target(locals_):
-                        mask |= 1 << bit
-                bit += 1
+        for bit, d in self._pred_in:
+            g = d.guard
+            if g is None or g(locals_):
+                # an active ranged dep whose range is EMPTY for these
+                # locals expects zero arrivals: it must not gate
+                # readiness (keeps the mask consistent with
+                # input_dep_goal — the dependencies_goal protocol)
+                if not d.ranged or d.each_target(locals_):
+                    mask |= bit
         return mask
 
     def input_dep_goal(self, locals_: dict) -> int:

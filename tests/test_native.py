@@ -94,6 +94,28 @@ def test_deptable_double_release_raises():
         t.release(9, 0b01, 0b11)
 
 
+def test_deptable_release_without_a_mask_never_creates_the_entry():
+    """Only the arrival that creates a tracker evaluates the task's mask: a
+    release that brings none (0) is answered ENTRY_MISSING while no entry
+    exists, and updates one that does like any other."""
+    t = native.NativeDepTable(64)
+    assert t.release(11, 0b01, 0) == native.ENTRY_MISSING
+    assert len(t) == 0
+    assert t.release(11, 0b01, 0b11) == 0       # created, with its mask
+    assert t.release(11, 0b10, 0) == 1          # ready through the entry
+    assert len(t) == 0
+    assert t.release(11, 0b10, 0) == native.ENTRY_MISSING
+
+
+def test_deptable_refuses_a_bit_the_task_does_not_wait_for():
+    t = native.NativeDepTable(64)
+    with pytest.raises(AssertionError, match="waits for"):
+        t.release(13, 0b100, 0b011)
+    t.release(13, 0b001, 0b011)
+    with pytest.raises(AssertionError, match="waits for"):
+        t.release(13, 0b100, 0)
+
+
 def test_deptable_threaded_stress():
     t = native.NativeDepTable(256)
     NKEYS, NBITS = 500, 8

@@ -415,6 +415,110 @@ def test_a_release_walks_each_edge_of_the_dag_once(
     assert (n["calls"], n["records"]) == (completions, edges)
 
 
+@pytest.mark.parametrize("make,p,ntasks,completions,edges", [
+    (_gemm, 16, 4096, 3840, 3840), (_potrf, 16, 816, 815, 2040),
+    (_potrf, 32, 5984, 5983, 16368)], ids=["gemm16", "potrf16", "potrf32"])
+def test_a_release_goes_by_plan_and_asks_each_successor_once(
+        accel_device, param, monkeypatch, make, p, ntasks, completions,
+        edges):
+    """What the release plan hoists, per cell graph: every edge handed to a
+    successor went through a resolved plan; a successor's required mask is
+    evaluated when its tracker is created, once a successor *task* and not
+    once an arrival; and a completion builds one namespace of the
+    completing task's locals and one of each successor's (16 a task before
+    the plan), which every guard, range, mask and priority then shares."""
+    from parsec_tpu.ptg import dsl
+    from parsec_tpu.runtime import scheduling
+    from parsec_tpu.runtime.deps import DependencyTracking
+    from parsec_tpu.runtime.task import TaskClass
+    n = {"masks": 0, "namespaces": 0, "releasing": 0}
+    release_many = DependencyTracking.release_many
+    input_dep_mask = TaskClass.input_dep_mask
+    complete_execution = scheduling.complete_execution
+
+    def releasing(fn):
+        def wrapped(*a, **kw):
+            n["releasing"] += 1
+            try:
+                return fn(*a, **kw)
+            finally:
+                n["releasing"] -= 1
+        return wrapped
+
+    def counted_mask(self, locals_):
+        n["masks"] += bool(n["releasing"])   # the start-up asks too
+        return input_dep_mask(self, locals_)
+
+    class CountedNS(dsl._NS):
+        def __init__(self, **kw):
+            n["namespaces"] += bool(n["releasing"])
+            super().__init__(**kw)
+
+    monkeypatch.setattr(DependencyTracking, "release_many",
+                        releasing(release_many))
+    monkeypatch.setattr(scheduling, "complete_execution",
+                        releasing(complete_execution))
+    monkeypatch.setattr(TaskClass, "input_dep_mask", counted_mask)
+    monkeypatch.setattr(dsl, "_NS", CountedNS)
+    before = dict(scheduling.release_totals)
+    _solve_counted(accel_device, param, monkeypatch, "lfq", make, p)
+    # the context's counters, as its teardown adds them to the process's
+    assert (scheduling.release_totals["edges"] - before["edges"],
+            scheduling.release_totals["planned"] - before["planned"]) == (
+        edges, edges)
+    assert n["masks"] == completions
+    assert n["namespaces"] <= ntasks + edges
+
+
+@pytest.mark.parametrize("fault,caught", [
+    ("dep_index", "not one the task waits for|are not ones the task waits"
+                  "|satisfied twice"),
+    ("flow_index", "Not equal to tolerance")])
+def test_a_plan_that_resolves_the_wrong_input_is_caught(
+        accel_device, param, monkeypatch, fault, caught):
+    """Planted faults in the release plan of the 16-panel Cholesky: a
+    candidate that names the input dep before its own sets a bit the
+    successor does not wait for or one another edge sets too, which the
+    dep-bit assertions of whichever tier holds the tracker refuse; GEMM's A and B operands resolved to
+    each other's flow arrive, make the task ready and give a wrong factor,
+    which the solve's answer shows."""
+    from parsec_tpu.runtime import scheduling
+    plan_edge = scheduling._plan_edge
+
+    def faulty(tp, tc, flow, dep):
+        ep = plan_edge(tp, tc, flow, dep)
+        if fault == "dep_index":
+            # the bit of the input dep declared before the one resolved
+            ep.cands = tuple((bit >> 1 or bit, g) for bit, g in ep.cands)
+        if fault == "flow_index" and dep.target_class == "GEMM" \
+                and dep.target_flow in ("A", "B"):
+            other = 1 - ep.succ_fi          # flows A and B are 0 and 1
+            ep.succ_fi = other
+            ep.cands = tuple((1 << ep.succ_tc.dep_bit(other, 0), g)
+                             for _, g in ep.cands)
+        return ep
+
+    # an assertion that fails inside a device batch's completions is
+    # reported by the module, which demotes itself; the solve then dies for
+    # want of a device
+    import logging
+    import re
+    from parsec_tpu.core.output import debug_stream
+    said = []
+    handler = logging.Handler()
+    handler.emit = lambda record: said.append(record.getMessage())
+    debug_stream._log.addHandler(handler)
+    monkeypatch.setattr(scheduling, "_plan_edge", faulty)
+    try:
+        with pytest.raises(Exception) as exc:
+            _solve_counted(accel_device, param, monkeypatch, "lfq", _potrf,
+                           16)
+    finally:
+        debug_stream._log.removeHandler(handler)
+    said.append(str(exc.value))
+    assert re.search(caught, "\n".join(said)), said
+
+
 @pytest.mark.parametrize("make,p,ntasks,calls", [
     (_potrf, 16, 816, 227), (_gemm, 8, 512, 64)], ids=["potrf16", "gemm8"])
 def test_a_batch_never_passes_device_tpu_batch_max(

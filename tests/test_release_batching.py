@@ -201,3 +201,151 @@ def test_a_release_pays_one_lock_a_class_group_and_one_schedule_a_batch(
     # the first layer is scheduled by the start-up, every other task here
     assert n["schedule_calls"] == n["ready_batches"]
     assert n["scheduled"] == completions
+
+
+# --------------------------------------------------------------------------
+# the release plan's fallbacks: edges the plan cannot resolve ahead
+# --------------------------------------------------------------------------
+
+N_FB = 4
+
+
+def _fallback_pool(case, rank, nranks, seen):
+    """``S(i)`` adds one to tile ``A(i)`` and feeds ``U(i)`` (beside it) and
+    ``T(i)`` (on the next tile's rank); each consumer records what it read.
+    ``case`` adds the one thing the release plan cannot state ahead."""
+    import numpy as np
+
+    from parsec_tpu.data.data import TileType
+    from parsec_tpu.data_dist.matrix import TwoDimBlockCyclic
+    A = TwoDimBlockCyclic(
+        f"A{case}", lm=N_FB * 8, ln=1, mb=8, nb=1, P=nranks, Q=1,
+        myrank=rank, init_fn=lambda m, n, sh: np.full(sh, m, np.float32))
+    p = ptg.PTGBuilder(f"fb_{case}", A=A, N=N_FB)
+    s = p.task("S", i=ptg.span(0, lambda g, l: g.N - 1))
+    s.affinity("A", lambda g, l: (l.i, 0))
+    fv = s.flow("V", ptg.RW)
+    fv.input(data=("A", lambda g, l: (l.i, 0)))
+    fv.output(succ=("U", "V", lambda g, l: {"i": l.i}))
+    fv.output(succ=("T", "V", lambda g, l: {"i": l.i}),
+              dtt=TileType((2, 4), np.float32) if case == "typed" else None)
+
+    def sbody(es, task, g, l):
+        c = task.flow_data("V")
+        c.value = np.asarray(c.value) + 1
+        c.version += 1
+
+    s.body(sbody)
+    if case == "sim":
+        s.simcost(lambda g, l: l.i + 1)
+    for name, shift in (("U", 0), ("T", 1)):
+        c = p.task(name, i=ptg.span(0, lambda g, l: g.N - 1))
+        c.affinity("A", lambda g, l, shift=shift: ((l.i + shift) % g.N, 0))
+        c.flow("V", ptg.READ).input(pred=("S", "V", lambda g, l: {"i": l.i}))
+        if case in ("ranged", "counted") and name == "T":
+            c.flow("c", ptg.CTL).output(succ=("J", "c", lambda g, l: {"z": 0}))
+        c.body(lambda es, task, g, l, name=name: seen.append(
+            (name, l.i, np.asarray(task.flow_data("V").value).copy())))
+    if case in ("ranged", "counted"):
+        # a join: one declared CTL dep that waits for every T(i)
+        j = p.task("J", z=ptg.span(0, 0))
+        j.affinity("A", lambda g, l: (0, 0))
+        j.flow("c", ptg.CTL).input(
+            pred=("T", "c", lambda g, l: [{"i": i} for i in range(g.N)]),
+            ranged=True)
+        if case == "counted":
+            # and a plain dep of the same, counted, class
+            u = [tcb for tcb in p._classes if tcb.name == "U"][0]
+            u.flow("d", ptg.CTL).output(
+                succ=("J", "d", lambda g, l: {"z": 0}),
+                guard=lambda g, l: l.i == 0)
+            j.flow("d", ptg.CTL).input(pred=("U", "d", lambda g, l: {"i": 0}))
+        j.body(lambda es, task, g, l: seen.append(("J", len(seen), None)))
+    return p.build()
+
+
+def _check_fallback(case, seen):
+    import numpy as np
+    got = {(name, i): v for name, i, v in seen if name != "J"}
+    assert sorted(got) == [(n, i) for n in ("T", "U") for i in range(N_FB)]
+    for (name, i), v in got.items():
+        want = np.full((8, 1), i + 1, np.float32)
+        if case == "typed" and name == "T":
+            want = want.reshape(2, 4)       # the consumer's declared type
+        np.testing.assert_array_equal(v, want)
+    if case in ("ranged", "counted"):
+        # the join ran once, after every T(i) and (counted) after U(0)
+        (at,) = [n for name, n, _ in seen if name == "J"]
+        order = [name for name, _, _ in seen[:at]]
+        assert order.count("T") == N_FB
+        assert case == "ranged" or ("U", 0) in [
+            (name, i) for name, i, _ in seen[:at]]
+
+
+@pytest.mark.parametrize("case,edges,planned", [
+    ("plain", 8, 8), ("ranged", 12, 8), ("counted", 13, 8), ("typed", 8, 4),
+    ("sim", 8, 0), ("two_rank", 4, 0)])
+def test_an_edge_the_plan_cannot_resolve_takes_the_general_walk(
+        param, case, edges, planned):
+    """The release plan resolves an edge ahead only where nothing more can
+    happen on it.  A counted successor (a ranged arrow into it, or a plain
+    dep of the same class), a type on the edge, a simulated pool and a pool
+    on two ranks keep the per-edge walk for those edges, give the answers
+    they always gave, and say so in the counters: of ``release_edges``
+    handed to local successors, ``release_edges_planned`` went by plan."""
+    param("runtime_dag_compile", False)
+    seen = []
+    if case == "two_rank":
+        from parsec_tpu.comm import run_multirank
+
+        def body(ctx, rank, nranks):
+            mine = []
+            ctx.add_taskpool(_fallback_pool(case, rank, nranks, mine))
+            ctx.wait(timeout=60)
+            ctx.comm_barrier()
+            return mine, ctx.release_edges, ctx.release_edges_planned
+
+        res = run_multirank(2, body)
+        seen = res[0][0] + res[1][0]
+        # S(i) -> U(i) stays on the rank, S(i) -> T(i) crosses to the other
+        assert [r[1] for r in res] == [edges // 2] * 2
+        counts = (sum(r[1] for r in res), sum(r[2] for r in res))
+    else:
+        tp = _fallback_pool(case, 0, 1, seen)
+        ctx = Context(nb_cores=0)
+        ctx.add_taskpool(tp)
+        ctx.wait(timeout=60)
+        ctx.fini()
+        counts = (ctx.release_edges, ctx.release_edges_planned)
+        if case == "sim":
+            assert tp.largest_simulation_date == N_FB
+    _check_fallback(case, seen)
+    assert counts == (edges, planned)
+    assert (planned < edges) == (case != "plain")
+
+
+def test_eight_streams_count_every_edge_they_release(param):
+    """The release counters are one pair a context and every stream adds to
+    them: eight workers on an eight-core machine's worth of interpreter
+    switches (the interval shortened a thousandfold) release a 40 x 50 grid's
+    3,900 edges, all by plan, and the counters hold exactly that, which one
+    lost update would break."""
+    import sys
+    param("runtime_dag_compile", False)
+    layers, width = 40, 50
+    in_edges = {(d, n): [n, (n + 1) % width]
+                for d in range(1, layers) for n in range(width)}
+    log, lock = [], threading.Lock()
+    tp = _build_pool(in_edges, layers, width, log, lock)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(interval / 1000)
+    try:
+        ctx = Context(nb_cores=8)
+        ctx.add_taskpool(tp)
+        ctx.wait(timeout=120)
+        ctx.fini()
+    finally:
+        sys.setswitchinterval(interval)
+    assert len(log) == layers * width == len(set(log))
+    assert (ctx.release_edges, ctx.release_edges_planned) == (
+        2 * (layers - 1) * width,) * 2
