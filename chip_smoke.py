@@ -351,50 +351,34 @@ def stage_dynamic_cholesky(cfg) -> str:
 
 
 def stage_dtd_gemm(cfg) -> str:
-    """GEMM tasks inserted at run time, hazards discovered from the tile
-    access chains, bodies resolved by kernel name."""
-    import parsec_tpu.ops.gemm  # noqa: F401  registers the "gemm" kernels
-    from parsec_tpu.dtd import INOUT, INPUT, DTDTaskpool
+    """GEMM tasks inserted at run time by the library's insertion program
+    (``tiled_gemm_dtd``), hazards discovered from the tile access chains,
+    bodies resolved by kernel name, every result tile pushed out at its
+    last k."""
+    from parsec_tpu.dtd import DTDTaskpool
+    from parsec_tpu.models.tiled_gemm import tiled_gemm_dtd
     from parsec_tpu.runtime import Context
 
     n, nb = cfg.n_dtd, cfg.nb_dynamic
     nt = n // nb
     A, B, C = gemm_operands(cfg.seed, n, nb, np.float32, salt=1)
-    a = [[A.data_of(m, k).get_copy(0).value for k in range(nt)]
-         for m in range(nt)]
-    b = [[B.data_of(k, j).get_copy(0).value for j in range(nt)]
-         for k in range(nt)]
-    c = [[np.zeros((nb, nb), np.float32) for _ in range(nt)]
-         for _ in range(nt)]
-
-    def gemm(x, y, z):          # the host incarnation; must not run here
-        z += x @ y
-
     ledger = DeviceLedger()
+    pushed0 = sum(d.pushouts for d in accelerators())
     ctx = Context()
     tp = DTDTaskpool()
     ctx.add_taskpool(tp)
-    for m in range(nt):
-        for j in range(nt):
-            for k in range(nt):
-                tp.insert_task(gemm, (a[m][k], INPUT), (b[k][j], INPUT),
-                               (c[m][j], INOUT), tpu_kernel="gemm")
+    tiled_gemm_dtd(tp, A, B, C)
     tp.wait(timeout=cfg.timeout)
     sync_all()
-    per_dev = ledger.check(nt ** 3)
-    settle()
-    X = probes(cfg.seed, n)
-    got = np.zeros_like(X)
-    for m in range(nt):
-        for j in range(nt):
-            tile = np.asarray(tp.tile_of_array(c[m][j]).data
-                              .newest_copy().value).astype(np.float64)
-            got[m * nb:(m + 1) * nb] += tile @ X[j * nb:(j + 1) * nb]
     ctx.fini()
-    res = rel_residual(got, tiled_apply(A, tiled_apply(B, X)))
+    per_dev = ledger.check(nt ** 3)
+    pushed = sum(d.pushouts for d in accelerators()) - pushed0
+    require(pushed == nt * nt, f"{pushed} push-outs for {nt * nt} tiles")
+    settle()
+    res = gemm_residual(cfg.seed, A, B, C)
     require(res < TOL_F32_DEFAULT_PRECISION, f"residual {res:.3e}")
     return (f"N={n} nb={nb} f32 tasks={nt ** 3} per_device={per_dev} "
-            f"cpu_tasks=0 residual={res:.3e}")
+            f"cpu_tasks=0 pushouts={pushed} residual={res:.3e}")
 
 
 def stage_server(cfg) -> str:

@@ -15,6 +15,11 @@ Rebuild of ``parsec/interfaces/dtd/insert_function.c`` (SURVEY §2.8, §3.6):
   (``parsec_execute_and_come_back``, ``insert_function.c:570``).
 - ``data_flush`` — inserts a flush task pushing the final tile version back
   to its home copy/rank (``parsec_dtd_data_flush.c``).
+- ``PUSHOUT`` — a written flow inserted with the flag is final when its task
+  completes: ``release_task`` has the accelerator that holds the copy start
+  its transfer home at once (``scheduling.start_home``, the call a PTG's
+  memory edge makes), so the flush only collects it.  An untagged tile pays
+  the synchronous read at the flush.
 
 TPU-first notes: a task body may carry a TPU incarnation (a kernel-registry
 name) next to the Python host body, exactly like the reference's per-chore
@@ -26,6 +31,7 @@ convention) which the engine writes back to the tile copy.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Callable
 
 import numpy as np
@@ -33,9 +39,9 @@ import numpy as np
 from ..core.params import params as _params
 from ..data.data import (ACCESS_READ, ACCESS_RW, ACCESS_WRITE, DataCopy,
                          data_create)
-from ..prof import pins
+from ..prof import pins, spans
 from ..prof.pins import PinsEvent
-from ..runtime.scheduling import schedule_tasks
+from ..runtime.scheduling import schedule_tasks, start_home
 from ..runtime.task import (DEV_CPU, DEV_TPU, HOOK_RETURN_DONE, Chore, Flow,
                             Task, TaskClass)
 from ..runtime.taskpool import Taskpool
@@ -55,8 +61,8 @@ REF = 0x40          # pass the object reference untracked
 
 AFFINITY = 0x100    # this argument's tile decides the executing rank
 DONT_TRACK = 0x200  # do not thread dependencies through this argument
-PUSHOUT = 0x400     # eagerly push the written tile back to its home
-PULLIN = 0x800      # eagerly pull the tile to the executing device
+PUSHOUT = 0x400     # this written version is final: start it home at release
+PULLIN = 0x800      # accepted and read by nothing: stage-in pulls every tile
 
 _params.register("dtd_window_size", 2048,
                  "max in-flight inserted tasks before the inserter "
@@ -66,6 +72,18 @@ _params.register("dtd_threshold_size", 1024,
                  "(parsec_dtd_threshold_size)")
 
 _MAX_TASK_CLASSES = 25  # PARSEC_DTD_NB_TASK_CLASSES (insert_function_internal.h:31)
+
+_now = time.perf_counter_ns
+
+# always on, like the device module's counters, per pool (the attributes of
+# the same names, less the prefix) and here as the process's totals over the
+# pools that have terminated (a benchmark's solves are a pool each):
+# tasks inserted, times the window made an inserter execute and come back,
+# tasks that had completed when wait() closed the insertion, and written
+# flows inserted with PUSHOUT
+dtd_totals = {"dtd_inserted": 0, "dtd_window_drives": 0,
+              "dtd_tasks_in_window": 0, "dtd_pushouts_flagged": 0}
+_totals_lock = threading.Lock()
 
 # concurrency contracts, enforced by analysis.runtimelint (docs/ANALYSIS.md):
 # accessor chains mutate under the tile's _lock, per-task dep state under
@@ -83,6 +101,9 @@ _LOCK_PROTECTED = {
     "DTDTaskpool._arrivals": "_alock",
     "DTDTaskpool._insert_seq": "_insert_lock",
     "DTDTaskpool._inflight": "_icond",
+    "DTDTaskpool.window_drives": "_icond",
+    "DTDTaskpool.inserted": "_insert_lock",
+    "DTDTaskpool.pushouts_flagged": "_insert_lock",
     "DTDTask.successors": "_dlock",
     "DTDTask.push_records": "_dlock",
     "DTDTask.deps_pending": "_dlock",
@@ -313,6 +334,11 @@ class DTDTaskpool(Taskpool):
         self._closed = False
         self.window_size = _params.get("dtd_window_size")
         self.threshold_size = _params.get("dtd_threshold_size")
+        # what dtd_totals sums (local tasks; shells are not counted)
+        self.inserted = 0
+        self.window_drives = 0
+        self.tasks_in_window: int | None = None    # set by close()
+        self.pushouts_flagged = 0
         # -- cross-rank state (shells + push/arrival protocol) --------------
         self._insert_seq = 0
         self._arrivals: dict[tuple, _Arrival] = {}
@@ -343,6 +369,10 @@ class DTDTaskpool(Taskpool):
             # then); end-of-insertion is the first structurally-complete
             # moment (tasks may already have run — checks are read-only)
             self.validate()
+        if not self._closed:
+            # what of the execution ran under discovery and not after it
+            with self._icond:
+                self.tasks_in_window = self.inserted - self._inflight
         self._closed = True
         if self._armed:
             self._armed = False
@@ -364,6 +394,25 @@ class DTDTaskpool(Taskpool):
         """``parsec_dtd_taskpool_wait``: no more insertions; drain."""
         self.close()
         super().wait(timeout)
+
+    def terminated(self) -> None:
+        super().terminated()
+        # every task has run: the accessor chains are the last references to
+        # them (each A and B tile's last_users lists every GEMM that read it),
+        # and through task.taskpool they close a cycle with this pool, which
+        # would keep a solve's 4,096 tasks for the cyclic collector to find
+        # (65,695 tracked objects against the PTG twin's 6,121)
+        with self._tlock:
+            tiles = list(self._tiles.values())
+        for tile in tiles:
+            with tile._lock:
+                tile.last_writer = None
+                tile.last_users = []
+        with _totals_lock:
+            dtd_totals["dtd_inserted"] += self.inserted
+            dtd_totals["dtd_window_drives"] += self.window_drives
+            dtd_totals["dtd_tasks_in_window"] += self.tasks_in_window or 0
+            dtd_totals["dtd_pushouts_flagged"] += self.pushouts_flagged
 
     # ----------------------------------------------------------------- tiles
     def tile_of(self, dc: Any, *key) -> DTDTile:
@@ -447,9 +496,14 @@ class DTDTaskpool(Taskpool):
         """
         if self.context is None:
             raise RuntimeError("taskpool not enqueued in a context")
+        # the plane's dtd.insert: a counter per task, no span; the window
+        # drive below is dtd.window's
+        t0 = _now() if spans.phase_on else 0
         with self._insert_lock:
             task = self._insert_task_locked(body, args, name, priority,
                                             tpu_kernel, _rank)
+        if t0:
+            spans.phase_add("dtd.insert", _now() - t0)
         # backpressure OUTSIDE the insert lock: a blocked inserter must not
         # stop worker bodies (which may themselves insert) from completing
         # tasks — that would hold _inflight above the threshold forever
@@ -489,6 +543,7 @@ class DTDTaskpool(Taskpool):
             task.is_shell = task.rank != self.context.my_rank
         if not task.is_shell:
             self.tdm.taskpool_addto_nb_tasks(+1)
+            self.inserted += 1
             with self._icond:
                 self._inflight += 1
 
@@ -503,6 +558,9 @@ class DTDTaskpool(Taskpool):
                 continue
             tile: DTDTile = spec.obj
             task.tiles[spec.flow_index] = tile
+            if spec.flags & PUSHOUT and spec.mode & ACCESS_WRITE \
+                    and not task.is_shell:
+                self.pushouts_flagged += 1
             if spec.flags & DONT_TRACK:
                 if not task.is_shell:
                     self._attach_tile_copy(task, spec, tile)
@@ -730,10 +788,12 @@ class DTDTaskpool(Taskpool):
     # ------------------------------------------------------------ completion
     def release_task(self, es: Any, task: DTDTask) -> None:
         """``complete_hook_of_dtd`` → ``dtd_release_dep_fct``: bump written
-        tile versions, ship cross-rank pushes, release instance successors,
-        notify the window.  Pushes snapshot *before* successors are released
-        — a successor writer mutating the host tile in place cannot corrupt
-        an in-flight payload (the WAR discipline of the shell protocol)."""
+        tile versions, start a ``PUSHOUT`` flow's transfer home where its
+        copy lies on an accelerator, ship cross-rank pushes, release instance
+        successors, notify the window.  Pushes snapshot *before* successors
+        are released — a successor writer mutating the host tile in place
+        cannot corrupt an in-flight payload (the WAR discipline of the shell
+        protocol)."""
         pins.fire(PinsEvent.RELEASE_DEPS_BEGIN, es, task)
         for spec in task.args:
             if spec.flow_index < 0 or spec.flags & SCRATCH:
@@ -742,6 +802,8 @@ class DTDTaskpool(Taskpool):
                 copy = task.data[spec.flow_index]
                 if copy is not None:
                     copy.version += 1
+                    if spec.flags & PUSHOUT:
+                        start_home(self.context, copy)
         with task._dlock:
             task.completed = True
             succs = list(task.successors)
@@ -771,9 +833,17 @@ class DTDTaskpool(Taskpool):
         thread with workers), or — when the inserter IS a worker running a
         task body (recursive discovery) — executes-and-comes-back on its
         own stream: parking it would strand its unfinished task, and with
-        every worker inserting at once nothing could ever drain."""
+        every worker inserting at once nothing could ever drain.  Each
+        engagement is one ``dtd.window`` span of the phase plane and one
+        ``window_drives``."""
         if self._inflight <= self.window_size:
             return
+        with self._icond:
+            self.window_drives += 1
+        with spans.phase("dtd.window"):
+            self._execute_and_come_back()
+
+    def _execute_and_come_back(self) -> None:
         ctx = self.context
         if not ctx.started:
             # insertion demands progress: release parked workers (the

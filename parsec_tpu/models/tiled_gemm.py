@@ -9,6 +9,8 @@ Two execution paths, by design (TPU-first):
 1. :func:`tiled_gemm_ptg` — the dynamic-runtime path: a PTG taskpool
    GEMM(m,n,k) whose C-flow chains along k; tiles stage into HBM through the
    TPU device module; correctness/irregular-shape path.
+   :func:`tiled_gemm_dtd` is the same graph through the other front end:
+   the reference harness's insertion program.
 2. :func:`tiled_gemm_fused` — the compiled path: the same dataflow lowered to
    one XLA program (single chip: one MXU-tiled matmul; multi-chip: shard_map
    over a mesh in :mod:`parsec_tpu.parallel`).  On TPU the compiler's
@@ -27,6 +29,7 @@ import numpy as np
 
 from .. import ptg
 from ..data_dist.matrix import TiledMatrix
+from ..dtd import AFFINITY, INOUT, INPUT, PUSHOUT
 from ..ops import gemm as gemm_ops
 
 
@@ -71,6 +74,38 @@ def tiled_gemm_ptg(A: TiledMatrix, B: TiledMatrix, C: TiledMatrix,
 
 def _cpu_wrap(es: Any, task: Any, g: Any, l: Any) -> None:
     gemm_ops.gemm_cpu_body(es, task)
+
+
+def _gemm_dtd_cpu(a: np.ndarray, b: np.ndarray, c: np.ndarray) -> None:
+    """The host incarnation of a DTD GEMM task: its arguments arrive unpacked
+    in insertion order, and C is updated in place."""
+    c += a.astype(np.float32) @ b.astype(np.float32)
+
+
+def tiled_gemm_dtd(tp: Any, A: TiledMatrix, B: TiledMatrix,
+                   C: TiledMatrix) -> int:
+    """C += A·B as PaRSEC's own DTD harness inserts it
+    (``tests/dsl/dtd/dtd_test_simple_gemm.c``): for every C tile, in (m, n)
+    order, one GEMM per k with A and B ``INPUT`` and C ``INOUT | AFFINITY``;
+    the last k of a tile carries ``PUSHOUT``, so its result starts home when
+    that task completes.  ``tp`` is a :class:`DTDTaskpool` already enqueued
+    in a context; the DAG is what the insertion order and the access modes
+    give.  Returns the number of tasks inserted.  The accelerator runs the
+    ``"gemm"`` kernel :func:`tiled_gemm_ptg` names, the host
+    :func:`_gemm_dtd_cpu`."""
+    MT, NT, KT = C.mt, C.nt, A.nt
+    assert A.mt == MT and B.nt == NT and B.mt == KT
+    tile_of, insert = tp.tile_of, tp.insert_task
+    for m in range(MT):
+        for n in range(NT):
+            for k in range(KT):
+                last = PUSHOUT if k == KT - 1 else 0
+                insert(_gemm_dtd_cpu,
+                       (tile_of(A, m, k), INPUT),
+                       (tile_of(B, k, n), INPUT),
+                       (tile_of(C, m, n), INOUT | AFFINITY | last),
+                       name="GEMM", tpu_kernel="gemm")
+    return MT * NT * KT
 
 
 def tiled_gemm_recursive_ptg(A: TiledMatrix, B: TiledMatrix, C: TiledMatrix,

@@ -569,15 +569,23 @@ def release_deps(es: ExecutionStream, task: Task) -> None:
         schedule_tasks(es, ready, 0)
 
 
+def start_home(ctx: Any, copy: Any) -> None:
+    """The graph says this written version is final: where it lies on an
+    accelerator, its device starts the transfer home now, under the rest of
+    the solve (:meth:`TPUDevice.pushout`).  The one place both front ends
+    decide it: a PTG's active output dep to a collection, walked by
+    :func:`release_deps`, and a DTD flow inserted with ``PUSHOUT``, at
+    ``DTDTaskpool.release_task``."""
+    if copy.device_index != 0:
+        ctx.devices.get(copy.device_index).pushout(copy)
+
+
 def _writeback(task: Task, dep, out_copy, view: Any) -> None:
     if out_copy is None or dep.data_ref is None:
         return
-    if out_copy.device_index != 0:
-        # the memory edge of a tile that lives on an accelerator: this
-        # version is final, so its device starts the transfer home now
-        # (jdf2c's pushout on a flow that writes to a collection)
-        task.taskpool.context.devices.get(out_copy.device_index).pushout(
-            out_copy)
+    # the memory edge (jdf2c's pushout on a flow that writes to a
+    # collection)
+    start_home(task.taskpool.context, out_copy)
     dc, key = dep.data_ref(view)
     out_copy = reshape_for_writeback(out_copy, dep, dc, key)
     apply_writeback_to_home(dc, key, out_copy,

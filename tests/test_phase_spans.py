@@ -200,3 +200,56 @@ def test_under_a_tight_budget_the_pressure_has_its_own_span(one_accelerator,
     assert table["devmod.inflight_wait"][2] >= dev.pressure_confirms
     owned = sum(row[0] for row in table.values()) / 1e9
     assert 0.9 * wall <= owned <= wall, (owned, wall)
+
+
+# the DTD front end's two rows (PR 34), on top of the ones above
+DTD_DOCUMENTED = {"dtd.insert", "dtd.window"}
+
+
+def test_a_dtd_solve_owns_its_insertion_and_its_window_drives(
+        one_accelerator, param):
+    """``dtd.insert`` is a counter per inserted task, outside any span on the
+    client's thread; ``dtd.window`` one inclusive span per
+    execute-and-come-back with ``ctx.progress`` and the device module's spans
+    nested in it, so its self time is next to nothing and the solve's seconds
+    stay owned."""
+    from parsec_tpu.dtd import DTDTaskpool
+    from parsec_tpu.models.tiled_gemm import tiled_gemm_dtd
+    rng = np.random.default_rng(34)
+    a = rng.standard_normal((N, N)).astype(np.float32)
+    colls = (TiledMatrix.from_dense("A", a, NB, NB),
+             TiledMatrix.from_dense("B", a.T.copy(), NB, NB),
+             TiledMatrix("C", N, N, NB, NB))
+    param("dtd_window_size", 64)
+    param("dtd_threshold_size", 32)
+    param("prof_spans", True)
+    try:
+        t0 = time.perf_counter()
+        ctx = Context(nb_cores=0)
+        (dev,) = [d for d in registry.devices if isinstance(d, TPUDevice)]
+        tp = DTDTaskpool()
+        ctx.add_taskpool(tp)
+        tiled_gemm_dtd(tp, *colls)
+        tp.wait(timeout=120)
+        dev.sync()
+        dev.flush_cache()
+        ctx.fini()
+        wall = time.perf_counter() - t0
+    finally:
+        param("prof_spans", False)
+        spans.uninstall()
+    table = spans.phase_totals()
+    assert DTD_DOCUMENTED <= set(table) <= DOCUMENTED | DTD_DOCUMENTED
+    assert table["dtd.insert"][2] == tp.inserted == NT ** 3
+    assert table["dtd.insert"][0] == table["dtd.insert"][1]     # no child
+    assert table["dtd.window"][2] == tp.window_drives >= 1
+    # each drive, and the wait, is one ctx.progress; the drives' lie inside
+    # dtd.window, whose own time is what is left around them
+    assert table["ctx.progress"][2] == tp.window_drives + 1
+    self_ns, inclusive_ns, _ = table["dtd.window"]
+    assert 0 <= self_ns <= 0.05 * inclusive_ns
+    assert inclusive_ns < table["ctx.progress"][1]
+    assert table["sched.release"][2] == NT ** 3
+    assert table["devmod.pushout"][2] == NT * NT
+    owned = sum(row[0] for row in table.values()) / 1e9
+    assert 0.8 * wall <= owned <= wall, (owned, wall)

@@ -32,8 +32,11 @@ def test_manifest_lists_the_flood_metric_on_every_dynamic_cell(
     (m,) = [m for m in bench["per_layer"] if m["name"] == name]
     assert (m["unit"], m["better"], m["source"]) == (unit, "lower", source)
     assert (m["layer"], m["moves"]) == ("host scheduler", "dynamic.gflops")
+    # every cell whose solves go through the device module's flood: the
+    # dynamic cells and, since PR 34, the DTD cell
     assert m["workloads"] == [w["name"] for w in bench["workloads"]
-                              if w["traffic"] == "dynamic_host_tiles"]
+                              if w["traffic"] in ("dynamic_host_tiles",
+                                                  "dtd_host_tiles")]
 
 
 def _dev(**kw):
