@@ -28,10 +28,13 @@ SURVEY §2.5, §3.5) redesigned around XLA's execution model:
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import OrderedDict, deque
 from typing import Any, Callable
+
+import numpy as np
 
 from ..core.params import params as _params
 from ..data.data import (ACCESS_WRITE, COHERENCY_EXCLUSIVE, COHERENCY_INVALID,
@@ -53,9 +56,6 @@ _params.register("device_tpu_batch", True,
                  "stack same-class pending tasks into one vmapped dispatch")
 _params.register("device_tpu_batch_max", 64,
                  "largest task batch a single vmapped dispatch may service")
-_params.register("device_tpu_prefetch", 8,
-                 "stage-in this many queued tasks ahead of dispatch "
-                 "(H2D overlaps in-flight compute; 0 disables)")
 _params.register("device_tpu_allow_cpu", False,
                  "register host CPU jax devices as accelerators, so the "
                  "device path (stage-in, LRU, batched dispatch) runs "
@@ -184,8 +184,7 @@ class TPUDevice(Device):
         # so D2H never blocks the manager mid-pipeline.  _evict_bytes
         # tracks their still-live buffers: residency may exceed the budget
         # by one batch's eviction volume until the drain (the budget is
-        # advisory — XLA owns physical HBM), and the prefetch guard reads
-        # the SUM so lookahead can't pile onto undrained victims.
+        # advisory — XLA owns physical HBM).
         self._evict_q: deque[DataCopy] = deque()
         self._evict_bytes = 0
         self.deferred_evictions = 0
@@ -398,7 +397,6 @@ class TPUDevice(Device):
 
     def _writeback(self, copy: DataCopy) -> None:
         """Push a dirty device copy back to the host copy, then drop it."""
-        import numpy as np
         d = copy.original
         if copy.coherency in (COHERENCY_OWNED, COHERENCY_EXCLUSIVE):
             host = d.get_copy(0)
@@ -453,12 +451,11 @@ class TPUDevice(Device):
 
     def stage_in_many(self, tasks: list[Any]) -> None:
         """Batched stage-in: resolve every task's misses first, then move
-        them in ONE ``jax.device_put`` call (PJRT batches the transfers
-        under a single enqueue).  Duplicate tiles across the batch stage
-        once; a hit re-inserted into the LRU resurrects an evicted-but-
-        not-yet-written-back victim (the pending w2r skips anything back
-        in the LRU)."""
-        import jax
+        them in one :meth:`_transfer` (the copies are made one after the
+        other; each returns once PJRT has the bytes and crosses behind the
+        next).  Duplicate tiles across the batch stage once; a hit
+        re-inserted into the LRU resurrects an evicted-but-not-yet-written-
+        back victim (the pending w2r skips anything back in the LRU)."""
         assigns: list[tuple[Any, int, Any]] = []   # (task, flow_idx, datum)
         missing: dict[Any, DataCopy] = {}          # datum -> source copy
         for task in tasks:
@@ -497,8 +494,7 @@ class TPUDevice(Device):
             return
         keys = list(missing)
         self._make_room(sum(_copy_nbytes(c) for c in missing.values()))
-        values = jax.device_put([missing[k].value for k in keys],
-                                self.jax_device)
+        values = self._transfer([missing[k].value for k in keys])
         landed: dict[Any, DataCopy] = {}
         batch_nb = 0
         for k, value in zip(keys, values):
@@ -524,6 +520,26 @@ class TPUDevice(Device):
             # lands above — a KeyError here is a real landing bug
             task.data[fi] = landed[k]
 
+    def _transfer(self, values: list) -> list:
+        """The host-to-device copy itself, for ``stage_in_many`` and
+        ``prefetch_data``.  A numpy tile goes straight to the call
+        ``jax.device_put`` ends in (:func:`_host_put`).  What
+        ``jax.device_put`` does around that call costs 76-86 us a tile of
+        Python on the chip's host, against 165-190 us for the call itself
+        (PERF.md, PR 35), and decides nothing here, where shape, dtype and
+        placement are those of the tile before."""
+        jd = self.jax_device
+        put = _host_put()
+        out = [put(v, jd) for v in values] if put is not None \
+            else [None] * len(values)
+        rest = [i for i, o in enumerate(out) if o is None]
+        if rest:
+            import jax
+            for i, o in zip(rest, jax.device_put([values[i] for i in rest],
+                                                 jd)):
+                out[i] = o
+        return out
+
     def prefetch_data(self, datas: list[Any]) -> int:
         """Data-grain prefetch (ISSUE 11): stage host-resident datums
         back into the device tier AHEAD of the tasks that will read
@@ -531,10 +547,9 @@ class TPUDevice(Device):
         the wavefront, so a paged-out stream re-enters decode without a
         synchronous stage-in stall.  Advisory and idempotent: datums
         with a current device copy are skipped, everything else moves
-        in one async ``jax.device_put`` that overlaps whatever the
-        manager is dispatching; a racing stage-in of the same datum
-        lands identical bytes at the same version.  Unlike the queue
-        lookahead (``_prefetch_upcoming``), this MAY evict: the caller
+        in one :meth:`_transfer` on the caller's thread, beside whatever
+        the manager is dispatching; a racing stage-in of the same datum
+        lands identical bytes at the same version.  It MAY evict: the caller
         asserts the datums are the next wavefront's inputs, so trading
         colder residents for them is the point of the call — but each
         call stages at most HALF the byte budget, leaving the in-flight
@@ -542,7 +557,6 @@ class TPUDevice(Device):
         working set then pays one overlapped transfer sweep per
         iteration instead of degenerating into prefetch-vs-dispatch
         thrash).  Returns the number of datums staged."""
-        import jax
         cap = self._mem_budget // 2
         todo: list[tuple[Any, DataCopy, int, Any]] = []
         for d in datas:
@@ -565,8 +579,7 @@ class TPUDevice(Device):
         if not todo:
             return 0
         with _Wall(self, "t_stage_in", "devmod.prefetch"):
-            values = jax.device_put([v for _, _, _, v in todo],
-                                    self.jax_device)
+            values = self._transfer([v for _, _, _, v in todo])
             nb_total = 0
             staged = 0
             for (d, host, snap_ver, _sv), value in zip(todo, values):
@@ -633,7 +646,6 @@ class TPUDevice(Device):
                     if _params.get("device_tpu_batch"):
                         with spans.phase("sched.flood"):
                             self._flood_from_scheduler(batch)
-                    self._prefetch_upcoming()
                     self._run_batch(batch)
                     self._drain_evictions()   # w2r: D2H post-dispatch
                 except Exception as e:
@@ -715,31 +727,6 @@ class TPUDevice(Device):
             self.release_task(t)
             t.status = "ready"
             schedule_tasks(d.es, [t], 0)
-
-    def _prefetch_upcoming(self) -> None:
-        """Issue stage-in for queued tasks beyond the current batch: the
-        ``device_put`` enqueues are asynchronous, so these H2D transfers
-        overlap whatever dispatches are still executing — the lookahead
-        half of the H2D/exec/D2H pipeline (``device_gpu.c:1928-2078``'s
-        stage-in stream).  Idempotent: ``stage_in`` short-circuits on a
-        current device copy, so the batch's own stage-in pass re-finds
-        the prefetched tiles."""
-        depth = _params.get("device_tpu_prefetch")
-        if depth <= 0:
-            return
-        # under HBM pressure a lookahead would evict tiles the in-flight
-        # batch still needs (thrash: MORE traffic, not less) — prefetch
-        # only while the cache has comfortable headroom
-        with self._lru_lock:
-            if self._mem_bytes + self._evict_bytes > 0.8 * self._mem_budget:
-                return
-        with self._mutex_lock:
-            upcoming = [d for d in list(self._pending)[:depth]
-                        if d.stage_in is None]
-        # prefetch transfers count toward the stage-in wall: the bench's
-        # achieved-H2D-rate attribution divides bytes_in by this timer
-        with _Wall(self, "t_stage_in", "devmod.prefetch"):
-            self.stage_in_many([d.task for d in upcoming])
 
     def _flood_from_scheduler(self, batch: list[TPUDeviceTask]) -> None:
         """Pull additional ready same-class tasks straight from the
@@ -1037,6 +1024,45 @@ class TPUDevice(Device):
         else:
             state["lru_tiles"] = "<lru lock held>"
         return state
+
+
+@functools.cache
+def _host_put() -> Callable | None:
+    """``put(x, jax_device) -> jax.Array | None``: a C-contiguous numpy array
+    of a dtype JAX keeps as it is whatever ``jax_enable_x64`` says (at most
+    32 bits an element), placed on one device by jaxlib's
+    ``batched_device_put``, the call ``jax.device_put`` ends in for such an
+    array, with the abstract value and the sharding kept from one tile to
+    the next.  The result is what ``jax.device_put(x, device)`` returns
+    (committed, ``SingleDeviceSharding``, not weakly typed), so a program
+    compiled for the one is the program for the other.  ``put`` returns None
+    for anything else, which the caller hands to ``jax.device_put``; this
+    function returns None where the installed jaxlib has no such call."""
+    try:
+        import jax
+        from jax.sharding import SingleDeviceSharding
+        from jaxlib._jax import batched_device_put
+    except ImportError:
+        return None
+    avals: dict[Any, Any] = {}
+    shardings: dict[Any, Any] = {}
+
+    def put(x: Any, jd: Any) -> Any:
+        if type(x) is not np.ndarray or not x.flags.c_contiguous:
+            return None
+        key = (x.shape, x.dtype)
+        aval = avals.get(key)
+        if aval is None:
+            if x.dtype.itemsize > 4 \
+                    or jax.dtypes.canonicalize_dtype(x.dtype) != x.dtype:
+                return None
+            aval = avals[key] = jax.core.ShapedArray(x.shape, x.dtype)
+        sharding = shardings.get(jd)
+        if sharding is None:
+            sharding = shardings[jd] = SingleDeviceSharding(jd)
+        return batched_device_put(aval, sharding, [x], [jd])
+
+    return put
 
 
 # the host-CPU stand-in (device_tpu_allow_cpu) has no HBM to report and no

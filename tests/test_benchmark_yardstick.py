@@ -1,10 +1,10 @@
 """Tier-1 guards the yardstick: the tests that live with the benchmark
 (``benchmarks/tests/``: the manifest, the trace reduction, the control, a
 traced rehearsal of every cell sound and broken, the phase metrics, the 64k
-cell's own, the DTD cell's own) and ``yardstick_writeback_early_share.py`` and
-``yardstick_flood_metrics.py`` beside this file are collected here under
-their own names, so each counts, and a name that two files give is an error
-here and not one test fewer.  They need no chip.  The rehearsals run in
+cell's own, the DTD cell's own) and ``yardstick_writeback_early_share.py``,
+``yardstick_flood_metrics.py`` and ``yardstick_stage_in_ms.py`` beside this
+file are collected here under their own names, so each counts, and a name
+that two files give is an error here and not one test fewer.  They need no chip.  The rehearsals run in
 processes of their own, and all from this one file, so that under ``--dist
 loadfile`` no two of them trace one cell at once (they would share
 ``.bench_trace/<cell>``).
@@ -13,7 +13,10 @@ One test is not taken over: ``test_potrf64k.py`` holds every list of the
 dynamic cells to *end* with the 64k cell, which stopped being true when PR 34
 appended ``gemm16k.dtd`` (a PR that adds a cell appends, and may not edit a
 file the benchmark has).  ``test_dtd_gemm.py`` asserts the same of the lists'
-third entry, so the count stays and the assertion holds again."""
+third entry, so the count stays and the assertion holds again.  Nor is
+``test_dtd_gemm.py``'s list of the metrics that the DTD cell shares with its
+twin, which stopped being whole when PR 35 added one: the assertion is
+``yardstick_stage_in_ms.py``'s, with that one among them."""
 
 import importlib.util
 import os
@@ -23,12 +26,15 @@ _BENCH = os.path.join(os.path.dirname(_HERE), "benchmarks", "tests")
 
 _SUPERSEDED = {
     # by test_manifest_still_lists_the_64k_cell_third_on_the_dynamic_lists
-    "test_manifest_lists_the_64k_cell_where_its_readers_find_something"}
+    "test_manifest_lists_the_64k_cell_where_its_readers_find_something",
+    # by test_manifest_lists_the_dtd_cell_on_the_twin_s_metrics_that_read_it
+    "test_manifest_lists_the_dtd_cell_where_its_readers_find_something"}
 
 for _dir, _name in ((_BENCH, "test_yardstick"), (_BENCH, "test_phase_metrics"),
                     (_BENCH, "test_potrf64k"), (_BENCH, "test_dtd_gemm"),
                     (_HERE, "yardstick_writeback_early_share"),
-                    (_HERE, "yardstick_flood_metrics")):
+                    (_HERE, "yardstick_flood_metrics"),
+                    (_HERE, "yardstick_stage_in_ms")):
     _spec = importlib.util.spec_from_file_location(
         f"benchmarks_tests_{_name}", os.path.join(_dir, _name + ".py"))
     _mod = importlib.util.module_from_spec(_spec)

@@ -204,36 +204,35 @@ class TestVmapBatching:
         assert accel_device.xla_calls == accel_device.batched_dispatches
 
 
-def test_prefetch_is_idempotent(accel_device):
-    """Prefetched stage-in must not double-transfer: bytes_in with the
-    lookahead enabled equals a run with it disabled (same tiles, same
-    numerics)."""
-    from parsec_tpu.core.params import params
-
+def test_stage_in_moves_each_tile_once_whatever_the_batches(accel_device,
+                                                            param):
+    """Stage-in must not double-transfer: bytes_in of a run in batches of 8
+    equals that of a run in one batch (same tiles, same numerics).  (The
+    queue lookahead this test used to switch, ``_prefetch_upcoming``, went in
+    ISSUE 35: it was dead under ``Context(nb_cores=0)``.)"""
     results = {}
-    for depth in (0, 8):
-        old = params.get("device_tpu_prefetch")
-        params.set("device_tpu_prefetch", depth)
-        try:
-            rng = np.random.default_rng(9)
-            a, b, c, A, B, C = _mk_abc(64, 64, 64, 16, rng)
-            bytes_before = accel_device.bytes_in
-            tp = tiled_gemm_ptg(A, B, C, devices="tpu")
-            ctx = Context(nb_cores=0)
-            ctx.add_taskpool(tp)
-            ctx.wait(timeout=120)
-            accel_device.sync()
-            accel_device.flush_cache()
-            ctx.fini()
-            results[depth] = accel_device.bytes_in - bytes_before
-            # atol floor: near-zero result elements otherwise fail the
-            # relative test on ~1e-6 absolute noise (CPU-backend matmul
-            # accumulation-order drift across jax releases)
-            np.testing.assert_allclose(C.to_dense(), c + a @ b, rtol=1e-3,
-                                       atol=1e-5)
-        finally:
-            params.set("device_tpu_prefetch", old)
-    assert results[0] == results[8], results
+    for batch_max in (64, 8):
+        param("device_tpu_batch_max", batch_max)
+        rng = np.random.default_rng(9)
+        a, b, c, A, B, C = _mk_abc(64, 64, 64, 16, rng)
+        bytes_before = accel_device.bytes_in
+        calls_before = accel_device.xla_calls
+        tp = tiled_gemm_ptg(A, B, C, devices="tpu")
+        ctx = Context(nb_cores=0)
+        ctx.add_taskpool(tp)
+        ctx.wait(timeout=120)
+        accel_device.sync()
+        accel_device.flush_cache()
+        ctx.fini()
+        results[batch_max] = accel_device.bytes_in - bytes_before
+        # four waves of 16 ready tasks: whole, or in halves
+        assert accel_device.xla_calls - calls_before == {64: 4, 8: 8}[batch_max]
+        # atol floor: near-zero result elements otherwise fail the
+        # relative test on ~1e-6 absolute noise (CPU-backend matmul
+        # accumulation-order drift across jax releases)
+        np.testing.assert_allclose(C.to_dense(), c + a @ b, rtol=1e-3,
+                                   atol=1e-5)
+    assert results[64] == results[8] == 3 * 16 * 16 * 16 * 4, results
 
 
 def test_deferred_eviction_under_pressure(accel_device):
