@@ -35,6 +35,8 @@ order of magnitude above what the TPU v5 lite runs of PR 21 showed
   product — 2.3e-3 to 2.4e-3 observed;
 - Cholesky at default precision on ``make_spd_fast``'s diagonally dominant
   matrix: stayed finite, 2.3e-5 observed;
+- tile QR at N=4096 with every product at the highest precision (f32):
+  8.5e-7 and 5.8e-7 observed on its two gaps (PR 36);
 - Pallas against its XLA twin: the same f32 taps in the same order — no
   difference observed on the chip, one ulp under the interpreter; ten ulp
   of O(1) values allowed.
@@ -54,6 +56,7 @@ import numpy as np
 TOL_BF16_IN_F32_ACC = 2e-6
 TOL_F32_DEFAULT_PRECISION = 2.5e-2
 TOL_CHOLESKY_DEFAULT_PRECISION = 2.5e-4
+TOL_QR_F32 = 1e-5
 TOL_PALLAS_VS_XLA_ABS = 1e-6
 NPROBE = 4
 
@@ -350,6 +353,48 @@ def stage_dynamic_cholesky(cfg) -> str:
             f"residual={res:.3e}")
 
 
+def stage_dynamic_qr(cfg) -> str:
+    """``tiled_qr_ptg`` through ``Context``: four classes that write two or
+    three tiles a task into two collections, every product in f32; both of
+    the benchmark's gaps (``benchmarks/reference_qr.py``) on seeded probes."""
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), "benchmarks"))
+    import reference_qr as refq
+    from parsec_tpu.data_dist.matrix import TwoDimBlockCyclic
+    from parsec_tpu.models.qr import tiled_qr_ptg
+    from parsec_tpu.runtime import Context
+
+    n, nb = cfg.n_qr, cfg.nb_qr
+    nt = n // nb
+    tiles = refq.qr_tiles(cfg.seed, n, nb)
+    X = probes(cfg.seed, n)
+    A = TwoDimBlockCyclic("A", n, n, nb, nb, dtype=np.float32,
+                          init_fn=lambda m, k, shape: tiles[m, k])
+    T = TwoDimBlockCyclic("T", n, n, nb, nb, dtype=np.float32)
+    ledger = DeviceLedger()
+
+    ctx = Context()
+    ctx.add_taskpool(tiled_qr_ptg(A, T))
+    ctx.wait(timeout=cfg.timeout)
+    sync_all()
+    ctx.fini()
+
+    ntasks = nt + nt * (nt - 1) + (nt - 1) * nt * (2 * nt - 1) // 6
+    per_dev = ledger.check(ntasks)
+    settle()
+    got = refq.qr_got(
+        {key: host_tile(A, *key) for key in tiles},
+        {(m, k): host_tile(T, m, k)
+         for m in range(nt) for k in range(m + 1)}, X, nb)
+    ax = refq.apply(tiles, X, nb)
+    res = [rel_residual(g, w)
+           for g, w in zip(got, (ax, refq.apply_t(tiles, ax, nb)))]
+    require(max(res) < TOL_QR_F32, f"residuals {res}")
+    return (f"N={n} nb={nb} f32 precision=highest tasks={ntasks} "
+            f"per_device={per_dev} cpu_tasks=0 all finite "
+            f"|Q(Rx)-Ax|={res[0]:.3e} |RtRx-AtAx|={res[1]:.3e}")
+
+
 def stage_dtd_gemm(cfg) -> str:
     """GEMM tasks inserted at run time by the library's insertion program
     (``tiled_gemm_dtd``), hazards discovered from the tile access chains,
@@ -536,6 +581,7 @@ STAGES = {
     "lowered_gemm": stage_lowered_gemm,
     "dynamic_gemm": stage_dynamic_gemm,
     "dynamic_cholesky": stage_dynamic_cholesky,
+    "dynamic_qr": stage_dynamic_qr,
     "dtd_gemm": stage_dtd_gemm,
     "server": stage_server,
     "kernels": stage_kernels,
@@ -566,12 +612,14 @@ def main(argv: list[str] | None = None) -> int:
     if cfg.rehearse:
         cfg.n_lowered, cfg.nb_lowered = 512, 128
         cfg.n_dynamic, cfg.nb_dynamic = 512, 128
+        cfg.n_qr, cfg.nb_qr = 512, 128
         cfg.n_dtd = 256
         cfg.n_served, cfg.nb_served = 256, 64
         cfg.stencil_rows, cfg.stencil_mb = 16, 1024
     else:
         cfg.n_lowered, cfg.nb_lowered = 16384, 512      # BASELINE headline
         cfg.n_dynamic, cfg.nb_dynamic = 16384, 1024
+        cfg.n_qr, cfg.nb_qr = 4096, 512
         cfg.n_dtd = 8192
         cfg.n_served, cfg.nb_served = 4096, 512
         cfg.stencil_rows, cfg.stencil_mb = 16, 1 << 16  # run_stencil_bench

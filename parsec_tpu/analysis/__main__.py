@@ -28,7 +28,7 @@ def _model_graphs(nt: int, ranks: int = 1):
     ``none`` — legitimately rank-local)."""
     from ..data_dist.matrix import (SymTwoDimBlockCyclic, TiledMatrix,
                                     TwoDimBlockCyclic, VectorTwoDimCyclic)
-    from ..models import (cholesky, irregular, lu, pingpong, reduction,
+    from ..models import (cholesky, irregular, lu, pingpong, qr, reduction,
                           stencil, stencil2d, tiled_gemm)
     nb = 8
     n = nt * nb
@@ -42,6 +42,9 @@ def _model_graphs(nt: int, ranks: int = 1):
         SymTwoDimBlockCyclic("A", n, n, nb, nb), devices="cpu")
     yield "lu", lu.tiled_lu_ptg(
         TiledMatrix.from_dense("A", lu.make_dd(n), nb, nb), devices="cpu")
+    yield "qr", qr.tiled_qr_ptg(
+        TwoDimBlockCyclic("A", n, n, nb, nb),
+        TwoDimBlockCyclic("T", n, n, nb, nb), devices="cpu")
     yield "pingpong", pingpong.pingpong_ptg(_vec("V"), 2 * nt)
     yield "reduction", reduction.bt_reduction_ptg(_vec("R"))
     yield "stencil1d", stencil.stencil_1d_ptg(

@@ -58,6 +58,31 @@ def find_incarnation(name: str, device: Any) -> Callable | None:
     return None
 
 
+def traceable_body(apply: Callable) -> Callable:
+    """A per-task device body from a jax-traceable: ``apply`` takes the
+    task's non-CTL flow values in flow order and returns the new value of its
+    written flows, one value or a tuple in flow order (the contract of
+    ``ptg.lowering.Traceable.apply`` and of the fused batch program).  Every
+    written flow gets its value and a new version, as ``_run_vmapped`` does
+    for a batch."""
+    def body(es: Any, task: Any, device: Any) -> Any:
+        from ..data.data import ACCESS_WRITE
+        flows = [f for f in task.task_class.flows if not f.is_ctl]
+        out = apply(*(task.data[f.flow_index].value for f in flows))
+        outs = out if isinstance(out, (tuple, list)) else (out,)
+        written = [f for f in flows if f.access & ACCESS_WRITE]
+        if len(outs) != len(written):
+            raise ValueError(
+                f"{task.task_class.name}: the kernel returned {len(outs)} "
+                f"values for {len(written)} written flows")
+        for f, value in zip(written, outs):
+            c = task.data[f.flow_index]
+            c.value = value
+            c.version += 1
+        return out
+    return body
+
+
 def registered() -> list[tuple[str, str]]:
     with _lock:
         return sorted(set(_kernels) | set(_lazy))

@@ -202,6 +202,10 @@ class TPUDevice(Device):
         # drive the same salvage/demote protocol)
         self._dispatch_hook: Callable | None = None
         self.batched_dispatches = 0   # XLA calls that serviced >1 task
+        # per task class, by name: tasks run and the XLA calls that ran them
+        # (one increment a dispatch): how far the flood batches each class
+        self.tasks_by_class: dict[str, int] = {}
+        self.calls_by_class: dict[str, int] = {}
         # attribution instrumentation: wall seconds per pipeline phase +
         # how many device calls paid an enqueue latency
         self.xla_calls = 0
@@ -805,6 +809,7 @@ class TPUDevice(Device):
                     note_xla_calls(1)
                     self._note_inflight(out, held)
                     self.executed_tasks += 1
+                    self._count_dispatch(dtask.task.task_class, 1)
                     self._mark_written(dtask.task)
         with _Wall(self, "t_complete", "devmod.complete"):
             # per task, the plane adds to a counter (sched.release) and
@@ -817,6 +822,11 @@ class TPUDevice(Device):
                 self.release_task(dtask.task)
                 complete(dtask.es, dtask.task)
         pins.fire(PinsEvent.DEVICE_BATCH_END, None, len(batch))
+
+    def _count_dispatch(self, tc: Any, ntasks: int) -> None:
+        by_tasks, by_calls = self.tasks_by_class, self.calls_by_class
+        by_tasks[tc.name] = by_tasks.get(tc.name, 0) + ntasks
+        by_calls[tc.name] = by_calls.get(tc.name, 0) + 1
 
     def _written_copies(self, task: Any):
         """The copies on this device that ``task``'s written flows hold."""
@@ -935,6 +945,7 @@ class TPUDevice(Device):
             self.executed_tasks += 1
             self._mark_written(dtask.task)
         self.batched_dispatches += 1
+        self._count_dispatch(tc, B)
         return True
 
     def _note_inflight(self, out: Any, held: int = 0) -> None:
@@ -988,6 +999,8 @@ class TPUDevice(Device):
                  "executed_tasks": self.executed_tasks,
                  "xla_calls": self.xla_calls,
                  "batched_dispatches": self.batched_dispatches,
+                 "tasks_by_class": dict(self.tasks_by_class),
+                 "calls_by_class": dict(self.calls_by_class),
                  "inflight_dispatches": len(self._inflight),
                  "inflight_held_bytes": self._held_bytes,
                  "inflight_held_bytes_peak": self.inflight_held_bytes_peak,

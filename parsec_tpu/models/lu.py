@@ -24,7 +24,7 @@ import numpy as np
 
 from .. import ptg
 from ..data_dist.matrix import TiledMatrix
-from ..device.kernels import register_kernel
+from ..device.kernels import register_kernel, traceable_body
 
 
 def lu_flops(n: int) -> float:
@@ -148,25 +148,10 @@ def _gemm_nn_traceable(a, b, c):
         preferred_element_type=jnp.float32, precision=_mm_precision())
 
 
-def _tpu_body(traceable):
-    def body(es: Any, task: Any, device: Any) -> Any:
-        from ..data.data import ACCESS_WRITE
-        flows = [f for f in task.task_class.flows if not f.is_ctl]
-        vals = [task.data[f.flow_index].value for f in flows]
-        out = traceable(*vals)
-        # write by access mode, matching _run_vmapped's written-flow rule
-        rw = [f for f in flows if f.access & ACCESS_WRITE][-1]
-        c = task.data[rw.flow_index]
-        c.value = out
-        c.version += 1
-        return out
-    return body
-
-
-register_kernel("lu_getrf", "tpu", _tpu_body(_getrf_traceable))
-register_kernel("lu_trsm_l", "tpu", _tpu_body(_trsm_l_traceable))
-register_kernel("lu_trsm_u", "tpu", _tpu_body(_trsm_u_traceable))
-register_kernel("lu_gemm", "tpu", _tpu_body(_gemm_nn_traceable))
+register_kernel("lu_getrf", "tpu", traceable_body(_getrf_traceable))
+register_kernel("lu_trsm_l", "tpu", traceable_body(_trsm_l_traceable))
+register_kernel("lu_trsm_u", "tpu", traceable_body(_trsm_u_traceable))
+register_kernel("lu_gemm", "tpu", traceable_body(_gemm_nn_traceable))
 
 
 def _register_traceables() -> None:

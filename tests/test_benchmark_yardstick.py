@@ -1,7 +1,7 @@
 """Tier-1 guards the yardstick: the tests that live with the benchmark
 (``benchmarks/tests/``: the manifest, the trace reduction, the control, a
 traced rehearsal of every cell sound and broken, the phase metrics, the 64k
-cell's own, the DTD cell's own) and ``yardstick_writeback_early_share.py``,
+cell's own, the DTD cell's own, the QR cell's own) and ``yardstick_writeback_early_share.py``,
 ``yardstick_flood_metrics.py`` and ``yardstick_stage_in_ms.py`` beside this
 file are collected here under their own names, so each counts, and a name
 that two files give is an error here and not one test fewer.  They need no chip.  The rehearsals run in
@@ -32,6 +32,7 @@ _SUPERSEDED = {
 
 for _dir, _name in ((_BENCH, "test_yardstick"), (_BENCH, "test_phase_metrics"),
                     (_BENCH, "test_potrf64k"), (_BENCH, "test_dtd_gemm"),
+                    (_BENCH, "test_geqrf32k"),
                     (_HERE, "yardstick_writeback_early_share"),
                     (_HERE, "yardstick_flood_metrics"),
                     (_HERE, "yardstick_stage_in_ms")):
