@@ -17,7 +17,7 @@ Page layout: one ``(3, page_size, heads, head_dim)`` array per page —
 channel 0 the keys, channel 1 the values, channel 2 metadata with
 ``page[2, 0, 0, 0]`` the page's **fill count** (valid slots).  Carrying
 the fill inside the tensor keeps the per-page attention kernel pure
-(same shapes across sequences → the PR-2 fused same-class vmapped
+(same shapes across sequences → the PR-2 fused same-class
 dispatch can batch every live sequence's decode task into one XLA
 call) rather than threading ragged lengths through the task signature.
 

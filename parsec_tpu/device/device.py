@@ -24,7 +24,7 @@ from ..data.data import ACCESS_WRITE
 # process-wide XLA dispatch ledger
 #
 # Every accelerator enqueue in the process — the dynamic device path's
-# per-task (or vmapped-batch) dispatches (device/tpu.py) AND the lowered
+# per-task (or fused-batch) dispatches (device/tpu.py) AND the lowered
 # paths' whole-program / per-region invocations (ptg/lowering.py) — bumps
 # ONE counter, so "XLA calls per DAG" is a single comparable axis across
 # execution modes (tests/test_lowering_regions.py holds the count per

@@ -19,11 +19,12 @@ SURVEY §2.5, §3.5) redesigned around XLA's execution model:
   eviction-by-writeback when an HBM budget is exceeded — the zone-malloc
   reservation becomes a byte budget, since XLA owns physical HBM.
 - **Batched execution** (TPU-first addition): consecutive pending tasks of
-  the same task class with the same kernel are stacked and dispatched as
-  ONE vmapped XLA call (:meth:`TPUDevice._run_vmapped`, consuming the same
-  traceable-kernel registry as the compiled lowering) — tiny-task dispatch
-  overhead amortizes onto the MXU (no reference analog; this is the
-  idiomatic TPU answer to its per-task CUDA-stream pipelining).
+  the same task class with the same kernel are dispatched as ONE fused XLA
+  call (:meth:`TPUDevice._run_vmapped`, consuming the same
+  traceable-kernel registry as the compiled lowering), one kernel a lane
+  on the tiles where they lie — tiny-task dispatch overhead amortizes onto
+  one enqueue (no reference analog; this is the idiomatic TPU answer to its
+  per-task CUDA-stream pipelining).
 """
 
 from __future__ import annotations
@@ -53,13 +54,32 @@ _params.register("device_tpu_max_inflight", 32,
                  "the ring is cut shorter than this whenever the bytes it "
                  "holds would take the module past device_tpu_memory_use")
 _params.register("device_tpu_batch", True,
-                 "stack same-class pending tasks into one vmapped dispatch")
+                 "run same-class pending tasks as one fused dispatch")
 _params.register("device_tpu_batch_max", 64,
-                 "largest task batch a single vmapped dispatch may service")
+                 "largest task batch a single fused dispatch may service")
 _params.register("device_tpu_allow_cpu", False,
                  "register host CPU jax devices as accelerators, so the "
                  "device path (stage-in, LRU, batched dispatch) runs "
                  "without a chip: tests and CPU smoke runs")
+
+
+def _fused_program(apply: Callable, dyld: str, lanes: int) -> Callable:
+    """The one jitted program of a same-class batch: ``apply`` once a lane on
+    the lane's own tiles (the flat arguments are flow-major: lane i's are
+    ``flat[i::lanes]``), the results per written flow, a tuple of the lanes'.
+    Its name on the trace's "XLA Modules" line is ``jit_fused_<dyld>``:
+    device time splits by task class."""
+    import jax
+
+    def fused(*flat):
+        with jax.named_scope("body"):
+            outs = [apply(*flat[i::lanes]) for i in range(lanes)]
+        if not isinstance(outs[0], (tuple, list)):
+            return (tuple(outs),)
+        return tuple(zip(*outs))
+
+    fused.__name__ = f"fused_{dyld}"
+    return jax.jit(fused)
 
 
 def _copy_nbytes(copy: DataCopy) -> int:
@@ -844,20 +864,20 @@ class TPUDevice(Device):
             c.coherency = COHERENCY_OWNED
             c.original.owner_device = self.device_index
 
-    # ------------------------------------------------- vmapped batch dispatch
+    # --------------------------------------------------- fused batch dispatch
     def _run_vmapped(self, batch: list[TPUDeviceTask]) -> bool:
         """Dispatch a same-class batch as ONE fused XLA call (the
         TPU-first answer to per-task CUDA-stream pipelining: tiny-task
         dispatch overhead amortizes onto the MXU).
 
-        The fused program takes the B x F per-task tiles FLAT, stacks
-        them on-device, runs the vmapped traceable, and returns per-task
-        output slices — so the whole batch costs ONE enqueue where a
-        stack-per-flow pipeline pays F stack calls + 1 exec + W unbind
-        calls (≈5 for GEMM).  B is padded to the next power of two with
-        copies of lane 0 (outputs of pad lanes are dropped; kernels are
-        pure XLA) to bound jit specializations to log2(batch_max) per
-        (dyld, signature).
+        The fused program takes the B x F per-task tiles FLAT, runs the
+        class's traceable once a lane on the parameter buffers as they lie
+        (no stack, no ``vmap``, no slices) and returns, per written flow,
+        the tuple of every lane's result — so the whole batch costs ONE
+        enqueue and allocates its results alone.  B is padded to the next
+        power of two with copies of lane 0 (outputs of pad lanes are
+        dropped; kernels are pure XLA) to bound jit specializations to
+        log2(batch_max) per (dyld, signature).
 
         Eligibility: the class's device chore has a jax-traceable
         incarnation registered under its ``dyld`` name
@@ -866,8 +886,6 @@ class TPUDevice(Device):
         agree on shape/dtype, and no task overrides its stage hooks.
         Returns False to fall back to per-task submission.
         """
-        import jax
-
         from ..ptg.lowering import find_traceable
 
         tc = batch[0].task.task_class
@@ -895,40 +913,27 @@ class TPUDevice(Device):
         Bp = 1
         while Bp < B:
             Bp <<= 1
-        nflows = len(data_flows)
         written = [f for f in data_flows if f.access & ACCESS_WRITE]
         sig = tuple((v.shape, str(v.dtype)) for v in
                     (c[0] for c in cols))
         key = (dyld, Bp, sig)
         fn = self._vmap_cache.get(key)
         if fn is None:
-            import jax.numpy as jnp
-            vmapped = jax.vmap(tr.apply)
-
-            def fused(*flat, _n=nflows, _b=Bp):
-                with jax.named_scope("stack"):
-                    stacked = [jnp.stack(flat[i * _b:(i + 1) * _b])
-                               for i in range(_n)]
-                with jax.named_scope("body"):
-                    out = vmapped(*stacked)
-                outs = out if isinstance(out, (tuple, list)) else (out,)
-                # per-task slices returned directly: no unbind call
-                with jax.named_scope("unstack"):
-                    return tuple(tuple(col) for col in outs)
-
-            # the program's name on the trace's "XLA Modules" line:
-            # device time splits by task class (jit_fused_gemm, ...)
-            fused.__name__ = f"fused_{dyld}"
-            fn = self._vmap_cache[key] = jax.jit(fused)
+            fn = self._vmap_cache[key] = _fused_program(tr.apply, dyld, Bp)
         flat = [v for vs in cols
                 for v in (vs + [vs[0]] * (Bp - B))]   # lane-0 padding
-        # what the call allocates: the stacked operands while it runs, and
-        # Bp output slices a written flow, which supersede B current
-        # versions and pad Bp - B lanes: both stay until the call has run
-        # (a flow's tiles are of one shape: one nbytes a flow, not a tile)
-        nb = [c[0].nbytes for c in cols]
-        held = Bp * sum(nb[data_flows.index(w)] for w in written)
-        self._make_room(Bp * sum(nb) + held)
+        # what the call allocates: Bp results a written flow, which
+        # supersede B current versions and pad Bp - B lanes, and stay until
+        # the call has run (a flow's tiles are of one shape: one nbytes a
+        # flow, not a tile).  Its temporaries are not asked for: at 4 MiB
+        # tiles the v5e compiler's memory_analysis gives the program 0
+        # bytes of them for gemm / gemm_nt (64 lanes), syrk_ln (16),
+        # qr_tsmqr / qr_unmqr (32), 11 MiB for trsm_rlt (16: 64 MiB of
+        # results) and 100 MiB for qr_tsqrt (32: 384 MiB; no cell batches
+        # it); tests/test_fused_tpu_compile.py holds the classes the cells
+        # batch to temporaries under a quarter of held
+        held = Bp * sum(cols[data_flows.index(w)][0].nbytes for w in written)
+        self._make_room(held)
         if self._dispatch_hook is not None:
             self._dispatch_hook(batch)
         outs = fn(*flat)

@@ -18,7 +18,7 @@ Page tiles are uniform ``(3, page_size, H, D)`` (the fill count rides
 in the tensor — ``data_dist/paged_kv.py``), so every live sequence's
 ATTN tasks are the SAME class with the SAME shapes: the TPU device
 module's fused same-class dispatch (``device/tpu.py:_run_vmapped``)
-batches them into one vmapped XLA call — continuous batching meets the
+batches them into one fused XLA call — continuous batching meets the
 PR-2 batched dispatch at the kernel level.
 
 **OUT(s)** — finalize the attention output into the O collection and
@@ -81,7 +81,7 @@ def prefill_ptg(kv: PagedKVCollection, T: DictCollection,
         t.body(device="tpu", dyld="llm_prefill_copy")
     # the dyld names the traceable twin (ops/ragged_attention.py), so
     # the pool lowers/warms (llm_prefill_tail) and the device tier can
-    # vmap-batch PF tasks; the CPU body stays the plain copy
+    # batch PF tasks; the CPU body stays the plain copy
     t.body(body, dyld="llm_prefill_copy")
     return p.build()
 
@@ -426,7 +426,7 @@ def spec_superpool_ptg(kv: PagedKVCollection, DRAFT: DictCollection,
 
         ATTN(s,t,p)   q3(draft_t) over page p, ACC threading — ALL
                       positions' frozen-page reads run in parallel (and
-                      vmap-batch: one class, one shape); only the tail
+                      fused batch: one class, one shape); only the tail
                       page serializes through OUT's appends
         OUT(s,t)      finalize -> VERIFY; append draft_t's k/v to the
                       tail page (speculative — rolled back on reject)
@@ -635,7 +635,7 @@ def spec_batched_ptg(kv: PagedKVCollection, QS: DictCollection,
     ``NP + 1`` tasks per stream per pool instead of ``~k * NP + 2k`` —
     per-task dispatch stops dominating the speculative win on the
     host-dispatched CPU path (the per-position pool gets the same
-    collapse only from vmapped same-class device dispatch).  The pool
+    collapse only from fused same-class device dispatch).  The pool
     only READS KV pages, so graphcheck is trivially clean; the
     write-side hazards live in the seed/rollback pair, which the
     batcher serializes against the pool (seed before submit, rollback
@@ -643,7 +643,7 @@ def spec_batched_ptg(kv: PagedKVCollection, QS: DictCollection,
 
     ``pad``: pad every stream's position axis to this count (default:
     the pool's max) — uniform tile shapes are what let the device tier
-    vmap SATTN across streams and keep the XLA cache warm across
+    batch SATTN across streams and keep the XLA cache warm across
     iterations.  Padded rows ride zero LIM limits and a zero query:
     they fold nothing in and VERIFY ignores them (the DTOKS count).
     """

@@ -7,11 +7,11 @@ The per-page online-softmax update at the heart of the decode task class
   CPU task bodies (fast for the host-dispatched dynamic path: no tracing
   per task);
 - jnp twins, registered as **traceables** under ``"ragged_attn_page"`` /
-  ``"ragged_attn_out"`` so the PR-2 fused same-class dispatch can vmap
+  ``"ragged_attn_out"`` so the PR-2 fused same-class dispatch can batch
   every live sequence's decode task into ONE XLA call — page shapes are
   uniform by construction (the fill count rides inside the page tensor,
   :mod:`parsec_tpu.data_dist.paged_kv`), which is exactly what makes the
-  ragged batch vmappable.  The device bodies resolve through the lazy
+  ragged batch uniform.  The device bodies resolve through the lazy
   kernel registry (``device/kernels.py``).
 
 The accumulator tile is ``(H, D+2)``: columns ``[:D]`` the unnormalized
@@ -164,7 +164,7 @@ def verify_step_np(o: np.ndarray, st_prev: np.ndarray, dtok: np.ndarray,
 
 def _verify_jnp(o: Any, st_prev: Any, dtok: Any, q3t: Any) -> Any:
     """jnp twin of :func:`verify_step_np` — branchless (``jnp.where``)
-    so the region lowering and vmapped same-class dispatch batch every
+    so the region lowering and fused same-class dispatch batch every
     stream's VERIFY chain the way they batch SAMPLE."""
     import jax.numpy as jnp
     V = q3t.shape[0]
@@ -203,7 +203,7 @@ def spec_attn_page_np(qs: np.ndarray, page: np.ndarray, lim: np.ndarray,
     bodies — the task count per token collapses from ~1 per (position,
     page) to ~1 per page, which is what makes speculation a throughput
     win on the host-dispatched path too (the per-position pool wins the
-    same way only through vmapped same-class device dispatch)."""
+    same way only through fused same-class device dispatch)."""
     S, H, Dp2 = acc.shape
     D = Dp2 - 2
     lim = np.asarray(lim, np.float32)
@@ -340,7 +340,7 @@ def _spec_verify_jnp(acc: Any, dtoks: Any, q3t: Any,
 def _sample_jnp(o: Any, tok_prev: Any, q3t: Any,
                 qn_scratch: Any = None) -> Any:
     """jnp twin of :func:`sample_step_np` — the traceable incarnation the
-    region lowering and the vmapped same-class dispatch batch over
+    region lowering and the fused same-class dispatch batch over
     (``qn_scratch`` is the QN flow's zeros tile, unused — flow-order
     contract, like ``_out_update_jnp``'s ``o_scratch``)."""
     import jax.numpy as jnp
@@ -375,7 +375,7 @@ def ragged_attention_reference(q: np.ndarray, ks: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# jnp twins: traceables (vmapped same-class batching) + device bodies
+# jnp twins: traceables (fused same-class batching) + device bodies
 # ---------------------------------------------------------------------------
 
 def _page_update_jnp(q3: Any, page: Any, acc: Any) -> Any:
@@ -417,7 +417,7 @@ def _prefill_copy_jnp(chunk: Any, page: Any) -> Any:
     """PF: the page's new contents ARE the prompt chunk tile.  Trivial
     on purpose — registering it is what makes the prefill pool
     lowerable/warmable (``llm_prefill_tail``, ISSUE 11) and lets the
-    device tier vmap-batch PF tasks like any other class."""
+    device tier batch PF tasks like any other class."""
     import jax.numpy as jnp
     del page
     return jnp.asarray(chunk)

@@ -26,7 +26,7 @@ Pipeline:
    over the tile stores — the k-chain of GEMM(m,n,k) becomes a single
    ``einsum('mkab,knbc->mnac')`` that XLA tiles onto the MXU at full size.
 4. **Wavefront-batch pass** — the general MXU-saturation pass (the compiled
-   analog of the device module's vmapped batching, and of the reference GPU
+   analog of the device module's fused batching, and of the reference GPU
    hook keeping a stream full across a whole panel, ``jdf2c.c:6566``,
    ``device_gpu.c:2522-2531``): every flow value is resolved to a *store
    row* (tile dataflow is tile versioning), tasks are grouped per

@@ -1,0 +1,58 @@
+"""The fused batch program (``device/tpu.py:_fused_program``) compiled for a
+described TPU v5e at the benchmark cells' widths, 4 MiB f32 tiles: what the
+chip's compiler allocates beside the results.  ``_run_vmapped`` asks the HBM
+budget for the results alone (``held``), so the executable's temporaries
+have to stay a small part of them.  Nothing runs and no chip is needed; the
+topology is described inside a fixture, in the test's own process (one
+process a machine may hold the TPU's library)."""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+NB = 1024
+# dyld -> (flows, written flows, the largest padded batch a cell gives it)
+PROGRAMS = {"gemm": (3, 1, 64), "gemm_nt": (3, 1, 64), "trsm_rlt": (2, 1, 16),
+            "syrk_ln": (2, 1, 16), "qr_unmqr": (3, 1, 32),
+            "qr_tsmqr": (4, 2, 32)}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable for a chip that is not attached cannot be read back
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cached)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("dyld", list(PROGRAMS))
+def test_the_program_s_temporaries_are_a_small_part_of_its_results(one_chip,
+                                                                   dyld):
+    import parsec_tpu.models.cholesky  # noqa: F401  (registers traceables)
+    import parsec_tpu.models.qr  # noqa: F401
+    import parsec_tpu.ops.gemm  # noqa: F401
+    from parsec_tpu.device.tpu import _fused_program
+    from parsec_tpu.ptg.lowering import find_traceable
+    flows, written, lanes = PROGRAMS[dyld]
+    tile = jax.ShapeDtypeStruct((NB, NB), jnp.float32, sharding=one_chip)
+    mem = _fused_program(find_traceable(dyld).apply, dyld, lanes).lower(
+        *[tile] * (flows * lanes)).compile().memory_analysis()
+    held = written * lanes * NB * NB * 4
+    assert held <= mem.output_size_in_bytes < held + (1 << 20)
+    assert mem.alias_size_in_bytes == 0          # nothing is donated
+    assert mem.temp_size_in_bytes <= held // 4, mem
