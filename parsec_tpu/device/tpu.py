@@ -129,13 +129,17 @@ class _Wall:
     """One phase of the device module, instrumented once: its host wall
     (``TPUDevice.t_<wall>``, always on, read as deltas by the benchmark and
     ``debug_state``) and, while the phase plane is on, its span, both from
-    one pair of clock readings."""
+    one pair of clock readings.  ``lock``: held while the wall is added to,
+    for the one wall that closes after its thread gave the managership up
+    (``t_manager``: the next manager may be closing its own by then)."""
 
-    __slots__ = ("dev", "attr", "span", "t0")
+    __slots__ = ("dev", "attr", "span", "t0", "lock")
 
-    def __init__(self, dev: "TPUDevice", attr: str, name: str) -> None:
+    def __init__(self, dev: "TPUDevice", attr: str, name: str,
+                 lock: Any = None) -> None:
         self.dev = dev
         self.attr = attr
+        self.lock = lock
         self.span = spans.phase(name) if spans.phase_on else None
 
     def __enter__(self) -> None:
@@ -148,7 +152,71 @@ class _Wall:
         dt = time.perf_counter_ns() - self.t0
         if self.span is not None:
             self.span.close(dt, *exc)
+        if self.lock is None:
+            self._add(dt)
+        else:
+            with self.lock:
+                self._add(dt)
+
+    def _add(self, dt: int) -> None:
         setattr(self.dev, self.attr, getattr(self.dev, self.attr) + dt / 1e9)
+
+
+# the fields of a row of ``TPUDevice.call_table`` (plain ints); ``tasks``
+# beside ``calls`` x lanes is the row's share of pad lanes
+CALL_FIELDS = ("calls", "tasks", "args", "results", "call_ns", "depth_sum",
+               "held_bytes_sum", "held_run_bytes_sum")
+
+
+def _first_array(out: Any) -> Any:
+    """The first ``jax.Array`` among what a dispatch handed back (nested
+    tuples and lists, in order), None where there is none."""
+    if isinstance(out, (tuple, list)):
+        for part in out:
+            leaf = _first_array(part)
+            if leaf is not None:
+                return leaf
+        return None
+    return out if hasattr(out, "is_ready") else None
+
+
+class _Call:
+    """The call of one dispatch while the phase plane is on: the chip's queue
+    read just before (:meth:`TPUDevice._queue_depth`), the span
+    ``devmod.call`` around the call alone, with the class, the lanes and the
+    depth as the annotation's arguments, and the dispatch added to its row of
+    ``call_table``; span and ``call_ns`` come from one pair of clock
+    readings, as a :class:`_Wall`'s do."""
+
+    __slots__ = ("row", "span")
+
+    def __init__(self, dev: "TPUDevice", tc: Any, lanes: int,
+                 tasks: int) -> None:
+        flows = [f for f in tc.flows if not f.is_ctl]
+        depth, held_run = dev._queue_depth()
+        row = dev.call_table.get((tc.name, lanes))
+        if row is None:
+            row = dev.call_table[(tc.name, lanes)] = dict.fromkeys(
+                CALL_FIELDS, 0)
+        row["calls"] += 1
+        row["tasks"] += tasks
+        row["args"] += lanes * len(flows)
+        row["results"] += lanes * sum(1 for f in flows
+                                      if f.access & ACCESS_WRITE)
+        row["depth_sum"] += depth
+        row["held_bytes_sum"] += dev._held_bytes
+        row["held_run_bytes_sum"] += held_run
+        self.row = row
+        self.span = spans.phase("devmod.call", task_class=tc.name,
+                                lanes=lanes, depth=depth)
+
+    def __enter__(self) -> None:
+        self.span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter_ns() - self.span.t0
+        self.span.close(dt, *exc)
+        self.row["call_ns"] += dt
 
 
 class TPUDeviceTask:
@@ -226,6 +294,10 @@ class TPUDevice(Device):
         # (one increment a dispatch): how far the flood batches each class
         self.tasks_by_class: dict[str, int] = {}
         self.calls_by_class: dict[str, int] = {}
+        # inside the call, filled only while the phase plane is on (a traced
+        # window's rows are that window's): (task class name, lanes) -> a
+        # row of CALL_FIELDS, lanes = 1 for a task submitted alone
+        self.call_table: dict[tuple[str, int], dict[str, int]] = {}
         # attribution instrumentation: wall seconds per pipeline phase +
         # how many device calls paid an enqueue latency
         self.xla_calls = 0
@@ -651,18 +723,16 @@ class TPUDevice(Device):
                 return HOOK_RETURN_ASYNC  # a manager is already in charge
             self._managing = True
         # we are the manager
-        with spans.phase("devmod.manage"):
+        with _Wall(self, "t_manager", "devmod.manage", self._mutex_lock):
             return self._manage()
 
     def _manage(self) -> int:
         """One managership: drain ``_pending`` batch by batch."""
-        _mgr0 = time.perf_counter()
         try:
             while True:
                 with self._mutex_lock:
                     if not self._pending:
                         self._managing = False
-                        self.t_manager += time.perf_counter() - _mgr0
                         return HOOK_RETURN_ASYNC
                     batch = self._take_batch_locked()
                 spans.phase_refresh()
@@ -682,7 +752,6 @@ class TPUDevice(Device):
             # managership so the error path never strands queued tasks
             with self._mutex_lock:
                 self._managing = False
-                self.t_manager += time.perf_counter() - _mgr0
             raise
 
     def _recover_failed_batch(self, batch: list[TPUDeviceTask],
@@ -824,13 +893,15 @@ class TPUDevice(Device):
                     held = sum(_copy_nbytes(c) for c in
                                self._written_copies(dtask.task))
                     self._make_room(held)
-                    out = dtask.submit(dtask.es, dtask.task, self)
-                    self.xla_calls += 1
-                    note_xla_calls(1)
-                    self._note_inflight(out, held)
-                    self.executed_tasks += 1
-                    self._count_dispatch(dtask.task.task_class, 1)
-                    self._mark_written(dtask.task)
+                    with self._call(dtask.task.task_class, 1, 1):
+                        out = dtask.submit(dtask.es, dtask.task, self)
+                    with spans.phase("devmod.land"):
+                        self.xla_calls += 1
+                        note_xla_calls(1)
+                        self._note_inflight(out, held)
+                        self.executed_tasks += 1
+                        self._count_dispatch(dtask.task.task_class, 1)
+                        self._mark_written(dtask.task)
         with _Wall(self, "t_complete", "devmod.complete"):
             # per task, the plane adds to a counter (sched.release) and
             # opens no span
@@ -936,22 +1007,56 @@ class TPUDevice(Device):
         self._make_room(held)
         if self._dispatch_hook is not None:
             self._dispatch_hook(batch)
-        outs = fn(*flat)
-        self.xla_calls += 1              # the whole batch, one enqueue
-        note_xla_calls(1)
-        assert len(outs) == len(written), (dyld, len(outs), len(written))
-        self._note_inflight(outs, held)
-        for w, parts in zip(written, outs):
-            for i, dtask in enumerate(batch):
-                c = dtask.task.data[w.flow_index]
-                c.value = parts[i]
-                c.version += 1
-        for dtask in batch:
-            self.executed_tasks += 1
-            self._mark_written(dtask.task)
-        self.batched_dispatches += 1
-        self._count_dispatch(tc, B)
+        with self._call(tc, Bp, B):
+            outs = fn(*flat)
+        with spans.phase("devmod.land"):
+            self.xla_calls += 1              # the whole batch, one enqueue
+            note_xla_calls(1)
+            assert len(outs) == len(written), (dyld, len(outs), len(written))
+            self._note_inflight(outs, held)
+            for w, parts in zip(written, outs):
+                for i, dtask in enumerate(batch):
+                    c = dtask.task.data[w.flow_index]
+                    c.value = parts[i]
+                    c.version += 1
+            for dtask in batch:
+                self.executed_tasks += 1
+                self._mark_written(dtask.task)
+            self.batched_dispatches += 1
+            self._count_dispatch(tc, B)
         return True
+
+    def _call(self, tc: Any, lanes: int, tasks: int) -> Any:
+        """What wraps the call of one dispatch (the fused program, or the
+        body of a task submitted alone, whose few lines of Python around its
+        jitted call are in it): nothing while the phase plane is off."""
+        return _Call(self, tc, lanes, tasks) if spans.phase_on \
+            else spans.phase("devmod.call")
+
+    def _queue_depth(self) -> tuple[int, int]:
+        """How many of the unconfirmed dispatches the chip has not run yet,
+        and the ``held`` bytes of those it has (which the budget still
+        counts): ``is_ready()`` on the first ``jax.Array`` among a
+        dispatch's results, which does not block.  One chip runs its
+        programs in the order they were enqueued, so the ring is ready up to
+        some entry and not from there on, and that entry is found by
+        bisection: at most 6 probes for a ring of 32.  A dispatch whose body
+        handed back no array says nothing of the chip: it is counted with
+        the dispatch enqueued before it.  What cannot be seen: a body whose
+        first array is one of its inputs passed through reads as run."""
+        ring = self._inflight
+        lo, hi = 0, len(ring)
+        while lo < hi:
+            mid = probe = (lo + hi) // 2
+            leaf = _first_array(ring[probe][0])
+            while leaf is None and probe > lo:
+                probe -= 1
+                leaf = _first_array(ring[probe][0])
+            if leaf is None or leaf.is_ready():
+                lo = mid + 1
+            else:
+                hi = probe
+        return len(ring) - lo, sum(ring[i][1] for i in range(lo))
 
     def _note_inflight(self, out: Any, held: int = 0) -> None:
         """Bound the enqueue depth: block on the oldest dispatch once more
@@ -1006,6 +1111,9 @@ class TPUDevice(Device):
                  "batched_dispatches": self.batched_dispatches,
                  "tasks_by_class": dict(self.tasks_by_class),
                  "calls_by_class": dict(self.calls_by_class),
+                 "call_table": [
+                     dict(row, task_class=name, lanes=lanes)
+                     for (name, lanes), row in list(self.call_table.items())],
                  "inflight_dispatches": len(self._inflight),
                  "inflight_held_bytes": self._held_bytes,
                  "inflight_held_bytes_peak": self.inflight_held_bytes_peak,

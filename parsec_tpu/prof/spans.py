@@ -326,14 +326,15 @@ def _phase_account(name: str, self_ns: int, incl_ns: int) -> None:
 
 class _Phase:
     """One open span: a TraceAnnotation on the calling thread's host line
-    and a frame on the thread's stack, so that what its children cover
-    comes off its self time."""
+    (``args`` become the event's arguments in the profiler's viewer) and a
+    frame on the thread's stack, so that what its children cover comes off
+    its self time."""
 
     __slots__ = ("name", "note", "t0", "child")
 
-    def __init__(self, name: str) -> None:
+    def __init__(self, name: str, args: dict) -> None:
         self.name = name
-        self.note = _jax_profiler.TraceAnnotation(name)
+        self.note = _jax_profiler.TraceAnnotation(name, **args)
         self.child = 0
 
     def __enter__(self) -> "_Phase":
@@ -360,10 +361,12 @@ class _Phase:
         _phase_account(self.name, dt - self.child, dt)
 
 
-def phase(name: str) -> Any:
+def phase(name: str, **args: Any) -> Any:
     """``with spans.phase("devmod.dispatch"): ...`` -- a span of the phase
-    plane while it is on, the shared no-op otherwise."""
-    return _Phase(name) if phase_on else _phase_off
+    plane while it is on, the shared no-op otherwise.  ``args`` go to the
+    annotation alone (one span looked at in the viewer); the table is keyed
+    by ``name``."""
+    return _Phase(name, args) if phase_on else _phase_off
 
 
 def phase_add(name: str, ns: int, covered: int = 0) -> None:

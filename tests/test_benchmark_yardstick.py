@@ -2,9 +2,10 @@
 (``benchmarks/tests/``: the manifest, the trace reduction, the control, a
 traced rehearsal of every cell sound and broken, the phase metrics, the 64k
 cell's own, the DTD cell's own, the QR cell's own) and ``yardstick_writeback_early_share.py``,
-``yardstick_flood_metrics.py`` and ``yardstick_stage_in_ms.py`` beside this
-file are collected here under their own names, so each counts, and a name
-that two files give is an error here and not one test fewer.  They need no chip.  The rehearsals run in
+``yardstick_flood_metrics.py``, ``yardstick_stage_in_ms.py`` and
+``yardstick_dispatch_metrics.py`` beside this file are collected here under
+their own names, so each counts, and a name that two files give is an error
+here and not one test fewer.  They need no chip.  The rehearsals run in
 processes of their own, and all from this one file, so that under ``--dist
 loadfile`` no two of them trace one cell at once (they would share
 ``.bench_trace/<cell>``).
@@ -35,7 +36,8 @@ for _dir, _name in ((_BENCH, "test_yardstick"), (_BENCH, "test_phase_metrics"),
                     (_BENCH, "test_geqrf32k"),
                     (_HERE, "yardstick_writeback_early_share"),
                     (_HERE, "yardstick_flood_metrics"),
-                    (_HERE, "yardstick_stage_in_ms")):
+                    (_HERE, "yardstick_stage_in_ms"),
+                    (_HERE, "yardstick_dispatch_metrics")):
     _spec = importlib.util.spec_from_file_location(
         f"benchmarks_tests_{_name}", os.path.join(_dir, _name + ".py"))
     _mod = importlib.util.module_from_spec(_spec)

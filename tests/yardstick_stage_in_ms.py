@@ -8,7 +8,9 @@ so that every traced rehearsal of the suite runs on one worker.
 ``benchmarks/tests/test_dtd_gemm.py`` holds the set of the twin's metrics that
 list the DTD cell to the nine PR 34 knew (``SHARED``), and a PR that adds a
 metric may not edit that file: its assertion is made here with the one added,
-and tier-1's collector takes this one in its place (PERF.md, section 7)."""
+and tier-1's collector takes this one in its place (PERF.md, section 7).
+PR 38's three metrics of the call that list every dynamic cell are among the
+shared ones too."""
 
 import json
 import math
@@ -38,10 +40,12 @@ def test_manifest_lists_the_stage_in_metric_on_every_dynamic_cell():
 
 
 def test_manifest_lists_the_dtd_cell_on_the_twin_s_metrics_that_read_it():
-    """``test_dtd_gemm.py``'s assertion of the same lists, with the metric of
-    PR 35 among the shared ones."""
+    """``test_dtd_gemm.py``'s assertion of the same lists, with the metrics of
+    PR 35 and PR 38 among the shared ones."""
     dtd = _load(os.path.join(BENCH, "tests", "test_dtd_gemm.py"))
-    shared = dtd.SHARED | {NAME}
+    shared = dtd.SHARED | {NAME, "devmod.call_us_per_result",
+                           "devmod.dispatch_own_us_per_task",
+                           "devmod.chip_queue_depth"}
     manifest, per_layer = dtd._manifest()
     (rate,) = [m for m in manifest["end_to_end"]
                if m["name"] == "dynamic.gflops"]
