@@ -36,7 +36,23 @@ _data_keys = itertools.count()
 
 
 class DataCopy:
-    """One device's copy of a datum (cf. ``parsec_data_copy_t``)."""
+    """One device's copy of a datum (cf. ``parsec_data_copy_t``).
+
+    **Who owns ``value``.**  A device copy's array belongs to its device
+    module.  Everyone else (tasks, repo entries, the LRU's eviction queue,
+    failure recovery, the comm engine) keeps the *copy* and reads ``.value``
+    when it uses it: the graph orders every reader of a version before the
+    task that writes the next one, and the writer replaces ``value``.  The
+    module may hand the array it replaces to the program that supersedes it
+    (``device/tpu.py:_run_vmapped`` donates a written tile so that the
+    result takes its buffer), after which that array is deleted.  It does
+    so only while nobody else refers to the array (one reading of its
+    reference count a lane: ``TPUDevice._sole_holder``): who does keep the
+    array itself (the datum's host copy after a memory edge,
+    ``scheduling.apply_writeback_to_home``; a send registered with the comm
+    engine, ``comm/remote_dep.py``; a caller's variable) keeps it valid, at
+    the price of the allocation the donation would have saved.  A weak
+    reference does not count: ``pushed`` is asked by itself."""
 
     __slots__ = ("original", "device_index", "coherency", "readers", "version",
                  "value", "dtt", "flags", "arena_chunk", "reshaped",

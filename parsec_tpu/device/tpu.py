@@ -24,7 +24,10 @@ SURVEY §2.5, §3.5) redesigned around XLA's execution model:
   traceable-kernel registry as the compiled lowering), one kernel a lane
   on the tiles where they lie — tiny-task dispatch overhead amortizes onto
   one enqueue (no reference analog; this is the idiomatic TPU answer to its
-  per-task CUDA-stream pipelining).
+  per-task CUDA-stream pipelining).  The call is donated the tiles it
+  overwrites, so each result takes the buffer of the version it supersedes
+  and the call allocates nothing (the in-place write of the reference's
+  kernels, got back under XLA's immutable arrays).
 """
 
 from __future__ import annotations
@@ -32,6 +35,7 @@ from __future__ import annotations
 import functools
 import threading
 import time
+from sys import getrefcount
 from collections import OrderedDict, deque
 from typing import Any, Callable
 
@@ -47,12 +51,17 @@ from .device import Device, note_xla_calls, registry
 
 _params.register("device_tpu_memory_use", 90,
                  "percent of per-device HBM the device module may hold: the "
-                 "tile cache's current copies and what only its unconfirmed "
-                 "dispatches keep alive (superseded versions, padding lanes)")
+                 "tile cache's current copies, the scratch tiles its pad "
+                 "lanes write to, and what only its unconfirmed dispatches "
+                 "keep alive: the versions they superseded without taking "
+                 "their buffers (a per-task body's, a fused call's that "
+                 "could not donate); a result that took its predecessor's "
+                 "buffer adds nothing")
 _params.register("device_tpu_max_inflight", 32,
                  "bound, by count, on enqueued-but-unconfirmed dispatches; "
                  "the ring is cut shorter than this whenever the bytes it "
-                 "holds would take the module past device_tpu_memory_use")
+                 "holds (of results that did not take a donated buffer) "
+                 "would take the module past device_tpu_memory_use")
 _params.register("device_tpu_batch", True,
                  "run same-class pending tasks as one fused dispatch")
 _params.register("device_tpu_batch_max", 64,
@@ -63,12 +72,17 @@ _params.register("device_tpu_allow_cpu", False,
                  "without a chip: tests and CPU smoke runs")
 
 
-def _fused_program(apply: Callable, dyld: str, lanes: int) -> Callable:
+def _fused_program(apply: Callable, dyld: str, lanes: int,
+                   donates: tuple[int, ...] = ()) -> Callable:
     """The one jitted program of a same-class batch: ``apply`` once a lane on
     the lane's own tiles (the flat arguments are flow-major: lane i's are
     ``flat[i::lanes]``), the results per written flow, a tuple of the lanes'.
     Its name on the trace's "XLA Modules" line is ``jit_fused_<dyld>``:
-    device time splits by task class."""
+    device time splits by task class.  ``donates``: the flows (positions
+    among the lane's arguments) every lane of which is donated, so that the
+    lane's result takes its buffer and the call allocates no output for it
+    (:func:`_donatable` says which can be; the compiled module pairs lane
+    i's input with lane i's result); kept on the program as ``.donates``."""
     import jax
 
     def fused(*flat):
@@ -79,7 +93,39 @@ def _fused_program(apply: Callable, dyld: str, lanes: int) -> Callable:
         return tuple(zip(*outs))
 
     fused.__name__ = f"fused_{dyld}"
-    return jax.jit(fused)
+    fn = jax.jit(fused, donate_argnums=tuple(
+        f * lanes + i for f in donates for i in range(lanes)))
+    fn.donates = tuple(donates)
+    return fn
+
+
+def _donatable(apply: Callable, lane: list, written: list[int]) -> tuple:
+    """Of a lane's ``written`` flows (positions among its arguments, in the
+    order of the traceable's results), those whose result has the shape and
+    dtype of the input it supersedes: only such a result can take the
+    input's buffer.  Decided from ``jax.eval_shape`` once a program, not by
+    class: a traceable whose result is another shape donates nothing."""
+    import jax
+    outs = jax.eval_shape(apply, *lane)
+    if not isinstance(outs, (tuple, list)):
+        outs = (outs,)
+    return tuple(w for w, o in zip(written, outs)
+                 if o.shape == lane[w].shape and o.dtype == lane[w].dtype)
+
+
+def _refs_of_slot(vals: list, i: int) -> int:
+    return getrefcount(vals[i])
+
+
+def _own_refs() -> int:
+    """What ``getrefcount(vals[i])`` reads for an array that its
+    ``DataCopy`` and the gather's list alone hold (measured, not reckoned:
+    the interpreter's own temporaries are in it)."""
+    held_by_the_copy = object()
+    return _refs_of_slot([held_by_the_copy], 0)
+
+
+_OWN_REFS = _own_refs()
 
 
 def _copy_nbytes(copy: DataCopy) -> int:
@@ -163,21 +209,40 @@ class _Wall:
 
 
 # the fields of a row of ``TPUDevice.call_table`` (plain ints); ``tasks``
-# beside ``calls`` x lanes is the row's share of pad lanes
-CALL_FIELDS = ("calls", "tasks", "args", "results", "call_ns", "depth_sum",
-               "held_bytes_sum", "held_run_bytes_sum")
+# beside ``calls`` x lanes is the row's share of pad lanes, and
+# ``results_donated`` beside ``results`` the results that took the buffer of
+# the version they supersede (pad lanes' on the scratch pool among them)
+CALL_FIELDS = ("calls", "tasks", "args", "results", "results_donated",
+               "call_ns", "depth_sum", "held_bytes_sum", "held_run_bytes_sum")
 
 
-def _first_array(out: Any) -> Any:
-    """The first ``jax.Array`` among what a dispatch handed back (nested
-    tuples and lists, in order), None where there is none."""
+def _arrays(out: Any):
+    """The ``jax.Array`` s among what a dispatch handed back (nested tuples
+    and lists, in order)."""
     if isinstance(out, (tuple, list)):
         for part in out:
-            leaf = _first_array(part)
-            if leaf is not None:
-                return leaf
-        return None
-    return out if hasattr(out, "is_ready") else None
+            yield from _arrays(part)
+    elif hasattr(out, "is_ready"):
+        yield out
+
+
+def _live(out: Any) -> list:
+    """Those of :func:`_arrays` that no later call was donated."""
+    return [a for a in _arrays(out) if not a.is_deleted()]
+
+
+_DONATED = object()
+
+
+def _first_live(out: Any) -> Any:
+    """The first array among what a dispatch handed back that no later call
+    was donated; ``_DONATED`` where every one was (a later dispatch of the
+    same ring consumed them), None where the body handed back no array."""
+    leaf = None
+    for leaf in _arrays(out):
+        if not leaf.is_deleted():
+            return leaf
+    return None if leaf is None else _DONATED
 
 
 class _Call:
@@ -190,8 +255,8 @@ class _Call:
 
     __slots__ = ("row", "span")
 
-    def __init__(self, dev: "TPUDevice", tc: Any, lanes: int,
-                 tasks: int) -> None:
+    def __init__(self, dev: "TPUDevice", tc: Any, lanes: int, tasks: int,
+                 donated: int) -> None:
         flows = [f for f in tc.flows if not f.is_ctl]
         depth, held_run = dev._queue_depth()
         row = dev.call_table.get((tc.name, lanes))
@@ -203,6 +268,7 @@ class _Call:
         row["args"] += lanes * len(flows)
         row["results"] += lanes * sum(1 for f in flows
                                       if f.access & ACCESS_WRITE)
+        row["results_donated"] += donated
         row["depth_sum"] += depth
         row["held_bytes_sum"] += dev._held_bytes
         row["held_run_bytes_sum"] += held_run
@@ -259,14 +325,26 @@ class TPUDevice(Device):
         self._mem_bytes = 0
         self._mem_budget = self._hbm_budget()
         # bounded in-flight window (poor-man's event ring): per dispatch,
-        # what to wait on and the bytes that stay allocated until it has run
-        # and nothing else accounts for: the versions it superseded (the
-        # program still reads them) and its padding lanes.  Bounded by
-        # count (_max_inflight) and, with the LRU, by the one byte budget
+        # what to wait on (a fused call: its first result, since one
+        # program's results are ready together) and the bytes that stay
+        # allocated until it has run and nothing else accounts for: the
+        # versions it superseded without taking their buffers (the program
+        # still reads them) and its padding lanes' results; nothing for a
+        # result that took a donated buffer.  Bounded by count
+        # (_max_inflight) and, with the LRU, by the one byte budget
         # (_make_room)
         self._inflight: deque[tuple] = deque()
         self._held_bytes = 0
         self._max_inflight = _params.get("device_tpu_max_inflight")
+        # what the pad lanes of a donating call write to: scratch tiles by
+        # (shape, dtype), handed to the call and taken back as its pad
+        # results, so that a pad lane allocates nothing either.  At most the
+        # pad lanes of one call a written flow (under half the widest
+        # batch); their bytes are charged to the budget
+        self._scratch: dict[tuple, list] = {}
+        self._scratch_bytes = 0
+        # results that took the buffer of the version they superseded
+        self.donated_results = 0
         # deferred evictions (the w2r-task analog): victims leave the LRU
         # immediately but write back AFTER the batch's dispatches enqueue,
         # so D2H never blocks the manager mid-pipeline.  _evict_bytes
@@ -375,15 +453,16 @@ class TPUDevice(Device):
 
     def _make_room(self, need: int) -> None:
         """The one place the budget is held.  It covers everything the
-        module holds on the chip: the LRU's current copies, what only the
-        unconfirmed dispatches keep alive (``_held_bytes``) and ``need``,
-        the bytes the caller is about to allocate (a stage-in's misses, a
-        dispatch's stack and outputs; 0 after an insertion).  Past the
-        budget the oldest dispatches are confirmed and dropped first: that
-        frees the versions they superseded, for a wait that is near nothing
-        while the chip idles behind a slower host.  Only when the ring is
-        empty do tiles leave through the w2r queue."""
-        room = self._mem_budget - need
+        module holds on the chip: the LRU's current copies, the scratch
+        pool, what only the unconfirmed dispatches keep alive
+        (``_held_bytes``) and ``need``, the bytes the caller is about to
+        allocate (a stage-in's misses, the results of a dispatch that take
+        no donated buffer, new scratch tiles; 0 after an insertion).  Past
+        the budget the oldest dispatches are confirmed and dropped first:
+        that frees the versions they superseded, for a wait that is near
+        nothing while the chip idles behind a slower host.  Only when the
+        ring is empty do tiles leave through the w2r queue."""
+        room = self._mem_budget - need - self._scratch_bytes
         if self._mem_bytes + self._held_bytes <= room:
             return
         with spans.phase("devmod.pressure"):
@@ -764,7 +843,11 @@ class TPUDevice(Device):
 
         Escalates (re-raises) when a tile newer than its host copy cannot
         be written back — re-execution would silently read stale inputs,
-        and fail-stop beats wrong answers.
+        and fail-stop beats wrong answers.  A call that failed after it was
+        donated its written tiles has consumed them: such a tile reads as
+        deleted, cannot be written back, and stops the run here unless its
+        host copy is as new (a tile staged in and never written, which the
+        retry reads from the host).
         """
         from ..core.output import warning
         from ..runtime.scheduling import schedule_tasks
@@ -781,6 +864,8 @@ class TPUDevice(Device):
             self._evict_q.clear()
             self._mem_bytes = 0
             self._evict_bytes = 0
+        self._scratch.clear()
+        self._scratch_bytes = 0
         # tiles the victims will recompute from scratch (WRITE-only flows)
         # may be dropped freely; an RW flow's prior value is an INPUT, so
         # it gets no exemption — and any other tile newer than its host
@@ -945,10 +1030,20 @@ class TPUDevice(Device):
         class's traceable once a lane on the parameter buffers as they lie
         (no stack, no ``vmap``, no slices) and returns, per written flow,
         the tuple of every lane's result — so the whole batch costs ONE
-        enqueue and allocates its results alone.  B is padded to the next
-        power of two with copies of lane 0 (outputs of pad lanes are
-        dropped; kernels are pure XLA) to bound jit specializations to
-        log2(batch_max) per (dyld, signature).
+        enqueue.  B is padded to the next power of two with copies of lane
+        0 (outputs of pad lanes are dropped; kernels are pure XLA) to bound
+        jit specializations to log2(batch_max) per (dyld, signature).
+
+        The program is donated every lane of each written flow whose result
+        has its input's shape and dtype, so that the result takes the buffer
+        of the version it supersedes and the call allocates no output for it
+        (libtpu allocates every other output one by one before the launch,
+        50-60 us each: PERF.md, PR 38 and PR 39); a pad lane of such a flow
+        writes to a scratch tile of the pool and hands it back.  That is
+        safe while the module alone holds the tiles it consumes
+        (:meth:`_sole_holder`; the rule is in ``DataCopy``'s docstring); a
+        call in which anyone else holds one runs the same program without
+        donation and allocates its results, as every call did before.
 
         Eligibility: the class's device chore has a jax-traceable
         incarnation registered under its ``dyld`` name
@@ -971,54 +1066,86 @@ class TPUDevice(Device):
         if tr is None:
             return False
         data_flows = [f for f in tc.flows if not f.is_ctl]
-        cols = []
+        copies, cols = [], []
         for f in data_flows:
-            vals = [t.task.data[f.flow_index].value for t in batch]
-            v0 = vals[0]
-            if any(v.shape != v0.shape or v.dtype != v0.dtype
-                   for v in vals[1:]):
+            cs = [t.task.data[f.flow_index] for t in batch]
+            vals = [c.value for c in cs]
+            # no name is bound to a tile here: the donation below counts
+            # who refers to it
+            shape, dtype = vals[0].shape, vals[0].dtype
+            if any(v.shape != shape or v.dtype != dtype for v in vals):
                 return False   # ragged tiles: per-task path
+            copies.append(cs)
             cols.append(vals)
 
         B = len(batch)
         Bp = 1
         while Bp < B:
             Bp <<= 1
-        written = [f for f in data_flows if f.access & ACCESS_WRITE]
-        sig = tuple((v.shape, str(v.dtype)) for v in
-                    (c[0] for c in cols))
+        written = [i for i, f in enumerate(data_flows)
+                   if f.access & ACCESS_WRITE]
+        sig = tuple((vs[0].shape, str(vs[0].dtype)) for vs in cols)
         key = (dyld, Bp, sig)
         fn = self._vmap_cache.get(key)
         if fn is None:
-            fn = self._vmap_cache[key] = _fused_program(tr.apply, dyld, Bp)
-        flat = [v for vs in cols
-                for v in (vs + [vs[0]] * (Bp - B))]   # lane-0 padding
-        # what the call allocates: Bp results a written flow, which
-        # supersede B current versions and pad Bp - B lanes, and stay until
-        # the call has run (a flow's tiles are of one shape: one nbytes a
-        # flow, not a tile).  Its temporaries are not asked for: at 4 MiB
-        # tiles the v5e compiler's memory_analysis gives the program 0
+            fn = self._vmap_cache[key] = _fused_program(
+                tr.apply, dyld, Bp,
+                _donatable(tr.apply, [vs[0] for vs in cols], written))
+        if fn.donates and not all(self._sole_holder(copies[w], cols[w])
+                                  for w in fn.donates):
+            # someone else holds a tile this call would consume: the same
+            # program without donation, compiled when first needed
+            key += ("plain",)
+            fn = self._vmap_cache.get(key)
+            if fn is None:
+                fn = self._vmap_cache[key] = _fused_program(tr.apply, dyld,
+                                                            Bp)
+        # what the call allocates: Bp results for each written flow that is
+        # not donated, which supersede B current versions and pad Bp - B
+        # lanes and stay until the call has run (a flow's tiles are of one
+        # shape: one nbytes a flow, not a tile); a donated flow's results
+        # take its inputs' buffers.  Its temporaries are not asked for: at
+        # 4 MiB tiles the v5e compiler's memory_analysis gives the program 0
         # bytes of them for gemm / gemm_nt (64 lanes), syrk_ln (16),
         # qr_tsmqr / qr_unmqr (32), 11 MiB for trsm_rlt (16: 64 MiB of
         # results) and 100 MiB for qr_tsqrt (32: 384 MiB; no cell batches
         # it); tests/test_fused_tpu_compile.py holds the classes the cells
-        # batch to temporaries under a quarter of held
-        held = Bp * sum(cols[data_flows.index(w)][0].nbytes for w in written)
+        # batch to temporaries under a quarter of their results, donating
+        # or not
+        held = Bp * sum(cols[w][0].nbytes for w in written
+                        if w not in fn.donates)
         self._make_room(held)
+        # pad lanes: copies of lane 0, whose results are dropped; in a
+        # donated flow a scratch tile each, which the pad result takes and
+        # which goes back to the pool at the landing
+        npad = Bp - B
+        flat = []
+        for i, vs in enumerate(cols):
+            flat += vs
+            if npad:
+                flat += self._take_scratch(vs[0], npad) if i in fn.donates \
+                    else [vs[0]] * npad
         if self._dispatch_hook is not None:
             self._dispatch_hook(batch)
-        with self._call(tc, Bp, B):
+        donated = Bp * len(fn.donates)
+        with self._call(tc, Bp, B, donated):
             outs = fn(*flat)
         with spans.phase("devmod.land"):
             self.xla_calls += 1              # the whole batch, one enqueue
             note_xla_calls(1)
             assert len(outs) == len(written), (dyld, len(outs), len(written))
-            self._note_inflight(outs, held)
+            self.donated_results += donated
+            # one program's results are ready together: the first stands
+            # for the call in the ring
+            self._note_inflight(outs[0][0] if outs else None, held)
             for w, parts in zip(written, outs):
+                fi = data_flows[w].flow_index
                 for i, dtask in enumerate(batch):
-                    c = dtask.task.data[w.flow_index]
+                    c = dtask.task.data[fi]
                     c.value = parts[i]
                     c.version += 1
+                if npad and w in fn.donates:
+                    self._scratch[sig[w]].extend(parts[B:])
             for dtask in batch:
                 self.executed_tasks += 1
                 self._mark_written(dtask.task)
@@ -1026,33 +1153,87 @@ class TPUDevice(Device):
             self._count_dispatch(tc, B)
         return True
 
-    def _call(self, tc: Any, lanes: int, tasks: int) -> Any:
+    def _take_scratch(self, like: Any, n: int) -> list:
+        """``n`` scratch tiles of ``like``'s shape and dtype off the pool,
+        made (of zeros, and asked of the budget) where the pool lacks them.
+        Two written flows of one shape share a pool and take one after the
+        other; what a call took comes back as its pad lanes' results."""
+        pool = self._scratch.setdefault((like.shape, str(like.dtype)), [])
+        lacking = n - len(pool)
+        if lacking > 0:
+            import jax
+            zeros = np.zeros(like.shape, like.dtype)
+            self._make_room(lacking * zeros.nbytes)
+            pool.extend(jax.device_put([zeros] * lacking, self.jax_device))
+            self._scratch_bytes += lacking * zeros.nbytes
+        taken = pool[-n:]
+        del pool[-n:]
+        return taken
+
+    def _sole_holder(self, copies: list, vals: list) -> bool:
+        """Whether the module alone holds every array of ``vals``, the
+        current values of ``copies`` in one written flow of a batch, so that
+        a call may consume them.  One reading of the reference count a lane:
+        the ``DataCopy`` and the gather's list hold the array, and the ring
+        may (a dispatch's first result); anyone else who kept the array and
+        not the copy (the datum's host copy after a memory edge, a send
+        registered with the comm engine, a second lane of this call, a
+        caller's variable) shows as one more and keeps the call from
+        donating.  A push-out holds the array weakly while its transfer
+        flies, so it is asked by itself."""
+        for i in range(len(vals)):
+            extra = _refs_of_slot(vals, i) - _OWN_REFS
+            if extra and (extra != 1 or not any(
+                    vals[i] is a for out, _ in self._inflight
+                    for a in _arrays(out))):
+                return False
+            pushed = copies[i].pushed
+            if pushed is not None and pushed() is vals[i]:
+                return False
+        return True
+
+    def _call(self, tc: Any, lanes: int, tasks: int, donated: int = 0) -> Any:
         """What wraps the call of one dispatch (the fused program, or the
         body of a task submitted alone, whose few lines of Python around its
         jitted call are in it): nothing while the phase plane is off."""
-        return _Call(self, tc, lanes, tasks) if spans.phase_on \
+        return _Call(self, tc, lanes, tasks, donated) if spans.phase_on \
             else spans.phase("devmod.call")
 
     def _queue_depth(self) -> tuple[int, int]:
         """How many of the unconfirmed dispatches the chip has not run yet,
         and the ``held`` bytes of those it has (which the budget still
-        counts): ``is_ready()`` on the first ``jax.Array`` among a
+        counts): ``is_ready()`` on the first live ``jax.Array`` among a
         dispatch's results, which does not block.  One chip runs its
         programs in the order they were enqueued, so the ring is ready up to
         some entry and not from there on, and that entry is found by
         bisection: at most 6 probes for a ring of 32.  A dispatch whose body
         handed back no array says nothing of the chip: it is counted with
-        the dispatch enqueued before it.  What cannot be seen: a body whose
-        first array is one of its inputs passed through reads as run."""
+        the dispatch enqueued before it.  One whose arrays were all donated
+        was consumed by a later dispatch of this ring: it is as ready as the
+        first later entry with a live array.  What cannot be seen: a body
+        whose first array is one of its inputs passed through reads as
+        run."""
         ring = self._inflight
         lo, hi = 0, len(ring)
         while lo < hi:
             mid = probe = (lo + hi) // 2
-            leaf = _first_array(ring[probe][0])
+            leaf = _first_live(ring[probe][0])
             while leaf is None and probe > lo:
                 probe -= 1
-                leaf = _first_array(ring[probe][0])
-            if leaf is None or leaf.is_ready():
+                leaf = _first_live(ring[probe][0])
+            later = probe
+            while leaf is _DONATED and later + 1 < hi:
+                later += 1
+                leaf = _first_live(ring[later][0])
+                if leaf is None:            # no array: says nothing
+                    leaf = _DONATED
+            if leaf is _DONATED:
+                # nothing live before hi: what is at hi is owed, and past
+                # the ring's end nothing is
+                ran = hi == len(ring)
+            else:
+                ran = leaf is None or leaf.is_ready()
+            if ran:
                 lo = mid + 1
             else:
                 hi = probe
@@ -1080,11 +1261,19 @@ class TPUDevice(Device):
         """Wait for an enqueued dispatch; a device-side failure disables
         this device so later tasks demote to their remaining incarnations
         (the ``PARSEC_HOOK_RETURN_DISABLE`` path, ``device_gpu.c:2647-2652``)
-        and is re-raised — a failed kernel must not pass silently."""
+        and is re-raised — a failed kernel must not pass silently.  Arrays a
+        later call was donated cannot be waited on: a dispatch all of whose
+        arrays went that way was consumed by a later entry of the ring, and
+        one chip runs in order, so it is confirmed by the first later entry
+        with a live array (which stays in the ring)."""
         import jax
+        live = _live(out)
+        if not live and _first_live(out) is _DONATED:
+            live = next(filter(None, (_live(later)
+                                      for later, _ in self._inflight)), [])
         try:
             with spans.phase("devmod.inflight_wait"):
-                jax.block_until_ready(out)
+                jax.block_until_ready(live)
         except Exception:
             from ..core.output import warning
             self.enabled = False
@@ -1117,6 +1306,9 @@ class TPUDevice(Device):
                  "inflight_dispatches": len(self._inflight),
                  "inflight_held_bytes": self._held_bytes,
                  "inflight_held_bytes_peak": self.inflight_held_bytes_peak,
+                 "donated_results": self.donated_results,
+                 "scratch_tiles": sum(map(len, self._scratch.values())),
+                 "scratch_bytes": self._scratch_bytes,
                  "pressure_confirms": self.pressure_confirms,
                  "evicted_bytes": self.evicted_bytes,
                  "evict_stuck": self.evict_stuck,

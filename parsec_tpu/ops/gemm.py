@@ -26,9 +26,10 @@ def _gemm_update(a, b, c, precision=None):
 
     ``precision``: None = platform default (bf16 MXU passes on TPU);
     ``jax.lax.Precision.HIGHEST`` = f32-strict (bf16x6 passes).
-    No donation: the chained C copy may still be referenced (in-flight ring,
-    repo entries) — XLA's allocator recycles the freed buffer one step later
-    anyway.
+    No donation here: this is the body of a task submitted alone, one
+    result a call.  The fused batch program donates the chained C tiles
+    (``device/tpu.py:_run_vmapped``) under the ownership rule in
+    ``data/data.py:DataCopy``.
     """
     acc = jnp.dot(a, b, preferred_element_type=jnp.float32,
                   precision=precision)

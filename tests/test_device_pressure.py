@@ -22,6 +22,7 @@ import pytest
 
 import jax
 
+from parsec_tpu.device import tpu
 from parsec_tpu.models.tiled_gemm import tiled_gemm_ptg
 from parsec_tpu.runtime import Context
 
@@ -398,23 +399,44 @@ def _cholesky_under_budget(dev, n, nb, seed, budget_tiles):
     return gap, peak[0] / tile, len(tiles)
 
 
-def test_the_64x64_cholesky_dag_stays_inside_a_budget_of_2600_tiles(dev):
+@pytest.mark.parametrize("donating", [True, False],
+                         ids=["donating", "every_tile_kept_elsewhere"])
+def test_the_64x64_cholesky_dag_stays_inside_a_budget_of_2600_tiles(
+        dev, monkeypatch, donating):
     """The DAG of ``potrf-64k`` (64 x 64 tiles, 45,760 tasks) at nb=64: the
-    triangle is 2,080 tiles, and unbounded the ring of 32 dispatches holds
-    up to 2,000 superseded versions and padding lanes beside it.  Under a
-    budget of 2,600 tiles the module confirms its oldest dispatches early
-    and never holds more; nothing has to be evicted."""
+    triangle is 2,080 tiles.  Where every fused call is donated its written
+    tiles, the results take the buffers of the versions they supersede and
+    the ring holds what the per-task bodies superseded alone: 23 tiles at
+    most, no dispatch confirmed early.  Where none can be (every tile read
+    as kept by someone else: the program that donates nothing), the ring of
+    32 dispatches would hold up to 2,000 superseded versions and padding
+    lanes beside the triangle; under a budget of 2,600 tiles the module
+    confirms its oldest dispatches early and never holds more.  Nothing
+    has to be evicted either way."""
+    if not donating:
+        monkeypatch.setattr(tpu, "_OWN_REFS", -1)
     gap, peak_tiles, triangle = _cholesky_under_budget(dev, 4096, 64, 29, 2600)
     assert dev.executed_tasks == 45760 and triangle == 2080
     # the budget leaves the batches alone: the chip's count, call for call
     assert dev.xla_calls == 1868
     assert gap < 2e-6, gap
     assert peak_tiles <= 2600, peak_tiles
-    # unbounded, the ring's peak reads 2,000 tiles on this graph
-    assert 0 < dev.inflight_held_bytes_peak < 2000 * 64 * 64 * 4
-    assert dev.pressure_confirms >= 1
+    tile = 64 * 64 * 4
+    if donating:
+        # every result of the 1,787 fused calls, pad lanes included
+        assert dev.donated_results == 47128
+        assert dev.inflight_held_bytes_peak == 23 * tile
+        assert peak_tiles == 2080 + 23 and dev.pressure_confirms == 0
+        # the pad lanes of the widest batch, a tile each, and no more
+        assert dev.debug_state()["scratch_tiles"] == 31
+        assert dev._scratch_bytes == 31 * tile
+    else:
+        assert dev.donated_results == 0 and not dev._scratch
+        # unbounded, the ring's peak reads 2,000 tiles on this graph
+        assert 0 < dev.inflight_held_bytes_peak < 2000 * tile
+        assert dev.pressure_confirms >= 1
     assert dev.evicted_bytes == 0 and dev.evict_stuck == 0
-    assert dev.bytes_in == 2080 * 64 * 64 * 4      # nothing staged twice
+    assert dev.bytes_in == 2080 * tile      # nothing staged twice
 
 
 def test_half_the_triangle_evicts_and_restages_and_loses_nothing(dev):
@@ -459,9 +481,14 @@ def test_the_ring_is_bounded_by_count_when_the_budget_is_far(dev):
     ctx = Context(nb_cores=0)
     ctx.add_taskpool(tiled_gemm_ptg(A, B, C, devices="tpu"))
     ctx.wait(timeout=120)
-    assert len(dev._inflight) <= dev._max_inflight
-    assert dev._held_bytes == sum(held for _, held in dev._inflight)
-    assert dev.inflight_held_bytes_peak >= dev._held_bytes > 0
+    assert len(dev._inflight) == 8 <= dev._max_inflight
+    # every C tile's result took the buffer of the version before it: the
+    # ring holds dispatches and no bytes, and all but the newest entries'
+    # arrays were consumed by the calls after them
+    assert dev.donated_results == dev.executed_tasks == 8 ** 3
+    assert dev._held_bytes == dev.inflight_held_bytes_peak == 0
+    assert [out.is_deleted() for out, _ in dev._inflight] \
+        == [True] * 7 + [False]
     dev.sync()
     dev.flush_cache()
     ctx.fini()

@@ -162,16 +162,22 @@ def test_a_solve_s_batched_calls_are_counted_as_before(accel_device):
     assert dev.calls_by_class == {"GEMM": 2}
 
 
-def test_the_budget_is_asked_for_the_results_alone(accel_device, param):
+@pytest.mark.parametrize("kept", [False, True],
+                         ids=["donating", "a_tile_kept_elsewhere"])
+def test_the_budget_is_asked_for_the_results_alone(accel_device, param, kept):
     """Eight GEMM lanes under a budget that holds the 24 staged tiles and the
     8 results and not a stack of 24 beside them: the call is asked for what
-    it allocates, its results, and makes no room (asking for stacked
-    operands too would open ``devmod.pressure``)."""
+    it allocates and makes no room (asking for stacked operands too would
+    open ``devmod.pressure``).  What it allocates is nothing where every C
+    tile is the module's alone, since each result takes the buffer of the
+    version it supersedes, and its 8 results where someone kept a C tile's
+    array: that call runs the program that donates nothing."""
     dev, nb = accel_device, NB
     tile = nb * nb * 4
     tasks = _tasks("gemm", 8, nb)
     dev.stage_in_many(tasks)
     assert dev._mem_bytes == 24 * tile
+    keeper = tasks[5].data[2].value if kept else None
     dev._mem_budget = (24 + 8 + 4) * tile
     asked = []
     make_room = dev._make_room
@@ -189,7 +195,9 @@ def test_the_budget_is_asked_for_the_results_alone(accel_device, param):
         param("prof_spans", False)
         spans.phase_refresh()
         spans.phase_reset()
-    held = 8 * tile
+    held = 8 * tile if kept else 0
     assert dev._held_bytes == 0 and dev.inflight_held_bytes_peak == held
     assert asked == [held] and not pressed
     assert dev.evict_stuck == 0 and dev.pressure_confirms == 0
+    assert dev.donated_results == (0 if kept else 8)
+    assert keeper is None or not keeper.is_deleted()

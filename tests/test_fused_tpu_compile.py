@@ -1,12 +1,15 @@
 """The fused batch program (``device/tpu.py:_fused_program``) compiled for a
 described TPU v5e at the benchmark cells' widths, 4 MiB f32 tiles: what the
 chip's compiler allocates beside the results.  ``_run_vmapped`` asks the HBM
-budget for the results alone (``held``), so the executable's temporaries
-have to stay a small part of them.  Nothing runs and no chip is needed; the
+budget for the results alone (``held``), and for nothing where the results
+take donated buffers, so the executable's temporaries have to stay a small
+part of them; and the donating program has to pair every written lane's
+input with that lane's result.  Nothing runs and no chip is needed; the
 topology is described inside a fixture, in the test's own process (one
 process a machine may hold the TPU's library)."""
 
 import os
+import re
 
 import pytest
 
@@ -18,6 +21,9 @@ NB = 1024
 PROGRAMS = {"gemm": (3, 1, 64), "gemm_nt": (3, 1, 64), "trsm_rlt": (2, 1, 16),
             "syrk_ln": (2, 1, 16), "qr_unmqr": (3, 1, 32),
             "qr_tsmqr": (4, 2, 32)}
+# dyld -> the written flows' positions among a lane's arguments
+WRITTEN = {"gemm": [2], "gemm_nt": [2], "trsm_rlt": [1], "syrk_ln": [1],
+           "qr_unmqr": [2], "qr_tsmqr": [0, 1]}
 
 
 @pytest.fixture(scope="module")
@@ -55,4 +61,39 @@ def test_the_program_s_temporaries_are_a_small_part_of_its_results(one_chip,
     held = written * lanes * NB * NB * 4
     assert held <= mem.output_size_in_bytes < held + (1 << 20)
     assert mem.alias_size_in_bytes == 0          # nothing is donated
+    assert mem.temp_size_in_bytes <= held // 4, mem
+
+
+@pytest.mark.parametrize("dyld", list(PROGRAMS))
+def test_the_donating_program_pairs_each_written_lane_with_its_result(
+        one_chip, dyld):
+    """What ``_run_vmapped`` runs where the module alone holds the written
+    tiles: every written flow of these classes has a result of its input's
+    shape and dtype, so all of them are donated; the compiled module's
+    ``input_output_alias`` gives result ``j * lanes + i`` (flow j, lane i)
+    the parameter of that flow's lane i, the aliased bytes are the results'
+    and the temporaries stay under a quarter of them."""
+    import parsec_tpu.models.cholesky  # noqa: F401  (registers traceables)
+    import parsec_tpu.models.qr  # noqa: F401
+    import parsec_tpu.ops.gemm  # noqa: F401
+    from parsec_tpu.device.tpu import _donatable, _fused_program
+    from parsec_tpu.ptg.lowering import find_traceable
+    flows, written, lanes = PROGRAMS[dyld]
+    apply = find_traceable(dyld).apply
+    tile = jax.ShapeDtypeStruct((NB, NB), jnp.float32, sharding=one_chip)
+    donates = _donatable(apply, [tile] * flows, WRITTEN[dyld])
+    assert list(donates) == WRITTEN[dyld] and len(donates) == written
+    fn = _fused_program(apply, dyld, lanes, donates)
+    assert fn.donates == donates and fn.__name__ == f"fused_{dyld}"
+    compiled = fn.lower(*[tile] * (flows * lanes)).compile()
+    (aliases,) = re.findall(r"input_output_alias=\{(.*?\)) \}",
+                            compiled.as_text())
+    pairs = {int(out): int(param) for out, param in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, may-alias\)", aliases)}
+    assert pairs == {j * lanes + i: f * lanes + i
+                     for j, f in enumerate(donates) for i in range(lanes)}
+    mem = compiled.memory_analysis()
+    held = written * lanes * NB * NB * 4
+    assert held <= mem.output_size_in_bytes < held + (1 << 20)
+    assert mem.alias_size_in_bytes == held
     assert mem.temp_size_in_bytes <= held // 4, mem

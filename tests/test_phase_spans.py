@@ -258,18 +258,26 @@ def test_on_every_second_of_a_solve_has_a_documented_owner(
 
 
 class _Result:
-    """What a dispatch hands back, as the probe sees it: ``is_ready``."""
+    """What a dispatch hands back, as the probe sees it: ``is_ready``, and
+    ``is_deleted`` (``ready`` None: an array a later call was donated,
+    which raises when asked whether it is ready, as a ``jax.Array`` does)."""
 
     def __init__(self, ready, asked):
         self.ready, self.asked = ready, asked
 
+    def is_deleted(self):
+        return self.ready is None
+
     def is_ready(self):
+        if self.ready is None:
+            raise RuntimeError("Array has been deleted.")
         self.asked.append(self)
         return self.ready
 
 
 # the ring, oldest first: R an entry the chip has run, N one it has not,
-# X one whose body handed back no array; and the depth the probe must give
+# X one whose body handed back no array, D one all of whose arrays a later
+# call was donated; and the depth the probe must give
 RINGS = [("empty", "", 0),
          ("all_run", "R" * 32, 0),
          ("none_run", "N" * 32, 32),
@@ -283,7 +291,17 @@ RINGS = [("empty", "", 0),
          ("no_array_oldest", "XNNN", 3),
          ("no_array_newest", "RRRX", 0),
          ("no_arrays_at_all", "XXXX", 0),
-         ("no_arrays_around_the_edge", "RXXXNXXX", 4)]
+         ("no_arrays_around_the_edge", "RXXXNXXX", 4),
+         # all donated: as ready as the first later entry with a live array
+         ("a_chain_of_donated_calls_the_last_run", "D" * 31 + "R", 0),
+         ("a_chain_of_donated_calls_the_last_owed", "D" * 31 + "N", 32),
+         ("donated_among_the_run", "RDDRRNN", 2),
+         ("donated_among_the_owed", "RRNDDN", 4),
+         ("donated_around_the_edge", "RDDDNDDN", 7),
+         ("donated_then_no_array", "RRDXNN", 4),
+         ("no_array_then_donated", "RRXDNN", 3),
+         ("donated_newest", "RRDD", 0),
+         ("one_live_result_beside_a_donated_one", "RRMNN", 3)]
 
 
 @pytest.mark.parametrize("ring,depth", [r[1:] for r in RINGS],
@@ -292,7 +310,9 @@ def test_the_probe_finds_the_first_dispatch_the_chip_still_owes(ring, depth):
     asked = []
     results = {"R": lambda: ((_Result(True, asked), 5.0),),
                "N": lambda: ((_Result(False, asked),), (np.zeros(1),)),
-               "X": lambda: (np.float32(1.0), [None])}
+               "X": lambda: (np.float32(1.0), [None]),
+               "D": lambda: (_Result(None, asked), (_Result(None, asked),)),
+               "M": lambda: (_Result(None, asked), _Result(False, asked))}
     dev = TPUDevice.__new__(TPUDevice)
     # every entry holds 2 ** i bytes, so the sum names the entries counted
     dev._inflight = [(results[kind](), 1 << i) for i, kind in enumerate(ring)]

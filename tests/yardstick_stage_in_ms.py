@@ -41,11 +41,12 @@ def test_manifest_lists_the_stage_in_metric_on_every_dynamic_cell():
 
 def test_manifest_lists_the_dtd_cell_on_the_twin_s_metrics_that_read_it():
     """``test_dtd_gemm.py``'s assertion of the same lists, with the metrics of
-    PR 35 and PR 38 among the shared ones."""
+    PR 35, PR 38 and PR 39 among the shared ones."""
     dtd = _load(os.path.join(BENCH, "tests", "test_dtd_gemm.py"))
     shared = dtd.SHARED | {NAME, "devmod.call_us_per_result",
                            "devmod.dispatch_own_us_per_task",
-                           "devmod.chip_queue_depth"}
+                           "devmod.chip_queue_depth",
+                           "devmod.donated_result_share"}
     manifest, per_layer = dtd._manifest()
     (rate,) = [m for m in manifest["end_to_end"]
                if m["name"] == "dynamic.gflops"]
