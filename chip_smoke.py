@@ -353,10 +353,10 @@ def stage_dynamic_cholesky(cfg) -> str:
             f"residual={res:.3e}")
 
 
-def stage_dynamic_qr(cfg) -> str:
-    """``tiled_qr_ptg`` through ``Context``: four classes that write two or
-    three tiles a task into two collections, every product in f32; both of
-    the benchmark's gaps (``benchmarks/reference_qr.py``) on seeded probes."""
+def run_qr(cfg, n: int, nb: int) -> tuple[dict[str, int], list[float], int]:
+    """``tiled_qr_ptg`` through one bare ``Context`` at (n, nb): which
+    accelerator ran how many tasks, both of the benchmark's gaps
+    (``benchmarks/reference_qr.py``) on seeded probes, the pool's tasks."""
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), "benchmarks"))
     import reference_qr as refq
@@ -364,7 +364,6 @@ def stage_dynamic_qr(cfg) -> str:
     from parsec_tpu.models.qr import tiled_qr_ptg
     from parsec_tpu.runtime import Context
 
-    n, nb = cfg.n_qr, cfg.nb_qr
     nt = n // nb
     tiles = refq.qr_tiles(cfg.seed, n, nb)
     X = probes(cfg.seed, n)
@@ -390,9 +389,40 @@ def stage_dynamic_qr(cfg) -> str:
     res = [rel_residual(g, w)
            for g, w in zip(got, (ax, refq.apply_t(tiles, ax, nb)))]
     require(max(res) < TOL_QR_F32, f"residuals {res}")
+    return per_dev, res, ntasks
+
+
+def stage_dynamic_qr(cfg) -> str:
+    """``tiled_qr_ptg`` through ``Context``: four classes that write two or
+    three tiles a task into two collections, every product in f32."""
+    n, nb = cfg.n_qr, cfg.nb_qr
+    per_dev, res, ntasks = run_qr(cfg, n, nb)
     return (f"N={n} nb={nb} f32 precision=highest tasks={ntasks} "
             f"per_device={per_dev} cpu_tasks=0 all finite "
             f"|Q(Rx)-Ax|={res[0]:.3e} |RtRx-AtAx|={res[1]:.3e}")
+
+
+def stage_ctx4_qr(cfg) -> str:
+    """The benchmark's ``geqrf52k.ctx4`` in small: the tile QR at 16 x 16
+    tiles under one ``Context`` over four chips.  ``best_device`` deals the
+    tile columns, a flood hands another chip's tasks back to the scheduler,
+    V and T tiles cross from chip to chip: every chip ran tasks, none more
+    than 40% of them, and tiles did cross."""
+    n, nb = cfg.n_ctx4, cfg.nb_ctx4
+    base = {d.name: (d.bytes_d2d, d.flood_putbacks) for d in accelerators()}
+    per_dev, res, ntasks = run_qr(cfg, n, nb)
+    require(len(per_dev) == 4 and min(per_dev.values()) > 0,
+            f"an accelerator executed nothing: {per_dev}")
+    require(max(per_dev.values()) <= 0.4 * ntasks,
+            f"one accelerator took more than 40%: {per_dev}")
+    d2d = sum(d.bytes_d2d - base.get(d.name, (0, 0))[0]
+              for d in accelerators())
+    putbacks = sum(d.flood_putbacks - base.get(d.name, (0, 0))[1]
+                   for d in accelerators())
+    require(d2d > 0, "no tile crossed from chip to chip")
+    return (f"N={n} nb={nb} f32 precision=highest tasks={ntasks} "
+            f"per_device={per_dev} cpu_tasks=0 d2d_GB={d2d / 1e9:.3f} "
+            f"putbacks={putbacks} |Q(Rx)-Ax|={res[0]:.3e} |RtRx-AtAx|={res[1]:.3e}")
 
 
 def stage_dtd_gemm(cfg) -> str:
@@ -589,6 +619,7 @@ STAGES = {
 FOUR_CHIP_STAGES = {
     "four_ranks": stage_four_ranks,
     "mesh_lowered": stage_mesh_lowered,
+    "ctx4_qr": stage_ctx4_qr,
 }
 
 
@@ -613,6 +644,7 @@ def main(argv: list[str] | None = None) -> int:
         cfg.n_lowered, cfg.nb_lowered = 512, 128
         cfg.n_dynamic, cfg.nb_dynamic = 512, 128
         cfg.n_qr, cfg.nb_qr = 512, 128
+        cfg.n_ctx4, cfg.nb_ctx4 = 1024, 64
         cfg.n_dtd = 256
         cfg.n_served, cfg.nb_served = 256, 64
         cfg.stencil_rows, cfg.stencil_mb = 16, 1024
@@ -620,6 +652,7 @@ def main(argv: list[str] | None = None) -> int:
         cfg.n_lowered, cfg.nb_lowered = 16384, 512      # BASELINE headline
         cfg.n_dynamic, cfg.nb_dynamic = 16384, 1024
         cfg.n_qr, cfg.nb_qr = 4096, 512
+        cfg.n_ctx4, cfg.nb_ctx4 = 8192, 512
         cfg.n_dtd = 8192
         cfg.n_served, cfg.nb_served = 4096, 512
         cfg.stencil_rows, cfg.stencil_mb = 16, 1 << 16  # run_stencil_bench

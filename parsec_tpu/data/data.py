@@ -102,8 +102,15 @@ class Data:
             self.device_copies[copy.device_index] = copy
             return copy
 
-    def detach_copy(self, device_index: int) -> DataCopy | None:
+    def detach_copy(self, device_index: int,
+                    copy: DataCopy | None = None) -> DataCopy | None:
+        """Drop the device's copy; given ``copy``, only if that is the one
+        the datum holds there (a copy made invalid and staged anew since is
+        another object)."""
         with self._lock:
+            if copy is not None \
+                    and self.device_copies.get(device_index) is not copy:
+                return None
             return self.device_copies.pop(device_index, None)
 
     def newest_copy(self) -> DataCopy | None:

@@ -4,9 +4,10 @@ Rebuild of ``parsec/mca/device/device.{c,h}`` (SURVEY §2.5): devices register
 with the process-global registry; each carries transfer/execution statistics
 (``device.h:151-156``), per-precision gflops ratings and a load accumulator
 (``device.h:161-166``); ``best_device`` implements
-``parsec_get_best_device``: the device that already owns the task's RW
-data, else argmin over (device_load + time_estimate(task)) with task
-classes contributing ``time_estimate`` functions
+``parsec_get_best_device``: the device that already owns the datum of the
+task's first written flow, else argmin over (device_load +
+time_estimate(task)) with task classes contributing ``time_estimate``
+functions
 (``parsec_internal.h:441``); the chosen device carries the task's estimate
 in its load from selection until the task completes.
 """
@@ -158,13 +159,20 @@ class DeviceRegistry:
     def best_device(self, task: Any, device_type: str | None = None,
                     allowed: Any = None) -> Device | None:
         """``parsec_get_best_device``.  A task keeps the device already
-        selected for it.  Otherwise the device that owns the data of the
-        task's first written flow wins, so a chain of updates to one tile
-        stays where the tile lives; failing that, min (load +
-        time_estimate).  The choice is committed: recorded in
-        ``task.selected_device`` and added to the device's load until
-        :meth:`Device.release_task`.  ``allowed`` restricts the candidates
-        to a set of device indices (a rank bound to its own chip)."""
+        selected for it.  Otherwise the datum of the task's first written
+        flow decides: the device that owns it wins, so a chain of updates
+        to one tile stays where the tile lives; failing that (a tile only
+        the host holds yet), min (load + time_estimate).  With several
+        accelerators the first writer of a tile is therefore chosen by load
+        and every later one follows it: the tile QR and the Cholesky deal
+        their tile columns over the accelerators at their first step and
+        keep them there.  (``Data.preferred_device``, the reference's
+        advice, is not read: PERF.md, PR 40, (e).)  The choice is
+        committed: recorded in ``task.selected_device`` and added to the
+        device's load until :meth:`Device.release_task`.  ``allowed``
+        restricts the candidates to a set of device indices (a rank bound
+        to its own chip); an owning device outside the candidates does not
+        decide."""
         def usable(d: Device) -> bool:
             return (d.enabled
                     and (device_type is None or d.type == device_type)

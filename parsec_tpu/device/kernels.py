@@ -58,13 +58,16 @@ def find_incarnation(name: str, device: Any) -> Callable | None:
     return None
 
 
-def traceable_body(apply: Callable) -> Callable:
+def traceable_body(apply: Callable, jitted: Callable | None = None) -> Callable:
     """A per-task device body from a jax-traceable: ``apply`` takes the
     task's non-CTL flow values in flow order and returns the new value of its
     written flows, one value or a tuple in flow order (the contract of
     ``ptg.lowering.Traceable.apply`` and of the fused batch program).  Every
     written flow gets its value and a new version, as ``_run_vmapped`` does
-    for a batch."""
+    for a batch.  ``jitted``: where ``apply`` is one ``jax.jit`` function on
+    those values, a callable that hands it out; the body carries it as
+    ``body.jitted``, and the device module compiles it for every accelerator
+    at once (``TPUDevice._meet_task_program``)."""
     def body(es: Any, task: Any, device: Any) -> Any:
         from ..data.data import ACCESS_WRITE
         flows = [f for f in task.task_class.flows if not f.is_ctl]
@@ -80,6 +83,7 @@ def traceable_body(apply: Callable) -> Callable:
             c.value = value
             c.version += 1
         return out
+    body.jitted = jitted
     return body
 
 

@@ -113,6 +113,15 @@ def _donatable(apply: Callable, lane: list, written: list[int]) -> tuple:
                  if o.shape == lane[w].shape and o.dtype == lane[w].dtype)
 
 
+def _compile_quietly(fn: Callable, avals: list) -> None:
+    """Lower and compile ``fn`` for ``avals`` ahead of its first call there;
+    a failure is left to that call, which meets it again and reports it."""
+    try:
+        fn.lower(*avals).compile()
+    except Exception:       # noqa: BLE001
+        pass
+
+
 def _refs_of_slot(vals: list, i: int) -> int:
     return getrefcount(vals[i])
 
@@ -130,6 +139,18 @@ _OWN_REFS = _own_refs()
 
 def _copy_nbytes(copy: DataCopy) -> int:
     return getattr(copy.value, "nbytes", 0) if copy.value is not None else 0
+
+
+def _host_is_newer(host: DataCopy | None, copy: DataCopy) -> bool:
+    """The host already holds, as a host array, a later version than the
+    dirty ``copy``: another accelerator wrote the tile after this one and
+    its flush came first.  A write-back never lowers the host's version,
+    so the order of the flushes does not decide what the host keeps.  (A
+    host copy that a memory edge handed the device array itself is ahead by
+    count only and waits for this very write-back.)"""
+    return host is not None and host.version > copy.version \
+        and host.coherency != COHERENCY_INVALID \
+        and isinstance(host.value, np.ndarray)
 
 
 def _pushed_out(copy: DataCopy) -> bool:
@@ -274,7 +295,8 @@ class _Call:
         row["held_run_bytes_sum"] += held_run
         self.row = row
         self.span = spans.phase("devmod.call", task_class=tc.name,
-                                lanes=lanes, depth=depth)
+                                lanes=lanes, depth=depth,
+                                device=dev.device_index)
 
     def __enter__(self) -> None:
         self.span.__enter__()
@@ -356,12 +378,26 @@ class TPUDevice(Device):
         self.deferred_evictions = 0
         self.evicted_bytes = 0      # the bytes behind deferred_evictions
         self.evict_stuck = 0        # over budget with nothing evictable
+        # clean copies the LRU let go (the host or the chip that wrote the
+        # tile still holds that version): no device-to-host copy, and not
+        # in evicted_bytes
+        self.replicas_dropped = 0
+        self.replica_bytes_dropped = 0
+        # several accelerators under one context: tiles staged in from
+        # another chip's copy (their bytes are Device.bytes_d2d, and
+        # bytes_in counts host tiles alone), and other chips' copies this
+        # chip's writes made invalid
+        self.d2d_tiles = 0
+        self.invalidated_copies = 0
         # the byte budget at work: dispatches confirmed early because of
         # it, and the most the ring ever held
         self.pressure_confirms = 0
         self.inflight_held_bytes_peak = 0
         # fused-dispatch cache ((dyld, padded B, signature) -> jitted fn)
         self._vmap_cache: dict[Any, Callable] = {}
+        # per-task bodies that name their jitted function (``body.jitted``),
+        # once this device or a peer has met them: body -> that function
+        self._task_programs: dict[Callable, Callable] = {}
         # fault-injection seam for the pressure harness: called with the
         # batch right before the fused XLA dispatch (the reference gates
         # its GPU fault tests on real hardware; here injected faults
@@ -517,15 +553,27 @@ class TPUDevice(Device):
                 if c.coherency != COHERENCY_INVALID:
                     self._start_d2h(c)
                     victims.append(c)
+                else:
+                    # written back earlier, or made invalid by another
+                    # chip's write: garbage, which the datum lets go here
+                    c.original.detach_copy(self.device_index, c)
             i = 0
             if victims:
                 pins.fire(PinsEvent.DEVICE_EVICT, None, len(victims))
             try:
                 while i < len(victims):
-                    self._writeback(victims[i])
-                    self.evicted_bytes += _copy_nbytes(victims[i])
+                    c = victims[i]
+                    # a clean copy only leaves: someone else holds this
+                    # version (the host, or the chip that wrote the tile)
+                    clean = c.coherency == COHERENCY_SHARED
+                    self._writeback(c)
+                    if clean:
+                        self.replicas_dropped += 1
+                        self.replica_bytes_dropped += _copy_nbytes(c)
+                    else:
+                        self.evicted_bytes += _copy_nbytes(c)
+                        self.deferred_evictions += 1
                     i += 1
-                    self.deferred_evictions += 1
             except BaseException:
                 # a failed writeback must leave the unwritten victims
                 # reachable: failure recovery salvages from _evict_q, and a
@@ -573,8 +621,9 @@ class TPUDevice(Device):
     def _writeback(self, copy: DataCopy) -> None:
         """Push a dirty device copy back to the host copy, then drop it."""
         d = copy.original
-        if copy.coherency in (COHERENCY_OWNED, COHERENCY_EXCLUSIVE):
-            host = d.get_copy(0)
+        host = d.get_copy(0)
+        if copy.coherency in (COHERENCY_OWNED, COHERENCY_EXCLUSIVE) \
+                and not _host_is_newer(host, copy):
             value = np.asarray(copy.value)
             self.writebacks += 1
             self.writebacks_early += _pushed_out(copy)
@@ -588,7 +637,7 @@ class TPUDevice(Device):
             if d.owner_device == self.device_index:
                 d.owner_device = 0
             self.bytes_out += value.nbytes
-        d.detach_copy(self.device_index)
+        d.detach_copy(self.device_index, copy)
         copy.coherency = COHERENCY_INVALID
         copy.pushed = None
         if _spill_hooks:
@@ -669,11 +718,17 @@ class TPUDevice(Device):
             return
         keys = list(missing)
         self._make_room(sum(_copy_nbytes(c) for c in missing.values()))
-        values = self._transfer([missing[k].value for k in keys])
+        srcs = [missing[k] for k in keys]
+        # a miss whose newest copy is another accelerator's array crosses
+        # from chip to chip
+        far = [i for i, c in enumerate(srcs)
+               if c.device_index not in (0, self.device_index)]
+        values = self._transfer([c.value for c in srcs], far)
+        crossed = set(far)
         landed: dict[Any, DataCopy] = {}
         batch_nb = 0
-        for k, value in zip(keys, values):
-            src = missing[k]
+        for i, (k, value) in enumerate(zip(keys, values)):
+            src = srcs[i]
             d = src.original
             dev_copy = d.get_copy(self.device_index)
             if dev_copy is None:
@@ -685,7 +740,11 @@ class TPUDevice(Device):
             dev_copy.version = src.version
             dev_copy.coherency = COHERENCY_SHARED
             nb = getattr(src.value, "nbytes", 0)
-            self.bytes_in += nb
+            if i in crossed:
+                self.bytes_d2d += nb
+                self.d2d_tiles += 1
+            else:
+                self.bytes_in += nb
             batch_nb += nb
             self._cache_insert(dev_copy, nb)
             landed[k] = dev_copy
@@ -695,9 +754,14 @@ class TPUDevice(Device):
             # lands above — a KeyError here is a real landing bug
             task.data[fi] = landed[k]
 
-    def _transfer(self, values: list) -> list:
-        """The host-to-device copy itself, for ``stage_in_many`` and
-        ``prefetch_data``.  A numpy tile goes straight to the call
+    def _transfer(self, values: list, far: list[int] = ()) -> list:
+        """The copy to this device itself, for ``stage_in_many`` and
+        ``prefetch_data``.  ``far``: the positions of the values that are
+        another accelerator's arrays; they cross in one ``jax.device_put``
+        under the span ``devmod.d2d`` (PJRT orders the copy behind the
+        program that writes the source and any donation of the source
+        behind the copy: PERF.md, PR 40, step 0 (d)).  A numpy tile goes
+        straight to the call
         ``jax.device_put`` ends in (:func:`_host_put`).  What
         ``jax.device_put`` does around that call costs 76-86 us a tile of
         Python on the chip's host, against 165-190 us for the call itself
@@ -707,12 +771,18 @@ class TPUDevice(Device):
         put = _host_put()
         out = [put(v, jd) for v in values] if put is not None \
             else [None] * len(values)
+        def put_together(which: list[int]) -> None:
+            import jax
+            for i, o in zip(which, jax.device_put([values[i] for i in which],
+                                                  jd)):
+                out[i] = o
+
+        if far:
+            with spans.phase("devmod.d2d"):
+                put_together(far)
         rest = [i for i, o in enumerate(out) if o is None]
         if rest:
-            import jax
-            for i, o in zip(rest, jax.device_put([values[i] for i in rest],
-                                                 jd)):
-                out[i] = o
+            put_together(rest)
         return out
 
     def prefetch_data(self, datas: list[Any]) -> int:
@@ -978,8 +1048,12 @@ class TPUDevice(Device):
                     held = sum(_copy_nbytes(c) for c in
                                self._written_copies(dtask.task))
                     self._make_room(held)
+                    warming = () if dtask.submit in self._task_programs \
+                        else self._meet_task_program(dtask)
                     with self._call(dtask.task.task_class, 1, 1):
                         out = dtask.submit(dtask.es, dtask.task, self)
+                    for thread in warming:  # a body's first call alone
+                        thread.join()
                     with spans.phase("devmod.land"):
                         self.xla_calls += 1
                         note_xla_calls(1)
@@ -998,6 +1072,21 @@ class TPUDevice(Device):
                 self.release_task(dtask.task)
                 complete(dtask.es, dtask.task)
         pins.fire(PinsEvent.DEVICE_BATCH_END, None, len(batch))
+
+    def _meet_task_program(self, dtask: TPUDeviceTask) -> list:
+        """The first task a per-task body runs on this device: where the
+        body names its jitted function (``kernels.traceable_body``'s
+        ``jitted``), the peers compile it beside this call."""
+        body = dtask.submit
+        jitted = getattr(body, "jitted", None)
+        fn = self._task_programs[body] = jitted() if jitted else None
+        if fn is None:
+            return []
+        task = dtask.task
+        values = [task.data[f.flow_index].value
+                  for f in task.task_class.flows if not f.is_ctl]
+        return self._compile_for_peers(
+            "_task_programs", body, fn, [(v.shape, v.dtype) for v in values])
 
     def _count_dispatch(self, tc: Any, ntasks: int) -> None:
         by_tasks, by_calls = self.tasks_by_class, self.calls_by_class
@@ -1018,7 +1107,24 @@ class TPUDevice(Device):
         # cf. kernel_epilog versions->owner, device_gpu.c:2251)
         for c in self._written_copies(task):
             c.coherency = COHERENCY_OWNED
-            c.original.owner_device = self.device_index
+            d = c.original
+            d.owner_device = self.device_index
+            copies = d.device_copies
+            if len(copies) > 1 + (0 in copies):
+                self._invalidate_elsewhere(d)
+
+    def _invalidate_elsewhere(self, d: Any) -> None:
+        """This device wrote ``d``: the copies other accelerators hold are
+        of an older version now (write-invalidate, ``Data.start_write``'s
+        rule on the device path).  They leave their chips' LRUs as garbage,
+        never as write-backs; the host's copy keeps its version and is what
+        :func:`_host_is_newer` compares."""
+        with d._lock:
+            for idx, other in d.device_copies.items():
+                if idx != 0 and idx != self.device_index \
+                        and other.coherency != COHERENCY_INVALID:
+                    other.coherency = COHERENCY_INVALID
+                    self.invalidated_copies += 1
 
     # --------------------------------------------------- fused batch dispatch
     def _run_vmapped(self, batch: list[TPUDeviceTask]) -> bool:
@@ -1087,10 +1193,13 @@ class TPUDevice(Device):
         sig = tuple((vs[0].shape, str(vs[0].dtype)) for vs in cols)
         key = (dyld, Bp, sig)
         fn = self._vmap_cache.get(key)
+        warming: Any = ()
         if fn is None:
             fn = self._vmap_cache[key] = _fused_program(
                 tr.apply, dyld, Bp,
                 _donatable(tr.apply, [vs[0] for vs in cols], written))
+            warming = self._compile_for_peers(
+                "_vmap_cache", key, fn, [a for a in sig for _ in range(Bp)])
         if fn.donates and not all(self._sole_holder(copies[w], cols[w])
                                   for w in fn.donates):
             # someone else holds a tile this call would consume: the same
@@ -1100,6 +1209,9 @@ class TPUDevice(Device):
             if fn is None:
                 fn = self._vmap_cache[key] = _fused_program(tr.apply, dyld,
                                                             Bp)
+                warming = self._compile_for_peers(
+                    "_vmap_cache", key, fn,
+                    [a for a in sig for _ in range(Bp)])
         # what the call allocates: Bp results for each written flow that is
         # not donated, which supersede B current versions and pad Bp - B
         # lanes and stay until the call has run (a flow's tiles are of one
@@ -1130,6 +1242,8 @@ class TPUDevice(Device):
         donated = Bp * len(fn.donates)
         with self._call(tc, Bp, B, donated):
             outs = fn(*flat)
+        for thread in warming:      # a program's first call alone
+            thread.join()
         with spans.phase("devmod.land"):
             self.xla_calls += 1              # the whole batch, one enqueue
             note_xla_calls(1)
@@ -1152,6 +1266,42 @@ class TPUDevice(Device):
             self.batched_dispatches += 1
             self._count_dispatch(tc, B)
         return True
+
+    def _compile_for_peers(self, cache: str, key: Any, fn: Callable,
+                           args: list[tuple]) -> list:
+        """A program this device meets for the first time (a fused batch
+        program it has just built, ``cache`` = ``_vmap_cache``; a per-task
+        body's jitted function, ``_task_programs``) is compiled for the
+        other accelerators of its kind at the same time, each on a thread of
+        its own beside this device's first call (XLA compiles outside the
+        interpreter lock): on the TPU an executable is bound to its chip and
+        so is its entry in the persistent cache, and four chips that meet
+        the same batches would otherwise compile every program four times,
+        one after the other, inside the solve that meets it first (PERF.md,
+        PR 40).  The peers share the jitted object (their ``cache`` gets
+        ``fn`` under ``key``), so what is compiled here for a peer is what
+        the peer's first call finds.  ``args``: (shape, dtype) of each
+        argument (no array: a reference more to a tile would keep a call
+        from donating it).  Returns the threads to join; nothing with one
+        accelerator."""
+        import jax
+        from jax.sharding import SingleDeviceSharding
+        kind = self.jax_device.device_kind
+        threads = []
+        for peer in registry.devices:
+            if peer is self or not isinstance(peer, TPUDevice) \
+                    or not peer.enabled or key in getattr(peer, cache) \
+                    or peer.jax_device.device_kind != kind:
+                continue
+            getattr(peer, cache)[key] = fn
+            where = SingleDeviceSharding(peer.jax_device)
+            avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=where)
+                     for shape, dtype in args]
+            thread = threading.Thread(target=_compile_quietly,
+                                      args=(fn, avals), daemon=True)
+            thread.start()
+            threads.append(thread)
+        return threads
 
     def _take_scratch(self, like: Any, n: int) -> list:
         """``n`` scratch tiles of ``like``'s shape and dtype off the pool,
@@ -1312,9 +1462,13 @@ class TPUDevice(Device):
                  "pressure_confirms": self.pressure_confirms,
                  "evicted_bytes": self.evicted_bytes,
                  "evict_stuck": self.evict_stuck,
+                 "replicas_dropped": self.replicas_dropped,
+                 "replica_bytes_dropped": self.replica_bytes_dropped,
+                 "invalidated_copies": self.invalidated_copies,
                  "cache_hits": self.cache_hits,
                  "cache_misses": self.cache_misses,
                  "bytes_in": self.bytes_in, "bytes_out": self.bytes_out,
+                 "bytes_d2d": self.bytes_d2d, "d2d_tiles": self.d2d_tiles,
                  "pushouts": self.pushouts, "writebacks": self.writebacks,
                  "writebacks_early": self.writebacks_early,
                  "flood_selected": self.flood_selected,

@@ -241,7 +241,8 @@ def _register() -> None:
     from ..ptg.lowering import register_traceable
     for name, tr in _TRACEABLES.items():
         register_kernel(name, "tpu", traceable_body(
-            lambda *vals, _name=name: _program(_name)(*vals)))
+            lambda *vals, _name=name: _program(_name)(*vals),
+            jitted=functools.partial(_program, name)))
         register_traceable(name, tr)
 
 

@@ -1,9 +1,10 @@
 """Tier-1 guards the yardstick: the tests that live with the benchmark
 (``benchmarks/tests/``: the manifest, the trace reduction, the control, a
 traced rehearsal of every cell sound and broken, the phase metrics, the 64k
-cell's own, the DTD cell's own, the QR cell's own) and ``yardstick_writeback_early_share.py``,
-``yardstick_flood_metrics.py``, ``yardstick_stage_in_ms.py``,
-``yardstick_dispatch_metrics.py`` and ``yardstick_donated_share.py`` beside this file are collected here under
+cell's own, the DTD cell's own, the QR cell's own, the four-chip cell's own)
+and ``yardstick_writeback_early_share.py``, ``yardstick_flood_metrics.py``,
+``yardstick_stage_in_ms.py``, ``yardstick_dispatch_metrics.py``,
+``yardstick_donated_share.py`` and ``yardstick_qr_cell.py`` beside this file are collected here under
 their own names, so each counts, and a name that two files give is an error
 here and not one test fewer.  They need no chip.  The rehearsals run in
 processes of their own, and all from this one file, so that under ``--dist
@@ -17,7 +18,10 @@ file the benchmark has).  ``test_dtd_gemm.py`` asserts the same of the lists'
 third entry, so the count stays and the assertion holds again.  Nor is
 ``test_dtd_gemm.py``'s list of the metrics that the DTD cell shares with its
 twin, which stopped being whole when PR 35 added one: the assertion is
-``yardstick_stage_in_ms.py``'s, with that one among them."""
+``yardstick_stage_in_ms.py``'s, with that one among them.  Nor is
+``test_geqrf32k.py``'s test of the manifest, which asserts that every cell has
+one chip and stopped holding when PR 40 appended ``geqrf52k.ctx4``:
+``yardstick_qr_cell.py`` is that test without that one assertion."""
 
 import importlib.util
 import os
@@ -29,16 +33,21 @@ _SUPERSEDED = {
     # by test_manifest_still_lists_the_64k_cell_third_on_the_dynamic_lists
     "test_manifest_lists_the_64k_cell_where_its_readers_find_something",
     # by test_manifest_lists_the_dtd_cell_on_the_twin_s_metrics_that_read_it
-    "test_manifest_lists_the_dtd_cell_where_its_readers_find_something"}
+    "test_manifest_lists_the_dtd_cell_where_its_readers_find_something",
+    # by test_manifest_still_lists_the_qr_cell_where_it_was_appended
+    "test_manifest_lists_the_qr_cell_where_it_was_appended",
+    # by test_manifest_still_lists_the_phase_metrics_on_the_two_16k_cells
+    "test_manifest_lists_the_phase_metrics_on_the_dynamic_cells_only"}
 
 for _dir, _name in ((_BENCH, "test_yardstick"), (_BENCH, "test_phase_metrics"),
                     (_BENCH, "test_potrf64k"), (_BENCH, "test_dtd_gemm"),
-                    (_BENCH, "test_geqrf32k"),
+                    (_BENCH, "test_geqrf32k"), (_BENCH, "test_geqrf52k_ctx4"),
                     (_HERE, "yardstick_writeback_early_share"),
                     (_HERE, "yardstick_flood_metrics"),
                     (_HERE, "yardstick_stage_in_ms"),
                     (_HERE, "yardstick_dispatch_metrics"),
-                    (_HERE, "yardstick_donated_share")):
+                    (_HERE, "yardstick_donated_share"),
+                    (_HERE, "yardstick_qr_cell")):
     _spec = importlib.util.spec_from_file_location(
         f"benchmarks_tests_{_name}", os.path.join(_dir, _name + ".py"))
     _mod = importlib.util.module_from_spec(_spec)

@@ -452,7 +452,11 @@ def test_half_the_triangle_evicts_and_restages_and_loses_nothing(dev):
     assert dev.evicted_bytes == dev.deferred_evictions * tile
     # what left early came back: staged bytes beyond the triangle
     assert dev.bytes_in > triangle * tile
-    assert dev.bytes_in - triangle * tile <= dev.evicted_bytes
+    # (a clean victim is dropped, not written back, and counted apart)
+    assert dev.replicas_dropped > 0
+    assert dev.replica_bytes_dropped == dev.replicas_dropped * tile
+    assert dev.bytes_in - triangle * tile <= \
+        dev.evicted_bytes + dev.replica_bytes_dropped
     assert dev.pressure_confirms >= 1
 
 

@@ -92,8 +92,8 @@ def _solve(dev, make, p):
     tp, _, result = make(p)
     moved = []
     transfer = dev._transfer
-    dev._transfer = lambda values: moved.append(len(values)) or \
-        transfer(values)
+    dev._transfer = lambda values, *far: moved.append(len(values)) or \
+        transfer(values, *far)
     before = {k: getattr(dev, k) for k in COUNTED}
     try:
         ctx = Context(nb_cores=0)

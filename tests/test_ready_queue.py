@@ -557,3 +557,8 @@ def test_two_accelerators_hand_back_what_best_device_gives_the_other(
     assert n["popped"] == n["pushed"]       # a put-back is a push and a pop
     assert [d.executed_tasks for d in devs] == [479, 337]
     assert [d.flood_putbacks for d in devs] == [272, 232]
+    # a tile another chip wrote crosses once to each chip that reads it, and
+    # is counted apart from the host's tiles (PR 40)
+    assert [d.d2d_tiles for d in devs] == [57, 78]
+    assert [d.bytes_d2d for d in devs] == [57 * 256, 78 * 256]
+    assert sum(d.bytes_in for d in devs) == 136 * 256

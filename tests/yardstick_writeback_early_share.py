@@ -41,7 +41,7 @@ def test_manifest_lists_the_early_share_on_the_dynamic_cells():
     assert (m["layer"], m["moves"]) == ("device module", "dynamic.gflops")
     assert m["workloads"][:2] == ["gemm16k.dynamic", "potrf16k.dynamic"]
     # the cells that write tiles back through the device module
-    assert all(w.endswith((".dynamic", ".dtd")) for w in m["workloads"])
+    assert all(w.endswith((".dynamic", ".dtd", ".ctx4")) for w in m["workloads"])
 
 
 @pytest.mark.parametrize("devices,expect", [
