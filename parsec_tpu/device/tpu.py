@@ -58,10 +58,14 @@ _params.register("device_tpu_memory_use", 90,
                  "could not donate); a result that took its predecessor's "
                  "buffer adds nothing")
 _params.register("device_tpu_max_inflight", 32,
-                 "bound, by count, on enqueued-but-unconfirmed dispatches; "
-                 "the ring is cut shorter than this whenever the bytes it "
-                 "holds (of results that did not take a donated buffer) "
-                 "would take the module past device_tpu_memory_use")
+                 "bound, by count, on enqueued-but-unconfirmed dispatches of "
+                 "one accelerator: past it an enqueue waits for the oldest, "
+                 "unless another accelerator of the context has run all it "
+                 "was given and nobody feeds it (the wait would starve it "
+                 "longer): then only what the chip has run leaves the ring; "
+                 "the ring is cut shorter than this, always, whenever the "
+                 "bytes it holds (of results that did not take a donated "
+                 "buffer) would take the module past device_tpu_memory_use")
 _params.register("device_tpu_batch", True,
                  "run same-class pending tasks as one fused dispatch")
 _params.register("device_tpu_batch_max", 64,
@@ -352,12 +356,20 @@ class TPUDevice(Device):
         # allocated until it has run and nothing else accounts for: the
         # versions it superseded without taking their buffers (the program
         # still reads them) and its padding lanes' results; nothing for a
-        # result that took a donated buffer.  Bounded by count
-        # (_max_inflight) and, with the LRU, by the one byte budget
-        # (_make_room)
+        # result that took a donated buffer.  Bounded, with the LRU, by
+        # the one byte budget (_make_room), always; and by count
+        # (_max_inflight), where the wait for the oldest entry costs no
+        # other chip anything: while an accelerator of _peers (the others
+        # of the context whose batch this one runs) starves, an enqueue
+        # past the count drops what the chip has run and waits for nothing
+        # (_note_inflight; how often: ring_excused against ring_bounded)
         self._inflight: deque[tuple] = deque()
         self._held_bytes = 0
         self._max_inflight = _params.get("device_tpu_max_inflight")
+        self._peers: list[TPUDevice] = []
+        self.ring_excused = 0
+        self.ring_bounded = 0
+        self.ring_peak = 0
         # what the pad lanes of a donating call write to: scratch tiles by
         # (shape, dtype), handed to the call and taken back as its pad
         # results, so that a pad lane allocates nothing either.  At most the
@@ -884,6 +896,9 @@ class TPUDevice(Device):
                         self._managing = False
                         return HOOK_RETURN_ASYNC
                     batch = self._take_batch_locked()
+                self._peers = [
+                    d for d in batch[0].es.context.accelerators()
+                    if d is not self and d.type == self.type]
                 spans.phase_refresh()
                 try:
                     if _params.get("device_tpu_batch"):
@@ -1389,18 +1404,56 @@ class TPUDevice(Device):
                 hi = probe
         return len(ring) - lo, sum(ring[i][1] for i in range(lo))
 
+    def _starving(self) -> bool:
+        """Asked by a peer's manager, which takes no lock and waits for
+        nothing here: this chip has run all it was given and nobody is
+        about to give it more (no manager, nothing pending, the newest ring
+        entry with a live array ``is_ready()``: one probe).  Whoever takes
+        the chip up meanwhile changes the ring under the walk, or donates
+        the array asked: both raise, and the chip is then not starving."""
+        if self._managing or self._pending or not self.enabled:
+            return False
+        try:
+            for out, _ in reversed(self._inflight):
+                leaf = _first_live(out)
+                if leaf is not None and leaf is not _DONATED:
+                    return leaf.is_ready()
+        except RuntimeError:
+            return False
+        return True
+
     def _note_inflight(self, out: Any, held: int = 0) -> None:
         """Bound the enqueue depth: block on the oldest dispatch once more
-        than ``max_inflight`` are unconfirmed (event-ring analog).  ``held``:
-        the bytes that stay allocated until this dispatch has run."""
+        than ``max_inflight`` are unconfirmed (event-ring analog), unless a
+        peer starves: the thread that would wait here is the one that could
+        feed it.  Then the entries the chip has run are confirmed (no wait
+        in it), the others stay past the count, and the next enqueue asks
+        again; ``_make_room`` bounds the ring by bytes either way.  With no
+        peer the count holds at every enqueue.  ``held``: the bytes that
+        stay allocated until this dispatch has run."""
         if out is None:
             return
-        self._inflight.append((out, held))
+        ring = self._inflight
+        ring.append((out, held))
         self._held_bytes += held
         if self._held_bytes > self.inflight_held_bytes_peak:
             self.inflight_held_bytes_peak = self._held_bytes
-        while len(self._inflight) > self._max_inflight:
-            self._confirm_oldest()
+        if len(ring) > self._max_inflight:
+            waited = False
+            while len(ring) > self._max_inflight:
+                if any(peer._starving() for peer in self._peers):
+                    owed = self._queue_depth()[0]
+                    while len(ring) > max(owed, self._max_inflight):
+                        self._confirm_oldest()
+                    break
+                self._confirm_oldest()
+                waited = True
+            if waited:
+                self.ring_bounded += 1
+            else:
+                self.ring_excused += 1
+        if len(ring) > self.ring_peak:
+            self.ring_peak = len(ring)
 
     def _confirm_oldest(self) -> None:
         out, held = self._inflight.popleft()
@@ -1456,6 +1509,9 @@ class TPUDevice(Device):
                  "inflight_dispatches": len(self._inflight),
                  "inflight_held_bytes": self._held_bytes,
                  "inflight_held_bytes_peak": self.inflight_held_bytes_peak,
+                 "ring_peak": self.ring_peak,
+                 "ring_excused": self.ring_excused,
+                 "ring_bounded": self.ring_bounded,
                  "donated_results": self.donated_results,
                  "scratch_tiles": sum(map(len, self._scratch.values())),
                  "scratch_bytes": self._scratch_bytes,

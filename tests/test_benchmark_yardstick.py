@@ -4,7 +4,8 @@ traced rehearsal of every cell sound and broken, the phase metrics, the 64k
 cell's own, the DTD cell's own, the QR cell's own, the four-chip cell's own)
 and ``yardstick_writeback_early_share.py``, ``yardstick_flood_metrics.py``,
 ``yardstick_stage_in_ms.py``, ``yardstick_dispatch_metrics.py``,
-``yardstick_donated_share.py`` and ``yardstick_qr_cell.py`` beside this file are collected here under
+``yardstick_donated_share.py``, ``yardstick_qr_cell.py`` and
+``yardstick_ring_excused_share.py`` beside this file are collected here under
 their own names, so each counts, and a name that two files give is an error
 here and not one test fewer.  They need no chip.  The rehearsals run in
 processes of their own, and all from this one file, so that under ``--dist
@@ -47,7 +48,8 @@ for _dir, _name in ((_BENCH, "test_yardstick"), (_BENCH, "test_phase_metrics"),
                     (_HERE, "yardstick_stage_in_ms"),
                     (_HERE, "yardstick_dispatch_metrics"),
                     (_HERE, "yardstick_donated_share"),
-                    (_HERE, "yardstick_qr_cell")):
+                    (_HERE, "yardstick_qr_cell"),
+                    (_HERE, "yardstick_ring_excused_share")):
     _spec = importlib.util.spec_from_file_location(
         f"benchmarks_tests_{_name}", os.path.join(_dir, _name + ".py"))
     _mod = importlib.util.module_from_spec(_spec)

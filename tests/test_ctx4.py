@@ -7,6 +7,7 @@ chips' copies invalid, no flush lowers a host version, a clean copy leaves
 the LRU without a device-to-host copy.  CPU stand-in with four of the suite's
 virtual devices; results and counts are asserted, never a duration."""
 
+import contextlib
 import os
 import sys
 from types import SimpleNamespace
@@ -20,8 +21,10 @@ from parsec_tpu.data.data import (ACCESS_RW, COHERENCY_INVALID,
                                   data_create)
 from parsec_tpu.data_dist.matrix import SymTwoDimBlockCyclic, TwoDimBlockCyclic
 from parsec_tpu.device.tpu import TPUDevice, TPUDeviceTask
+from parsec_tpu.prof import spans
 from parsec_tpu.runtime import Context
 from test_fused_forms import NB, _dispatch, _tasks
+from test_phase_spans import _Result
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks")
@@ -184,6 +187,9 @@ def test_one_accelerator_counts_a_16_tile_cholesky_as_before(
             dev.replicas_dropped, dev.replica_bytes_dropped,
             dev.evicted_bytes) == (0, 0, 0, 0, 0, 0)
     assert dev.stats()["bytes_d2d"] == 0 == dev.debug_state()["bytes_d2d"]
+    # no peer: the ring's count holds at every enqueue
+    assert dev._peers == [] and dev.ring_excused == 0 < dev.ring_bounded
+    assert dev.ring_peak == dev._max_inflight
 
 
 def _writer(copy):
@@ -335,6 +341,208 @@ def test_a_batch_donates_tiles_another_chip_has_just_read(four):
         d.flush_cache()
 
 
+# the ring's count bound with several accelerators (ISSUE 41)
+# --------------------------------------------------------------------------
+
+class _Dispatch(_Result):
+    """What a dispatch left in a stand-in ring: ``_Result`` (what the probe
+    asks) that also counts who waited for it, reads as run from then on, and
+    can fail there, as ``jax.block_until_ready`` meets the array of a program
+    that failed on the chip."""
+
+    def __init__(self, ready=False, fails=False):
+        super().__init__(ready, [])
+        self.waited, self.fails = 0, fails
+
+    def block_until_ready(self):
+        self.waited += 1
+        if self.fails:
+            raise RuntimeError("the program failed on the chip")
+        self.ready = True
+        return self
+
+
+def _enqueue(dev, *results, held=0):
+    for r in results:
+        dev._note_inflight((r,), held)
+
+
+@pytest.fixture
+def entered(monkeypatch):
+    """The phase spans the module enters, by name and in order."""
+    names = []
+
+    @contextlib.contextmanager
+    def phase(name, **args):
+        names.append(name)
+        yield
+
+    monkeypatch.setattr(spans, "phase", phase)
+    return names
+
+
+@pytest.fixture
+def pair(four):
+    """Two accelerators of one context, A's count bound cut to 4."""
+    a, b = four[:2]
+    a._peers, a._max_inflight = [b], 4
+    return a, b
+
+
+def _ring(dev, kinds):
+    """R: an entry the chip has run, N: one it has not, X: a body that
+    handed back no array, D: one whose array a later call was donated."""
+    made = {"R": lambda: (_Dispatch(True),), "N": lambda: (_Dispatch(),),
+            "X": lambda: (np.float32(1.0),), "D": lambda: (_Result(None, []),)}
+    dev._inflight.extend((made[k](), 0) for k in kinds)
+
+
+# the peer as the asking chip finds it, and whether that excuses the wait
+PEERS = [
+    ("ring_empty", lambda b: None, True),
+    ("newest_entry_run", lambda b: _ring(b, "RR"), True),
+    ("newest_entry_owed", lambda b: _ring(b, "RN"), False),
+    ("newest_live_entry_run", lambda b: _ring(b, "RDX"), True),
+    ("newest_live_entry_owed", lambda b: _ring(b, "NXD"), False),
+    ("no_array_in_the_ring", lambda b: _ring(b, "XX"), True),
+    ("being_managed", lambda b: setattr(b, "_managing", True), False),
+    ("work_pending", lambda b: b._pending.append(object()), False),
+    ("demoted", lambda b: setattr(b, "enabled", False), False)]
+
+
+@pytest.mark.parametrize("setup,excused", [p[1:] for p in PEERS],
+                         ids=[p[0] for p in PEERS])
+def test_a_ring_past_its_count_waits_only_where_no_peer_starves(
+        pair, entered, setup, excused):
+    """Six dispatches the chip has not run on a ring bounded at 4: where the
+    other accelerator has run all it was given and nobody feeds it, none is
+    waited for and all six stay; else the two oldest are, as with one."""
+    a, b = pair
+    setup(b)
+    owed = [_Dispatch() for _ in range(6)]
+    _enqueue(a, *owed)
+    if excused:
+        assert "devmod.inflight_wait" not in entered
+        assert [r.waited for r in owed] == [0] * 6
+        assert len(a._inflight) == 6 == a.ring_peak
+        assert (a.ring_excused, a.ring_bounded) == (2, 0)
+    else:
+        assert entered == ["devmod.inflight_wait"] * 2
+        assert [r.waited for r in owed] == [1, 1, 0, 0, 0, 0]
+        assert len(a._inflight) == 4 == a.ring_peak
+        assert (a.ring_excused, a.ring_bounded) == (0, 2)
+    state = a.debug_state()
+    assert (state["ring_peak"], state["ring_excused"], state["ring_bounded"]) \
+        == (a.ring_peak, a.ring_excused, a.ring_bounded)
+    # the peer was asked and nothing else: no lock taken, nothing waited for
+    assert not b._mutex_lock.locked()
+    assert all(not r.waited for out, _ in b._inflight
+               for r in out if isinstance(r, _Dispatch))
+
+
+def test_the_count_holds_again_as_soon_as_the_peer_is_fed(pair, entered):
+    """Excused, the ring keeps what the chip owes and lets go of what it has
+    run (through ``_confirm``: a failed dispatch among them is raised); once
+    the peer has work the next enqueue confirms down to the count."""
+    a, b = pair
+    _ring(b, "R")
+    owed = [_Dispatch() for _ in range(6)]
+    _enqueue(a, *owed)
+    assert not entered and len(a._inflight) == 6
+    owed[0].ready = True                 # the chip ran the oldest meanwhile
+    owed.append(_Dispatch())
+    _enqueue(a, owed[-1])
+    assert entered == ["devmod.inflight_wait"] and owed[0].waited == 1
+    assert [out[0] for out, _ in a._inflight] == owed[1:]
+    assert (a.ring_excused, a.ring_bounded, a.ring_peak) == (3, 0, 6)
+    _ring(b, "N")                        # the peer is given a dispatch
+    owed.append(_Dispatch())
+    _enqueue(a, owed[-1])
+    assert entered == ["devmod.inflight_wait"] * 4
+    assert [r.waited for r in owed] == [1, 1, 1, 1, 0, 0, 0, 0]
+    assert [out[0] for out, _ in a._inflight] == owed[4:]
+    assert (a.ring_excused, a.ring_bounded) == (3, 1)
+    b._inflight[-1][0][0].ready = True   # which it runs: excused again
+    _enqueue(a, _Dispatch(), _Dispatch())
+    assert len(entered) == 4 and len(a._inflight) == 6
+    assert (a.ring_excused, a.ring_bounded, a.ring_peak) == (5, 1, 6)
+    a.sync()
+    assert not a._inflight and len(entered) == 4 + 1 + 6     # devmod.sync
+
+
+def test_a_peer_taken_up_while_it_is_asked_does_not_starve(pair):
+    """The asking thread holds no lock: a manager that takes the peer up
+    changes the ring under the walk, or donates the array asked.  Both raise
+    there, and the peer is then being fed."""
+    a, b = pair
+
+    class Donated(_Dispatch):
+        def is_ready(self):
+            raise RuntimeError("Array has been deleted.")
+
+    b._inflight.append(((Donated(),), 0))
+    assert not b._starving()
+
+    class Grows(_Dispatch):
+        def is_deleted(self):
+            b._inflight.append(((_Dispatch(),), 0))
+            return True
+
+    b._inflight.clear()
+    b._inflight.extend([((_Dispatch(True),), 0), ((Grows(),), 0)])
+    assert not b._starving()
+    b._inflight.clear()
+    assert b._starving()
+
+
+def test_a_solve_over_four_accelerators_counts_every_enqueue_past_the_count(
+        four, monkeypatch):
+    """The tile QR over four accelerators whose rings are bounded at 2: each
+    manager finds the other three of the context, every enqueue that found
+    the ring past its count is counted once, as excused or as bounded, the
+    rings drain in ``sync``, and the factors are right."""
+    past = {d: 0 for d in four}
+    note = TPUDevice._note_inflight
+
+    def counting(self, out, held=0):
+        before = len(self._inflight)
+        note(self, out, held)
+        past[self] += out is not None and before + 1 > self._max_inflight
+
+    monkeypatch.setattr(TPUDevice, "_note_inflight", counting)
+    for d in four:
+        d._max_inflight = 2
+    _, gap, ntasks, _, _ = _solve(four, _qr, monkeypatch)
+    assert gap < LIMIT["qr"], gap
+    assert sum(d.executed_tasks for d in four) == ntasks
+    for d in four:
+        assert d._peers == [p for p in four if p is not d]
+        assert d.ring_excused + d.ring_bounded == past[d] > 0
+        assert 2 <= d.ring_peak <= d.xla_calls
+        assert not d._inflight and d._held_bytes == 0 and d.enabled
+
+
+def test_a_context_bound_to_one_of_several_accelerators_keeps_the_count(
+        four, param, monkeypatch):
+    """A context that may use one accelerator (a rank bound to its chip:
+    ``Context(accelerators=...)``) has no peer to ask, however many
+    accelerators the process registered and however idle they are."""
+    param("device_tpu_allow_cpu", True)      # the mask is made of these
+    dev = four[0]
+    dev._max_inflight = 2
+    pool, ntasks, _, result, gap = _qr()
+    ctx = Context(nb_cores=0, accelerators=[dev.jax_device])
+    ctx.add_taskpool(pool)
+    ctx.wait(timeout=300)
+    dev.sync()
+    dev.flush_cache()
+    ctx.fini()
+    assert gap(result()[1]) < LIMIT["qr"]
+    assert dev.executed_tasks == ntasks
+    assert dev._peers == [] and dev.ring_excused == 0 < dev.ring_bounded
+    assert dev.ring_peak == 2
+
+
 @pytest.mark.parametrize("name,make", [("qr", _qr), ("cholesky", _cholesky)])
 @pytest.mark.parametrize("workers,chips", [(2, 2), (4, 4)])
 def test_worker_threads_over_several_accelerators_finish_the_solve(
@@ -362,6 +570,38 @@ def test_worker_threads_over_several_accelerators_finish_the_solve(
     assert sum(d.executed_tasks for d in devs) == ntasks
     assert not any(d._managing or d._pending for d in devs)
     assert all(d.enabled for d in devs)
+
+
+@pytest.mark.parametrize("name,make", [("qr", _qr), ("cholesky", _cholesky)])
+def test_worker_threads_ask_each_other_s_rings_and_finish_the_solve(
+        four, name, make):
+    """Four workers over four accelerators whose rings are bounded at 2, the
+    interpreter switching threads every 10 us: every manager asks rings that
+    another thread is changing (``_starving`` takes no lock).  The solve ends
+    inside its bound, right; every enqueue past the count was counted once,
+    as excused or bounded, and every dispatch left its ring."""
+    for d in four:
+        d._max_inflight = 2
+    pool, ntasks, _, result, gap = make()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    ctx = Context(nb_cores=4)
+    try:
+        ctx.add_taskpool(pool)
+        ctx.wait(timeout=120)      # raises where the solve does not end
+        for d in four:
+            d.sync()
+        for d in four:
+            d.flush_cache()
+    finally:
+        sys.setswitchinterval(interval)
+        ctx.fini()
+    assert gap(result()[1]) < LIMIT[name]
+    assert sum(d.executed_tasks for d in four) == ntasks
+    assert not any(d._managing or d._pending or d._inflight for d in four)
+    assert all(d.enabled and d._held_bytes == 0 for d in four)
+    assert sum(d.ring_excused + d.ring_bounded for d in four) > 0
+    assert all(2 <= d.ring_peak <= d.xla_calls for d in four)
 
 
 def test_a_program_one_chip_builds_is_compiled_for_its_peers_at_once(

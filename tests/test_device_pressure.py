@@ -25,6 +25,7 @@ import jax
 from parsec_tpu.device import tpu
 from parsec_tpu.models.tiled_gemm import tiled_gemm_ptg
 from parsec_tpu.runtime import Context
+from test_ctx4 import _Dispatch
 
 
 @pytest.fixture
@@ -499,3 +500,80 @@ def test_the_ring_is_bounded_by_count_when_the_budget_is_far(dev):
     np.testing.assert_allclose(C.to_dense(), c + a @ b, rtol=1e-3, atol=1e-4)
     assert dev.pressure_confirms == 0 and dev.evict_stuck == 0
     assert dev.evicted_bytes == 0 and dev._held_bytes == 0
+
+
+# the count bound excused by a starving peer (ISSUE 41): the byte budget and
+# the confirmation of every dispatch hold as with one accelerator
+# --------------------------------------------------------------------------
+
+@pytest.fixture
+def excused(dev, device_registry):
+    """``dev`` with its ring bounded at 2 and a peer that starves (nothing
+    given, nobody managing): no enqueue past the count waits."""
+    peer = device_registry.add(tpu.TPUDevice(jax.devices()[1]))
+    dev._peers, dev._max_inflight = [peer], 2
+    return dev
+
+
+def test_a_short_budget_confirms_the_oldest_although_a_peer_starves(excused):
+    """Five dispatches that each keep 3 tiles alive under a budget of 10:
+    the count (2) is excused, the bytes are not: ``_make_room`` confirms the
+    oldest, before the enqueue that would pass the budget, every time."""
+    dev, tile = excused, 1 << 10
+    dev._mem_budget = 10 * tile
+    owed = [_Dispatch() for _ in range(5)]
+    for r in owed:
+        dev._make_room(3 * tile)         # as ``_run_batch`` asks, before
+        dev._note_inflight((r,), 3 * tile)
+        assert dev._held_bytes <= dev._mem_budget
+    assert [r.waited for r in owed] == [1, 1, 0, 0, 0]
+    assert dev.pressure_confirms == 2 and dev.evict_stuck == 0
+    assert len(dev._inflight) == 3 == dev.ring_peak
+    assert (dev.ring_excused, dev.ring_bounded) == (3, 0)
+    assert dev._held_bytes == 9 * tile == dev.inflight_held_bytes_peak
+    dev.sync()
+    assert dev._held_bytes == 0 and [r.waited for r in owed] == [1] * 5
+
+
+# where the failed dispatch is met: (the peer starves, what meets it)
+MET = [("excused_then_sync", True, "sync"),
+       ("excused_then_pressure", True, "pressure"),
+       ("excused_then_run_and_dropped", True, "enqueue"),
+       ("bounded_at_the_next_enqueue", False, "enqueue")]
+
+
+@pytest.mark.parametrize("starves,met", [m[1:] for m in MET],
+                         ids=[m[0] for m in MET])
+def test_a_dispatch_that_failed_is_raised_and_demotes_on_every_path(
+        excused, starves, met):
+    """Whatever takes an entry out of the ring goes through ``_confirm``:
+    a program that failed on the chip is raised there and the device is
+    disabled, inside an excused stretch as outside one."""
+    dev, tile = excused, 1 << 10
+    (peer,) = dev._peers
+    failed = _Dispatch(fails=True)
+    rest = [_Dispatch() for _ in range(3)]
+    if not starves:
+        peer._pending.append(object())
+        dev._note_inflight((failed,), tile)
+        dev._note_inflight((rest[0],), tile)
+        with pytest.raises(RuntimeError, match="failed on the chip"):
+            dev._note_inflight((rest[1],), tile)
+        assert (dev.ring_excused, dev.ring_bounded) == (0, 0)
+    else:
+        dev._note_inflight((failed,), tile)
+        for r in rest:
+            dev._note_inflight((r,), tile)    # past the count: not waited
+        assert dev.enabled and failed.waited == 0 and dev.ring_excused == 2
+        with pytest.raises(RuntimeError, match="failed on the chip"):
+            if met == "sync":
+                dev.sync()
+            elif met == "pressure":
+                dev._mem_budget = 4 * tile
+                dev._make_room(tile)
+            else:       # the chip is past it: the next enqueue drops it
+                failed.ready = True
+                dev._note_inflight((_Dispatch(),), tile)
+    assert failed.waited == 1 and not dev.enabled
+    assert all(r.waited == 0 for r in rest)
+    peer._pending.clear()

@@ -156,12 +156,14 @@ def test_on_every_second_of_a_solve_has_a_documented_owner(
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
-    fused, probes = [], []       # probes: (depth, held run, held) a call
+    # probes: (depth, held run, held, the ring's length) a call
+    fused, probes = [], []
     probe = TPUDevice._queue_depth
 
     def recorded(self):
         depth, held_run = probe(self)
-        probes.append((depth, held_run, self._held_bytes))
+        probes.append((depth, held_run, self._held_bytes,
+                       len(self._inflight)))
         return depth, held_run
     monkeypatch.setattr(TPUDevice, "_queue_depth", recorded)
     try:
@@ -245,12 +247,13 @@ def test_on_every_second_of_a_solve_has_a_documented_owner(
         # the pad lanes: what the calls ran beyond the tasks they were for
         assert 0 <= n * lanes - b < n * max(lanes // 2, 1)
     assert sum(r["call_ns"] for r in rows.values()) == table["devmod.call"][1]
-    # the chip's queue at every enqueue: at most the ring, and what has run
-    # is part of what the ring holds
+    # the chip's queue at every enqueue: at most what the ring held then
+    # (with one accelerator never past the count), and what has run is part
+    # of what the ring holds
     assert len(probes) == calls
-    ring = dev._max_inflight
-    assert all(0 <= depth <= ring and 0 <= run <= held
-               for depth, run, held in probes), probes
+    assert all(0 <= depth <= ring <= dev._max_inflight and 0 <= run <= held
+               for depth, run, held, ring in probes), probes
+    assert dev.ring_peak <= dev._max_inflight and dev.ring_excused == 0
     for i, field in ((0, "depth_sum"), (1, "held_run_bytes_sum"),
                      (2, "held_bytes_sum")):
         assert sum(r[field] for r in rows.values()) \
