@@ -42,6 +42,9 @@ def _model_graphs(nt: int, ranks: int = 1):
         SymTwoDimBlockCyclic("A", n, n, nb, nb), devices="cpu")
     yield "lu", lu.tiled_lu_ptg(
         TiledMatrix.from_dense("A", lu.make_dd(n), nb, nb), devices="cpu")
+    yield "getrf", lu.tiled_getrf_ptg(
+        TwoDimBlockCyclic("A", n, n, nb, nb), lu.ipiv_matrix(n, nb),
+        devices="cpu")
     yield "qr", qr.tiled_qr_ptg(
         TwoDimBlockCyclic("A", n, n, nb, nb),
         TwoDimBlockCyclic("T", n, n, nb, nb), devices="cpu")

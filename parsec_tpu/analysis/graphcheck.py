@@ -432,7 +432,7 @@ def check_ptg(tp: Any, nb_ranks: int | None = None,
                     targets = probe(d.each_target, "input params", tc.name,
                                     flow.name, locals_, locals_, default=())
                     if pred_tc.name == tc.name and \
-                            d.target_flow == flow.name:
+                            d.flow_name(locals_) == flow.name:
                         chain_in.add(fkey)
                     for pl in targets:
                         _check_input_arrow(report, tp, tc, flow, d, locals_,
@@ -491,7 +491,7 @@ def check_ptg(tp: Any, nb_ranks: int | None = None,
                             instance=locals_)
                         continue
                     if succ_tc.name == tc.name and \
-                            d.target_flow == flow.name:
+                            d.flow_name(locals_) == flow.name:
                         chain_out.add(fkey)
                     targets = probe(d.each_target, "output params", tc.name,
                                     flow.name, locals_, locals_, default=())
@@ -680,11 +680,12 @@ def _check_input_arrow(report, tp, tc, flow, d, locals_, node, pred_tc, pl,
             task_class=tc.name, flow=flow.name, instance=locals_)
         return
     # the predecessor must actively send to exactly this instance/flow
-    pf = next((f for f in pred_tc.flows if f.name == d.target_flow), None)
+    pname = d.flow_name(locals_)
+    pf = next((f for f in pred_tc.flows if f.name == pname), None)
     if pf is None:
         report.add(
             "missing-output-edge", ERROR,
-            f"input names flow {d.target_flow!r} which "
+            f"input names flow {pname!r} which "
             f"{pred_tc.name} does not declare",
             task_class=tc.name, flow=flow.name, instance=locals_)
         return
@@ -697,10 +698,10 @@ def _check_input_arrow(report, tp, tc, flow, d, locals_, node, pred_tc, pl,
             task_class=tc.name, flow=flow.name, instance=locals_)
     my_key = node[1]
     for od in pf.deps_out:
-        if od.target_class != tc.name or od.target_flow != flow.name:
+        if od.target_class != tc.name:
             continue
         try:
-            if not od.active(pl):
+            if not od.active(pl) or od.flow_name(pl) != flow.name:
                 continue
             tgts = od.each_target(pl)
         except Exception:
@@ -713,7 +714,7 @@ def _check_input_arrow(report, tp, tc, flow, d, locals_, node, pred_tc, pl,
                 continue
     report.add(
         "missing-output-edge", ERROR,
-        f"input expects {pred_tc.name}.{d.target_flow} of "
+        f"input expects {pred_tc.name}.{pname} of "
         f"{_node_str((pred_tc.name, pkey))} but that instance has no "
         f"active output arrow back to this flow — the consumer waits "
         f"forever", task_class=tc.name, flow=flow.name, instance=locals_)
@@ -749,12 +750,14 @@ def _check_output_arrow(report, tp, tc, flow, d, locals_, node, succ_tc, sl,
             task_class=tc.name, flow=flow.name, instance=locals_)
         return
     try:
-        fi, _di = _find_input_dep(succ_tc, d.target_flow, tc.name, sl)
+        fi, _di = _find_input_dep(succ_tc, d.flow_name(locals_), tc.name,
+                                  sl)
     except (KeyError, LookupError):
         report.add(
             "missing-input-edge", ERROR,
             f"output arrow lands on "
-            f"{_node_str((succ_tc.name, skey))}.{d.target_flow} which has "
+            f"{_node_str((succ_tc.name, skey))}.{d.flow_name(locals_)} "
+            f"which has "
             f"no matching active input dep from {tc.name} — the datum "
             f"arrives with no dep bit to satisfy (the pool hangs)",
             task_class=tc.name, flow=flow.name, instance=locals_)

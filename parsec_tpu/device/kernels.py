@@ -62,15 +62,17 @@ def traceable_body(apply: Callable, jitted: Callable | None = None) -> Callable:
     """A per-task device body from a jax-traceable: ``apply`` takes the
     task's non-CTL flow values in flow order and returns the new value of its
     written flows, one value or a tuple in flow order (the contract of
-    ``ptg.lowering.Traceable.apply`` and of the fused batch program).  Every
-    written flow gets its value and a new version, as ``_run_vmapped`` does
-    for a batch.  ``jitted``: where ``apply`` is one ``jax.jit`` function on
+    ``ptg.lowering.Traceable.apply`` and of the fused batch program); a null
+    flow is left out of both.  Every written flow gets its value and a new
+    version, as ``_run_vmapped`` does for a batch.  ``jitted``: where ``apply`` is one ``jax.jit`` function on
     those values, a callable that hands it out; the body carries it as
     ``body.jitted``, and the device module compiles it for every accelerator
     at once (``TPUDevice._meet_task_program``)."""
     def body(es: Any, task: Any, device: Any) -> Any:
         from ..data.data import ACCESS_WRITE
-        flows = [f for f in task.task_class.flows if not f.is_ctl]
+        # a flow the instance leaves null is not the kernel's
+        flows = [f for f in task.task_class.flows
+                 if not f.is_ctl and task.data[f.flow_index] is not None]
         out = apply(*(task.data[f.flow_index].value for f in flows))
         outs = out if isinstance(out, (tuple, list)) else (out,)
         written = [f for f in flows if f.access & ACCESS_WRITE]

@@ -77,7 +77,8 @@ _params.register("device_tpu_allow_cpu", False,
 
 
 def _fused_program(apply: Callable, dyld: str, lanes: int,
-                   donates: tuple[int, ...] = ()) -> Callable:
+                   donates: tuple[int, ...] = (),
+                   compiler_options: dict | None = None) -> Callable:
     """The one jitted program of a same-class batch: ``apply`` once a lane on
     the lane's own tiles (the flat arguments are flow-major: lane i's are
     ``flat[i::lanes]``), the results per written flow, a tuple of the lanes'.
@@ -86,7 +87,9 @@ def _fused_program(apply: Callable, dyld: str, lanes: int,
     among the lane's arguments) every lane of which is donated, so that the
     lane's result takes its buffer and the call allocates no output for it
     (:func:`_donatable` says which can be; the compiled module pairs lane
-    i's input with lane i's result); kept on the program as ``.donates``."""
+    i's input with lane i's result); kept on the program as ``.donates``.
+    ``compiler_options``: XLA's, for this program alone (a traceable's
+    ``tpu_compiler_options`` on the TPU)."""
     import jax
 
     def fused(*flat):
@@ -98,7 +101,8 @@ def _fused_program(apply: Callable, dyld: str, lanes: int,
 
     fused.__name__ = f"fused_{dyld}"
     fn = jax.jit(fused, donate_argnums=tuple(
-        f * lanes + i for f in donates for i in range(lanes)))
+        f * lanes + i for f in donates for i in range(lanes)),
+        compiler_options=compiler_options)
     fn.donates = tuple(donates)
     return fn
 
@@ -376,6 +380,9 @@ class TPUDevice(Device):
         # pad lanes of one call a written flow (under half the widest
         # batch); their bytes are charged to the budget
         self._scratch: dict[tuple, list] = {}
+        # tiles of zeros a class with ``pad_rows`` appends to its family
+        # of row flows (``_run_fused``); its kernel hands them back zero
+        self._zeros: dict[tuple, list] = {}
         self._scratch_bytes = 0
         # results that took the buffer of the version they superseded
         self.donated_results = 0
@@ -448,6 +455,9 @@ class TPUDevice(Device):
         # the hit-rate gauge the metrics snapshotter samples
         self.cache_hits = 0
         self.cache_misses = 0
+        # data flows an instance left null (a flow family's rows above the
+        # panel): nothing staged, nothing handed to the kernel
+        self.null_flows_skipped = 0
         # gauges hold the device only WEAKLY: devices are never fini'd,
         # and a strong closure would keep a discarded device (test
         # fixtures, demoted devices) plus its LRU tile cache alive in
@@ -700,6 +710,7 @@ class TPUDevice(Device):
                     continue
                 copy = task.data[f.flow_index]
                 if copy is None:
+                    self.null_flows_skipped += 1
                     continue
                 d = copy.original
                 dev_copy = d.get_copy(self.device_index)
@@ -950,6 +961,7 @@ class TPUDevice(Device):
             self._mem_bytes = 0
             self._evict_bytes = 0
         self._scratch.clear()
+        self._zeros.clear()
         self._scratch_bytes = 0
         # tiles the victims will recompute from scratch (WRITE-only flows)
         # may be dropped freely; an RW flow's prior value is an INPUT, so
@@ -1054,7 +1066,8 @@ class TPUDevice(Device):
             self.stage_in_many([d.task for d in batch
                                 if d.stage_in is None])
         with _Wall(self, "t_dispatch", "devmod.dispatch"):
-            if len(batch) > 1 and self._run_vmapped(batch):
+            if (len(batch) > 1 or batch[0].task.task_class.pad_rows) \
+                    and self._run_vmapped(batch):
                 pass              # one XLA call serviced the whole batch
             else:
                 for dtask in batch:   # exec phase (exec streams analog)
@@ -1099,7 +1112,8 @@ class TPUDevice(Device):
             return []
         task = dtask.task
         values = [task.data[f.flow_index].value
-                  for f in task.task_class.flows if not f.is_ctl]
+                  for f in task.task_class.flows
+                  if not f.is_ctl and task.data[f.flow_index] is not None]
         return self._compile_for_peers(
             "_task_programs", body, fn, [(v.shape, v.dtype) for v in values])
 
@@ -1166,6 +1180,14 @@ class TPUDevice(Device):
         call in which anyone else holds one runs the same program without
         donation and allocates its results, as every call did before.
 
+        A flow an instance leaves ``null`` is not handed to the kernel: the
+        batch's instances are grouped by the flows they hold, a call a
+        group.  A class with ``pad_rows`` (a family of row flows, some null)
+        gets tiles of zeros after its present rows, up to the bucket, so
+        that every height of a bucket shares one program; its instances go
+        one a call, with no pad lane (a pad lane of such a class would take
+        a scratch tile for every row).
+
         Eligibility: the class's device chore has a jax-traceable
         incarnation registered under its ``dyld`` name
         (:func:`parsec_tpu.ptg.lowering.register_traceable` — the same
@@ -1187,43 +1209,80 @@ class TPUDevice(Device):
         if tr is None:
             return False
         data_flows = [f for f in tc.flows if not f.is_ctl]
-        copies, cols = [], []
-        for f in data_flows:
-            cs = [t.task.data[f.flow_index] for t in batch]
-            vals = [c.value for c in cs]
-            # no name is bound to a tile here: the donation below counts
-            # who refers to it
-            shape, dtype = vals[0].shape, vals[0].dtype
-            if any(v.shape != shape or v.dtype != dtype for v in vals):
-                return False   # ragged tiles: per-task path
-            copies.append(cs)
-            cols.append(vals)
+        groups: dict[tuple, list] = {}
+        for d in batch:
+            data = d.task.data
+            groups.setdefault(tuple(
+                f for f in data_flows if data[f.flow_index] is not None),
+                []).append(d)
+        calls = []
+        for flows, group in groups.items():
+            # a wide class's instances, one call each: a lane of such a
+            # class is tens of tiles and bound by the chip, and one lane a
+            # program keeps its programs to one a bucket (PERF.md, PR 42)
+            parts = [group] if tc.pad_rows is None else [[d] for d in group]
+            for part in parts:
+                copies, cols = [], []
+                for f in flows:
+                    cs = [t.task.data[f.flow_index] for t in part]
+                    vals = [c.value for c in cs]
+                    # no name is bound to a tile here: the donation below
+                    # counts who refers to it
+                    shape, dtype = vals[0].shape, vals[0].dtype
+                    if any(v.shape != shape or v.dtype != dtype
+                           for v in vals):
+                        return False   # ragged tiles: per-task path
+                    copies.append(cs)
+                    cols.append(vals)
+                calls.append((part, flows, copies, cols))
+        for call in calls:
+            self._run_fused(tc, dyld, tr, *call)
+        return True
 
+    def _run_fused(self, tc: Any, dyld: str, tr: Any,
+                   batch: list[TPUDeviceTask], flows: tuple,
+                   copies: list, cols: list) -> None:
+        """One fused call of :meth:`_run_vmapped`: ``batch``'s lanes hold
+        ``flows``, whose copies and values are ``copies`` and ``cols``."""
         B = len(batch)
         Bp = 1
         while Bp < B:
             Bp <<= 1
-        written = [i for i, f in enumerate(data_flows)
+        written = [i for i, f in enumerate(flows)
                    if f.access & ACCESS_WRITE]
+        # a flow family's zero tiles, after its present rows: written as
+        # the family is, and handed back to the pool of zeros
+        rows = 0 if tc.pad_rows is None else \
+            -(len(flows) - tc.pad_rows[0]) % tc.pad_rows[1]
+        for _ in range(rows):
+            if flows[-1].access & ACCESS_WRITE:
+                written.append(len(cols))
+            cols.append(self._take_scratch(cols[-1][0], B, zeros=True))
+            copies.append(None)
         sig = tuple((vs[0].shape, str(vs[0].dtype)) for vs in cols)
         key = (dyld, Bp, sig)
         fn = self._vmap_cache.get(key)
         warming: Any = ()
+        # options of the TPU's compiler a kernel needs (the panel LU's
+        # scoped VMEM: models/lu.py); no other backend knows them
+        opts = getattr(tr.apply, "tpu_compiler_options", None) \
+            if self.jax_device.platform == "tpu" else None
         if fn is None:
             fn = self._vmap_cache[key] = _fused_program(
                 tr.apply, dyld, Bp,
-                _donatable(tr.apply, [vs[0] for vs in cols], written))
+                _donatable(tr.apply, [vs[0] for vs in cols], written), opts)
             warming = self._compile_for_peers(
                 "_vmap_cache", key, fn, [a for a in sig for _ in range(Bp)])
         if fn.donates and not all(self._sole_holder(copies[w], cols[w])
-                                  for w in fn.donates):
+                                  for w in fn.donates
+                                  if copies[w] is not None):
             # someone else holds a tile this call would consume: the same
             # program without donation, compiled when first needed
             key += ("plain",)
             fn = self._vmap_cache.get(key)
             if fn is None:
-                fn = self._vmap_cache[key] = _fused_program(tr.apply, dyld,
-                                                            Bp)
+                fn = self._vmap_cache[key] = _fused_program(
+                    tr.apply, dyld, Bp, compiler_options=opts)
                 warming = self._compile_for_peers(
                     "_vmap_cache", key, fn,
                     [a for a in sig for _ in range(Bp)])
@@ -1268,7 +1327,10 @@ class TPUDevice(Device):
             # for the call in the ring
             self._note_inflight(outs[0][0] if outs else None, held)
             for w, parts in zip(written, outs):
-                fi = data_flows[w].flow_index
+                if copies[w] is None:       # zeros, as they came
+                    self._zeros[sig[w]].extend(parts[:B])
+                    continue
+                fi = flows[w].flow_index
                 for i, dtask in enumerate(batch):
                     c = dtask.task.data[fi]
                     c.value = parts[i]
@@ -1280,7 +1342,6 @@ class TPUDevice(Device):
                 self._mark_written(dtask.task)
             self.batched_dispatches += 1
             self._count_dispatch(tc, B)
-        return True
 
     def _compile_for_peers(self, cache: str, key: Any, fn: Callable,
                            args: list[tuple]) -> list:
@@ -1318,19 +1379,22 @@ class TPUDevice(Device):
             threads.append(thread)
         return threads
 
-    def _take_scratch(self, like: Any, n: int) -> list:
+    def _take_scratch(self, like: Any, n: int, zeros: bool = False) -> list:
         """``n`` scratch tiles of ``like``'s shape and dtype off the pool,
         made (of zeros, and asked of the budget) where the pool lacks them.
         Two written flows of one shape share a pool and take one after the
-        other; what a call took comes back as its pad lanes' results."""
-        pool = self._scratch.setdefault((like.shape, str(like.dtype)), [])
+        other; what a call took comes back as its pad lanes' results.
+        ``zeros``: the pool of ``pad_rows``' tiles of zeros, which their
+        kernels hand back zero, kept apart from the pad lanes' garbage."""
+        pools = self._zeros if zeros else self._scratch
+        pool = pools.setdefault((like.shape, str(like.dtype)), [])
         lacking = n - len(pool)
         if lacking > 0:
             import jax
-            zeros = np.zeros(like.shape, like.dtype)
-            self._make_room(lacking * zeros.nbytes)
-            pool.extend(jax.device_put([zeros] * lacking, self.jax_device))
-            self._scratch_bytes += lacking * zeros.nbytes
+            host = np.zeros(like.shape, like.dtype)
+            self._make_room(lacking * host.nbytes)
+            pool.extend(jax.device_put([host] * lacking, self.jax_device))
+            self._scratch_bytes += lacking * host.nbytes
         taken = pool[-n:]
         del pool[-n:]
         return taken
@@ -1514,6 +1578,8 @@ class TPUDevice(Device):
                  "ring_bounded": self.ring_bounded,
                  "donated_results": self.donated_results,
                  "scratch_tiles": sum(map(len, self._scratch.values())),
+                 "zero_tiles": sum(map(len, self._zeros.values())),
+                 "null_flows_skipped": self.null_flows_skipped,
                  "scratch_bytes": self._scratch_bytes,
                  "pressure_confirms": self.pressure_confirms,
                  "evicted_bytes": self.evicted_bytes,

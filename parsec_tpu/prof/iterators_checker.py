@@ -46,12 +46,12 @@ def check_task(task: Any) -> int:
         succ_tc = tp.task_class(dep.target_class)
         for succ_locals in dep.each_target(t.locals):
             try:
-                _find_input_dep(succ_tc, dep.target_flow, tc.name,
-                                succ_locals)
+                _find_input_dep(succ_tc, dep.flow_name(t.locals),
+                                tc.name, succ_locals)
             except (KeyError, LookupError) as e:
                 raise IteratorsCheckerError(
                     f"{t}: arrow to {dep.target_class}({succ_locals})."
-                    f"{dep.target_flow} has no matching active input dep "
+                    f"{dep.flow_name(t.locals)} has no matching active input dep "
                     f"({e})") from e
             count += 1
 

@@ -157,6 +157,7 @@ class TaskClassBuilder:
         self._simcost: Callable | None = None
         self._stage_in_hook: Callable | None = None
         self._stage_out_hook: Callable | None = None
+        self._pad_rows: tuple[int, int] | None = None
 
     # -- structure ----------------------------------------------------------
     def affinity(self, collection: Any, key_fn: Callable) -> "TaskClassBuilder":
@@ -182,6 +183,17 @@ class TaskClassBuilder:
 
     def time_estimate(self, fn: Callable) -> "TaskClassBuilder":
         self._time_estimate = fn
+        return self
+
+    def pad_rows(self, lead: int, bucket: int) -> "TaskClassBuilder":
+        """The class's data flows after its first ``lead`` form a family
+        (a tile row each) of which an instance leaves some ``null``: a
+        device batch hands its kernel the family's present tiles followed
+        by tiles of zeros, up to a multiple of ``bucket``, so that the
+        instances of every height between two multiples share one program.
+        The kernel has to leave a zero tile zero; what it returns for one
+        goes back to the device's pool of zeros."""
+        self._pad_rows = (int(lead), int(bucket))
         return self
 
     # -- user-defined overrides (the jdf.h:185-210 UD property family) ------
@@ -301,6 +313,12 @@ class TaskClassBuilder:
         if ref is not None:
             cls_name, flow_name, params_fn = ref
             tparams = lambda locals_: params_fn(g, _ns(locals_))
+            if callable(flow_name):
+                # a flow of a family (one a tile row), named per instance:
+                # ``fn(g, l)`` of the producer's locals on an output, of the
+                # consumer's on an input (``Dep.flow_name``)
+                name_fn = flow_name
+                flow_name = lambda locals_: name_fn(g, _ns(locals_))
             return Dep(guard=gfn, target_class=cls_name,
                        target_flow=flow_name, target_params=tparams, dtt=dtt,
                        ranged=ranged, wire=wfn)
@@ -356,6 +374,7 @@ class TaskClassBuilder:
             tc.stage_in_hook = self._stage_in_hook
         if self._stage_out_hook is not None:
             tc.stage_out_hook = self._stage_out_hook
+        tc.pad_rows = self._pad_rows
 
         # execution-space membership (the generated bounds-check role):
         # parameters validate in declaration order against their ranges.

@@ -532,8 +532,8 @@ def release_deps(es: ExecutionStream, task: Task) -> None:
                     with tp._sim_lock:
                         if task.sim_exec_date > tp._sim_ready.get(skey, 0.0):
                             tp._sim_ready[skey] = task.sim_exec_date
-                fi, di = _find_input_dep(succ_tc, dep.target_flow, tc.name,
-                                         sv)
+                fi, di = _find_input_dep(succ_tc, dep.flow_name(tv),
+                                         tc.name, sv)
                 bit = 0 if succ_tc.counted else 1 << succ_tc.dep_bit(fi, di)
             send = out_copy
             if out_copy is not None:

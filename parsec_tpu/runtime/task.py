@@ -38,6 +38,10 @@ _task_counter = itertools.count()
 
 _UNSET = object()   # lazy-attribute sentinel (space_extents)
 
+# bits of a task's IN-dep mask the native dep table holds (``uint64_t``,
+# native/src/core.cpp): a class with more input deps is tracked by count
+MASK_BITS = 64
+
 
 class Dep:
     """One dependency edge endpoint on a flow (cf. ``parsec_dep_t``).
@@ -91,6 +95,15 @@ class Dep:
 
     def active(self, locals_: dict) -> bool:
         return self.guard is None or bool(self.guard(locals_))
+
+    def flow_name(self, locals_: dict) -> str | None:
+        """The flow at the other end: ``target_flow``, or what it gives for
+        ``locals_`` where it is a function (a class whose flows form a
+        family, one a tile row: ``T3`` of ``PANEL`` for ``GEMM(3, n, k)``).
+        An output dep's function reads the producer's locals, an input
+        dep's the consumer's."""
+        tf = self.target_flow
+        return tf(locals_) if callable(tf) else tf
 
     def each_target(self, locals_: dict) -> tuple[dict, ...]:
         """Successor instances of this out-dep for ``locals_``.
@@ -246,11 +259,10 @@ class TaskClass:
         self.space_extents_fn: Callable[[], tuple | None] | None = None
         self._space_extents: Any = _UNSET
         self.repo = None                  # DataRepo, attached by the taskpool
-        # counted mode: any ranged input dep means arrivals are *counted*
-        # toward a per-task goal instead of OR-ed into a bitmask (the
-        # reference's dependencies_goal counting vs mask protocol)
-        self.counted = any(d.ranged for f in self.flows for d in f.deps_in)
         self.dependencies_goal = 0        # static goal unused when guarded
+        # a device batch may append zero tiles to the trailing data flows
+        # (``pad_rows``: (lead, bucket), set by the front end; None: never)
+        self.pad_rows: tuple[int, int] | None = None
         # make_key on the C path: itemgetter over the param names
         from operator import itemgetter
         if len(self.params) >= 2:
@@ -272,6 +284,15 @@ class TaskClass:
                 if d.target_class is not None:
                     self._pred_in.append((1 << bit, d))
                 bit += 1
+        # counted mode: arrivals are *counted* toward a per-task goal
+        # instead of OR-ed into a bitmask (the reference's
+        # dependencies_goal counting vs mask protocol) where a ranged input
+        # dep fans N arrivals into one declared dep, and where the input
+        # deps outnumber the native table's mask (a class with a flow a
+        # tile row: every flow still has one active input, so no datum
+        # slot is raced)
+        self.counted = bit > MASK_BITS or any(
+            d.ranged for f in self.flows for d in f.deps_in)
 
     # -- keys ---------------------------------------------------------------
     def make_key(self, locals_: dict) -> tuple:

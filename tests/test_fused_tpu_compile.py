@@ -97,3 +97,21 @@ def test_the_donating_program_pairs_each_written_lane_with_its_result(
     assert held <= mem.output_size_in_bytes < held + (1 << 20)
     assert mem.alias_size_in_bytes == held
     assert mem.temp_size_in_bytes <= held // 4, mem
+
+
+def test_the_pivoting_panel_compiles_with_its_own_scoped_vmem(one_chip):
+    """``jit_fused_getrf_panel`` on a column of 16 tiles (PR 42): XLA's TPU
+    LU keeps a 128-column block of the whole stack in scoped VMEM, which the
+    default 16 MiB cannot hold at that height (PERF.md, PR 42, step 0); with
+    the traceable's ``tpu_compiler_options`` it compiles, and the donating
+    program takes every row's and the pivot tile's buffer."""
+    from parsec_tpu.device.tpu import _fused_program
+    from parsec_tpu.models import lu
+    tr = lu._panel_traceable
+    rows = [jax.ShapeDtypeStruct((NB, NB), jnp.float32, sharding=one_chip)] \
+        * 16
+    piv = jax.ShapeDtypeStruct((4, NB), jnp.int32, sharding=one_chip)
+    mem = _fused_program(tr, "getrf_panel", 1, tuple(range(17)),
+                         tr.tpu_compiler_options).lower(
+        piv, *rows).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == 16 * NB * NB * 4 + 4 * NB * 4

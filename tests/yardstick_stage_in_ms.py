@@ -46,7 +46,10 @@ def test_manifest_lists_the_dtd_cell_on_the_twin_s_metrics_that_read_it():
     shared = dtd.SHARED | {NAME, "devmod.call_us_per_result",
                            "devmod.dispatch_own_us_per_task",
                            "devmod.chip_queue_depth",
-                           "devmod.donated_result_share"}
+                           "devmod.donated_result_share",
+                           # PR 42 gave it the list of every accepted cell,
+                           # in the manifest's order
+                           "startup.fresh_compiles_at_setup"}
     manifest, per_layer = dtd._manifest()
     (rate,) = [m for m in manifest["end_to_end"]
                if m["name"] == "dynamic.gflops"]
