@@ -73,6 +73,12 @@ _params.register("dtd_threshold_size", 1024,
 
 _MAX_TASK_CLASSES = 25  # PARSEC_DTD_NB_TASK_CLASSES (insert_function_internal.h:31)
 
+# PINS fast path, as in runtime/scheduling.py: the identity-stable dispatch
+# table, so a site nothing listens to is an index load and a branch
+_hooks = pins.hooks
+_RELEASE_DEPS_BEGIN = int(PinsEvent.RELEASE_DEPS_BEGIN)
+_RELEASE_DEPS_END = int(PinsEvent.RELEASE_DEPS_END)
+
 _now = time.perf_counter_ns
 
 # always on, like the device module's counters, per pool (the attributes of
@@ -794,7 +800,9 @@ class DTDTaskpool(Taskpool):
         are released — a successor writer mutating the host tile in place
         cannot corrupt an in-flight payload (the WAR discipline of the shell
         protocol)."""
-        pins.fire(PinsEvent.RELEASE_DEPS_BEGIN, es, task)
+        h = _hooks[_RELEASE_DEPS_BEGIN]
+        if h is not None:
+            h(es, task)
         for spec in task.args:
             if spec.flow_index < 0 or spec.flags & SCRATCH:
                 continue
@@ -819,7 +827,9 @@ class DTDTaskpool(Taskpool):
                 if succ.deps_pending == 0:
                     succ.status = "ready"
                     ready.append(succ)
-        pins.fire(PinsEvent.RELEASE_DEPS_END, es, task)
+        h = _hooks[_RELEASE_DEPS_END]
+        if h is not None:
+            h(es, task)
         if ready:
             schedule_tasks(es, ready, 0)
         with self._icond:

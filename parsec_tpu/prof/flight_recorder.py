@@ -5,12 +5,13 @@ bus and binary profiling streams (``parsec/mca/pins/pins.h``, SURVEY
 §layer map) — wired, unlike the reference, to be ON by default and to
 survive a wedged run:
 
-- **Flight recorder** — every :func:`pins.fire` site feeds a per-worker
+- **Flight recorder** — :func:`pins.fire` sites feed a per-worker
   fixed-size ring of ``(event, timestamp_ns, task_id, payload_summary)``
-  records through ``pins.recorder``.  Enabled cost per site is one branch
-  plus one ring write; disabled cost is one attribute load + truth test
-  (the compiled-out analog).  Rings are thread-local, so no site ever
-  takes a lock.
+  records through ``pins.recorder``.  Enabled cost per site is one call
+  and at most one ring write; a task's completion writes one record, at
+  ``COMPLETE_EXEC_BEGIN``, and tallies its end (:meth:`FlightRecorder.site`);
+  disabled cost is one attribute load + truth test (the compiled-out
+  analog).  Rings are thread-local, so no site ever takes a lock.
 - **Stall dump** — :func:`stall_dump` serializes every worker's last-N
   events, scheduler queue depths, in-flight comm operations, and device
   stage-in state to stderr and a ``flightrec-<rank>.json`` artifact.
@@ -63,6 +64,12 @@ _now = time.perf_counter_ns
 _N_EVENTS = max(int(e) for e in PinsEvent) + 1
 _SB, _SE = PinsEvent.SELECT_BEGIN, PinsEvent.SELECT_END
 _DFB, _DFE = PinsEvent.DAG_FETCH_BEGIN, PinsEvent.DAG_FETCH_END
+_CEB, _CEE = PinsEvent.COMPLETE_EXEC_BEGIN, PinsEvent.COMPLETE_EXEC_END
+# the begin/end pairs inside a completion: its COMPLETE_EXEC_BEGIN record
+# already names the task they are about, so they never reach the recorder
+_IN_COMPLETION = frozenset((PinsEvent.RELEASE_DEPS_BEGIN,
+                            PinsEvent.RELEASE_DEPS_END,
+                            PinsEvent.SCHEDULE_BEGIN, PinsEvent.SCHEDULE_END))
 
 
 def _describe(p: Any) -> tuple[Any, Any]:
@@ -96,7 +103,7 @@ class _Ring:
     (thread-local); snapshots from other threads are best-effort reads."""
 
     __slots__ = ("name", "size", "slots", "total", "counts", "vsums",
-                 "idle", "idle_ns")
+                 "idle", "idle_ns", "settled")
 
     def __init__(self, name: str, size: int) -> None:
         self.name = name
@@ -109,6 +116,19 @@ class _Ring:
         self.vsums = [0] * _N_EVENTS    # sum of integer payloads
         self.idle = 0                   # empty selects (liveness ticks)
         self.idle_ns = 0
+        # ``total`` at the last COMPLETE_EXEC_END: a completion record at
+        # or past it belongs to a completion that has not ended
+        self.settled = 0
+
+    def completing(self) -> dict | None:
+        """The task whose completion this thread is in (or left by an
+        exception): the newest completion record, if its end never came."""
+        for i in range(self.total - 1,
+                       max(self.settled, self.total - self.size) - 1, -1):
+            rec = self.slots[i % self.size]
+            if rec is not None and rec[0] is _CEB:
+                return {"task": rec[2], "info": rec[3]}
+        return None
 
     def events(self, last: int | None = None) -> list[dict]:
         n = min(self.total, self.size)
@@ -143,6 +163,7 @@ class FlightRecorder:
         self._retired_counts = [0] * _N_EVENTS
         self._retired_vsums = [0] * _N_EVENTS
         self._retired_idle = 0
+        self._retired_writes = 0
 
     def _new_ring(self) -> _Ring:
         r = _Ring(threading.current_thread().name, self.size)
@@ -153,12 +174,51 @@ class FlightRecorder:
                     self._retired_counts[i] += old.counts[i]
                     self._retired_vsums[i] += old.vsums[i]
                 self._retired_idle += old.idle
+                self._retired_writes += old.total
             self.rings[r.name] = r
         self._tls.ring = r
         return r
 
+    def site(self, event: PinsEvent) -> Any:
+        """The recorder's part of ``event``'s PINS slot (``pins`` calls it
+        as each slot is compiled): an ``(es, payload)`` callable, or None
+        where the event does not reach the recorder.  A completion writes
+        one record: its begin, the task's uid and class read off the
+        ``Task``; its end is a tally (``tasks_retired``); the release and
+        schedule pairs inside it are left to whatever PINS chain
+        subscribes to them."""
+        if event in _IN_COMPLETION:
+            return None
+        if event is _CEB:
+            return self._completion_begin
+        if event is _CEE:
+            return self._completion_end
+
+        def h(es: Any, payload: Any, _n=self.note, _e=event) -> None:
+            _n(_e, payload)
+        return h
+
+    def _completion_begin(self, es: Any, task: Any) -> None:
+        try:
+            r = self._tls.ring
+        except AttributeError:
+            r = self._new_ring()
+        r.counts[_CEB] += 1
+        i = r.total
+        r.slots[i % r.size] = (_CEB, _now(), task.uid, task.task_class.name)
+        r.total = i + 1
+
+    def _completion_end(self, es: Any, task: Any) -> None:
+        try:
+            r = self._tls.ring
+        except AttributeError:
+            r = self._new_ring()
+        r.counts[_CEE] += 1
+        r.settled = r.total
+
     def note(self, event: Any, payload: Any) -> None:
-        """The ``pins.recorder`` hook: one branch + one ring write."""
+        """The recorder as a plain ``(event, payload)`` hook: at most one
+        ring write."""
         try:
             r = self._tls.ring
         except AttributeError:
@@ -191,9 +251,17 @@ class FlightRecorder:
         r.slots[i % r.size] = (event, _now(), tid, summ)
         r.total = i + 1
 
+    __call__ = note
+
     def all_rings(self) -> list[_Ring]:
         with self._lock:
             return list(self.rings.values())
+
+    def writes(self) -> int:
+        """Records written to the rings, displaced rings' included."""
+        with self._lock:
+            n = self._retired_writes
+        return n + sum(r.total for r in self.all_rings())
 
     def snapshot(self, last: int | None = None) -> dict[str, dict]:
         """Per-worker ring contents, oldest-first (best-effort under
@@ -206,6 +274,7 @@ class FlightRecorder:
                 "idle_selects": r.idle,
                 "idle_age_ms": (round((now - r.idle_ns) / 1e6, 1)
                                 if r.idle else None),
+                "completing": r.completing(),
                 "events": r.events(last),
             }
         return out
@@ -231,7 +300,7 @@ def install(size: int | None = None) -> FlightRecorder:
     if size is None:
         size = _params.get("prof_flightrec_size")
     recorder = FlightRecorder(max(int(size), 1))
-    pins.recorder = recorder.note
+    pins.recorder = recorder
     return recorder
 
 
@@ -424,6 +493,9 @@ def stall_dump(context: Any = None, reason: str = "", last: int = 32,
                             f"info={e['info']} {age:.0f}ms ago")
             else:
                 lastline = "no events"
+            c = r.get("completing")
+            if c:
+                lastline += f", completing task={c['task']} info={c['info']}"
             w(f"[flightrec]   {name}: {r['total']} events, "
               f"{r['idle_selects']} idle selects, {lastline}\n")
     w(f"[flightrec]   sched_pending={report.get('sched_pending')} "
@@ -522,6 +594,11 @@ def runtime_report(max_workers: int = 6) -> dict:
     rep["dag_tasks_completed"] = vsums[PinsEvent.DAG_COMPLETE_END]
     rep["tasks_retired"] = (rep["dynamic_tasks_retired"]
                             + rep["dag_tasks_completed"])
+    # what the recorder costs a task, in ring writes: reckoned here from
+    # the rings' totals, nothing counted on the hot path for it
+    rep["notes_per_task_retired"] = (
+        round(r.writes() / rep["tasks_retired"], 3)
+        if rep["tasks_retired"] else None)
     rep["h2d_bytes"] = vsums[PinsEvent.DEVICE_STAGE_IN]
     rep["comm_activations_sent"] = counts[PinsEvent.COMM_ACTIVATE_SEND]
     if counts[PinsEvent.COMM_ACTIVATE_SEND] \

@@ -105,7 +105,8 @@ enabled = False
 # chains so the always-on recorder costs one slot call per site without
 # flipping ``enabled`` (which would tax the compiled executor's per-task
 # instrumentation branches).  Exposed as the assignable ``pins.recorder``
-# attribute through the module-class property below.
+# attribute through the module-class property below; a hook with a
+# ``site`` method compiles its own part of each slot (:func:`_recorder_site`).
 _recorder: Callable[[Any, Any], None] | None = None
 
 # the per-event dispatch table.  IDENTITY-STABLE: hot call sites bind this
@@ -113,27 +114,39 @@ _recorder: Callable[[Any, Any], None] | None = None
 hooks: list[Callable[[Any, Any], None] | None] = [None] * N_EVENTS
 
 
-def _slot(event: int) -> Callable[[Any, Any], None] | None:
-    """Compile one event's dispatch slot from the current recorder/chains."""
+def _recorder_site(event: int) -> Callable[[Any, Any], None] | None:
+    """The recorder's part of one event's slot, an ``(es, payload)``
+    callable or None.  A recorder with a ``site(event)`` method compiles
+    its own (the flight recorder: per event, what a note costs and whether
+    the event reaches it at all); any other callable is called with the
+    event."""
     rec = _recorder
-    chain = _chains.get(event)
-    if not chain:
-        chain = None
-    if rec is None and chain is None:
+    if rec is None:
         return None
     ev = PinsEvent(event)
-    if chain is None:
-        def h(es: Any, payload: Any, _r=rec, _e=ev) -> None:
-            _r(_e, payload)
-        return h
+    site = getattr(rec, "site", None)
+    if site is not None:
+        return site(ev)
+
+    def h(es: Any, payload: Any, _r=rec, _e=ev) -> None:
+        _r(_e, payload)
+    return h
+
+
+def _slot(event: int) -> Callable[[Any, Any], None] | None:
+    """Compile one event's dispatch slot from the current recorder/chains."""
+    rec = _recorder_site(event)
+    chain = _chains.get(event)
+    if not chain:
+        return rec
     if rec is None:
         def h(es: Any, payload: Any, _c=chain) -> None:
             for cb in _c:               # snapshot-free: append-only lists
                 cb(es, payload)
         return h
 
-    def h(es: Any, payload: Any, _r=rec, _c=chain, _e=ev) -> None:
-        _r(_e, payload)
+    def h(es: Any, payload: Any, _r=rec, _c=chain) -> None:
+        _r(es, payload)
         for cb in _c:
             cb(es, payload)
     return h

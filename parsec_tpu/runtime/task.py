@@ -381,7 +381,7 @@ class Task:
     __slots__ = ("taskpool", "task_class", "locals", "priority", "data",
                  "repo_entries", "status", "chore_mask", "uid",
                  "selected_device", "_mempool_owner", "on_complete",
-                 "sim_exec_date")
+                 "sim_exec_date", "__weakref__")
 
     def __init__(self, taskpool: Any, task_class: TaskClass,
                  locals_: dict, priority: int = 0) -> None:

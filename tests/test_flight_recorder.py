@@ -3,6 +3,8 @@ path's zero-allocation contract, the stall dump a wedged run must produce
 (a hung device or peer must not leave a run without self-reported
 evidence), the metrics snapshotter, and the unified run-report export."""
 
+import gc
+import io
 import json
 import threading
 import time
@@ -369,6 +371,186 @@ def test_export_run_report_roundtrip_chrome(tmp_path, param):
     finally:
         comp.close(mod)
         trace_state.fini()
+
+
+# ---------------------------------------------------------------------------
+# the completion path on the stand-in accelerator
+# ---------------------------------------------------------------------------
+
+_IN_COMPLETION = {"RELEASE_DEPS_BEGIN", "RELEASE_DEPS_END",
+                  "SCHEDULE_BEGIN", "SCHEDULE_END",
+                  "COMPLETE_EXEC_BEGIN", "COMPLETE_EXEC_END"}
+
+
+@pytest.fixture
+def roomy_recorder():
+    """A private recorder whose rings keep every record of a small solve,
+    with whatever was installed before restored after."""
+    old_rec, old_hook = flight_recorder.recorder, pins.recorder
+    rec = flight_recorder.install(1 << 14)
+    yield rec
+    flight_recorder.recorder, pins.recorder = old_rec, old_hook
+
+
+_NT, _NB = 4, 16           # 4 x 4 x 4 GEMM tasks on 16-square tiles
+
+
+def _gemm_operands():
+    import numpy as np
+    from parsec_tpu.data_dist.matrix import TiledMatrix
+    a = np.random.default_rng(43).standard_normal(
+        (_NT * _NB, _NT * _NB)).astype(np.float32)
+    return (a, TiledMatrix.from_dense("A", a, _NB, _NB),
+            TiledMatrix.from_dense("B", a.T.copy(), _NB, _NB),
+            TiledMatrix.from_dense("C", np.zeros_like(a), _NB, _NB))
+
+
+def _gemm_solve(front: str, device) -> int:
+    """C += A·Aᵀ on ``device`` through one front end, to the flush and
+    ``fini``; returns the number of tasks."""
+    import numpy as np
+    from parsec_tpu.models.tiled_gemm import tiled_gemm_dtd, tiled_gemm_ptg
+    a, A, B, C = _gemm_operands()
+    ctx = Context(nb_cores=0)
+    if front == "ptg":
+        ctx.add_taskpool(tiled_gemm_ptg(A, B, C, devices="tpu"))
+        ctx.wait(timeout=120)
+    else:
+        from parsec_tpu.dtd import DTDTaskpool
+        tp = DTDTaskpool()
+        ctx.add_taskpool(tp)
+        tiled_gemm_dtd(tp, A, B, C)
+        tp.wait(timeout=120)
+    device.sync()
+    device.flush_cache()
+    ctx.fini()
+    np.testing.assert_allclose(C.to_dense(), a @ a.T, rtol=1e-4, atol=1e-3)
+    return _NT ** 3
+
+
+@pytest.mark.parametrize("front", ["ptg", "dtd"])
+def test_a_completion_writes_one_record(front, accel_device, roomy_recorder):
+    """Each completion on the device path is one ring record, its begin,
+    naming the task; the release and schedule pairs inside it and its end
+    write none, the end still counts the task retired, and the report's
+    ``notes_per_task_retired`` is what the rings were written over it
+    (six to eight a completion when each site wrote its own)."""
+    ntasks = _gemm_solve(front, accel_device)
+    assert accel_device.executed_tasks == ntasks
+    rings = roomy_recorder.snapshot().values()
+    assert all(r["completing"] is None for r in rings)
+    records = [e for r in rings for e in r["events"]]
+    done = [e for e in records if e["event"] in _IN_COMPLETION]
+    assert len(done) == ntasks
+    assert {e["event"] for e in done} == {"COMPLETE_EXEC_BEGIN"}
+    assert len({e["task"] for e in done}) == ntasks
+    assert all(isinstance(e["task"], int) and e["info"] for e in done)
+    rep = runtime_report()
+    assert rep["dynamic_tasks_retired"] == ntasks
+    assert rep["notes_per_task_retired"] == round(
+        roomy_recorder.writes() / rep["tasks_retired"], 3)
+    assert rep["notes_per_task_retired"] == round(
+        len(records) / ntasks, 3)
+    assert rep["notes_per_task_retired"] < 2
+
+
+class _ReleaseFailed(BaseException):
+    """Escapes the device module's demotion path, which catches
+    ``Exception``: the run stops at the completion that raised."""
+
+
+def test_failed_release_names_its_task_in_the_stall_dump(
+        accel_device, roomy_recorder, param, tmp_path):
+    param("prof_flightrec_dir", str(tmp_path))
+    seen = []
+
+    def release_raises(es, task):
+        seen.append((task.uid, task.task_class.name))
+        if len(seen) == 5:
+            raise _ReleaseFailed
+
+    from parsec_tpu.models.tiled_gemm import tiled_gemm_ptg
+    _, A, B, C = _gemm_operands()
+    ctx = Context(nb_cores=0)
+    ctx.add_taskpool(tiled_gemm_ptg(A, B, C, devices="tpu"))
+    pins.register(PinsEvent.RELEASE_DEPS_BEGIN, release_raises)
+    try:
+        with pytest.raises(_ReleaseFailed):
+            ctx.wait(timeout=120)
+    finally:
+        pins.unregister(PinsEvent.RELEASE_DEPS_BEGIN, release_raises)
+        ctx.fini()           # the failure poisoned it: no drain
+    err = io.StringIO()
+    report = flight_recorder.stall_dump(ctx, "a release raised", file=err)
+    ring = report["workers"][threading.current_thread().name]
+    uid, cls = seen[-1]
+    assert ring["completing"] == {"task": uid, "info": cls}
+    assert f"completing task={uid} info={cls}" in err.getvalue()
+    # the four completions before it are on the ring too
+    begun = [e["task"] for e in ring["events"]
+             if e["event"] == "COMPLETE_EXEC_BEGIN"]
+    assert begun == [u for u, _ in seen]
+
+
+@pytest.mark.parametrize("front", ["ptg", "dtd"])
+def test_rings_hold_no_task(front, accel_device, roomy_recorder):
+    """A record names its task by uid and class: a ring that held a Task
+    would keep its data copies alive (and defeat the device module's
+    sole-holder probe), so every completed task is gone after fini."""
+    import weakref
+    from parsec_tpu.runtime.task import Task
+    refs = []
+
+    def keep_weakly(es, task):
+        refs.append(weakref.ref(task))
+
+    pins.register(PinsEvent.COMPLETE_EXEC_END, keep_weakly)
+    try:
+        ntasks = _gemm_solve(front, accel_device)
+    finally:
+        pins.unregister(PinsEvent.COMPLETE_EXEC_END, keep_weakly)
+    assert len(refs) == ntasks
+    for ring in roomy_recorder.all_rings():
+        assert not any(isinstance(x, Task) for rec in ring.slots
+                       if rec is not None for x in rec)
+    gc.collect()
+    assert [r for r in refs if r() is not None] == []
+
+
+def test_chains_on_release_and_schedule_see_every_event(
+        accel_device, roomy_recorder, monkeypatch):
+    """The recorder left the release and schedule sites; a PINS chain
+    registered there still receives each of them."""
+    from parsec_tpu.runtime import context as context_mod
+    from parsec_tpu.runtime import scheduling
+    released, scheduled, calls = [], [], []
+    plain = scheduling.schedule_tasks
+
+    def counted(es, tasks, distance=0):
+        if tasks:
+            calls.append(len(tasks))
+        plain(es, tasks, distance)
+
+    monkeypatch.setattr(scheduling, "schedule_tasks", counted)
+    monkeypatch.setattr(context_mod, "schedule_tasks", counted)
+
+    def on_release(es, task):
+        released.append(task.uid)
+
+    def on_schedule_end(es, tasks):
+        scheduled.append(es)
+
+    pins.register(PinsEvent.RELEASE_DEPS_BEGIN, on_release)
+    pins.register(PinsEvent.SCHEDULE_END, on_schedule_end)
+    try:
+        ntasks = _gemm_solve("ptg", accel_device)
+    finally:
+        pins.unregister(PinsEvent.RELEASE_DEPS_BEGIN, on_release)
+        pins.unregister(PinsEvent.SCHEDULE_END, on_schedule_end)
+    assert len(released) == len(set(released)) == ntasks
+    # the startup batch, then one a completion that readied GEMM(m, n, k+1)
+    assert len(scheduled) == len(calls) == 1 + ntasks - 4 * 4
+    assert runtime_report()["dynamic_tasks_retired"] == ntasks
 
 
 def test_runtime_report_is_json_serializable_and_compact():
