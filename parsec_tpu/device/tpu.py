@@ -455,6 +455,12 @@ class TPUDevice(Device):
         # the hit-rate gauge the metrics snapshotter samples
         self.cache_hits = 0
         self.cache_misses = 0
+        # the LRU's side of the hits: distinct hit copies a batch moved to
+        # its recent end (summed over batches), and those of them it did not
+        # hold under their datum (evicted meanwhile, or another copy), which
+        # took the charging insert
+        self.lru_touches = 0
+        self.lru_recharged = 0
         # data flows an instance left null (a flow family's rows above the
         # panel): nothing staged, nothing handed to the kernel
         self.null_flows_skipped = 0
@@ -508,6 +514,43 @@ class TPUDevice(Device):
             self._mem_lru.move_to_end(key)
             self._mem_bytes += nbytes
         self._make_room(0)
+
+    def _touch(self, hits: list[DataCopy]) -> None:
+        """The LRU's side of a batch's hits (a copy a reference, in the
+        walk's order): each distinct copy once, in the order of its last
+        reference, as a move to the end per reference would leave them.  A
+        copy the LRU holds under its datum only moves: it was charged its
+        bytes when it landed, and again where a result of another size
+        replaced its value (:meth:`_recharge`).  Any other takes the
+        charging insert: a victim of an eviction whose write-back is still
+        queued (the w2r drain skips what is back in the LRU), or a datum the
+        LRU holds another copy of.  The budget is asked once, after."""
+        touched = dict.fromkeys(reversed(hits))
+        lru = self._mem_lru
+        recharged = 0
+        with self._lru_lock:
+            for c in reversed(touched):
+                d = c.original
+                old = lru.get(d)
+                if old is not c:
+                    if old is not None:
+                        self._mem_bytes -= _copy_nbytes(old)
+                    lru[d] = c
+                    self._mem_bytes += _copy_nbytes(c)
+                    recharged += 1
+                lru.move_to_end(d)
+        self.lru_touches += len(touched)
+        self.lru_recharged += recharged
+        self._make_room(0)
+
+    def _recharge(self, copies: list[DataCopy], delta: int) -> None:
+        """Written results of another size than the versions they replaced:
+        the LRU charged each of ``copies`` it holds the old value's bytes,
+        and ``delta`` more is what the new one holds."""
+        with self._lru_lock:
+            for c in copies:
+                if self._mem_lru.get(c.original) is c:
+                    self._mem_bytes += delta
 
     def _make_room(self, need: int) -> None:
         """The one place the budget is held.  It covers everything the
@@ -699,29 +742,40 @@ class TPUDevice(Device):
         """Batched stage-in: resolve every task's misses first, then move
         them in one :meth:`_transfer` (the copies are made one after the
         other; each returns once PJRT has the bytes and crosses behind the
-        next).  Duplicate tiles across the batch stage once; a hit
-        re-inserted into the LRU resurrects an evicted-but-not-yet-written-
-        back victim (the pending w2r skips anything back in the LRU)."""
+        next).  Duplicate tiles across the batch stage once.  A hit is a
+        read of the datum's copy here and a recency touch, made once a
+        distinct copy after the walk (:meth:`_touch`), before the misses
+        land; a hit the LRU no longer holds resurrects an evicted-but-not-
+        yet-written-back victim (the pending w2r skips anything back in the
+        LRU)."""
         assigns: list[tuple[Any, int, Any]] = []   # (task, flow_idx, datum)
         missing: dict[Any, DataCopy] = {}          # datum -> source copy
+        hits: list[DataCopy] = []                  # a hit copy a reference
+        hit = hits.append
+        here = self.device_index
+        misses = nulls = 0
+        tc = indices = None
         for task in tasks:
-            for f in task.task_class.flows:
-                if f.is_ctl:
-                    continue
-                copy = task.data[f.flow_index]
+            if task.task_class is not tc:
+                tc = task.task_class
+                indices = [f.flow_index for f in tc.flows if not f.is_ctl]
+            data = task.data
+            for fi in indices:
+                copy = data[fi]
                 if copy is None:
-                    self.null_flows_skipped += 1
+                    nulls += 1
                     continue
                 d = copy.original
-                dev_copy = d.get_copy(self.device_index)
+                # one read of the datum's dict, which whoever changes it does
+                # under the datum's lock: the copy before or after the change
+                dev_copy = d.device_copies.get(here)
                 if dev_copy is not None \
                         and dev_copy.version >= copy.version \
                         and dev_copy.coherency != COHERENCY_INVALID:
-                    self.cache_hits += 1
-                    task.data[f.flow_index] = dev_copy
-                    self._cache_insert(dev_copy, _copy_nbytes(dev_copy))
+                    data[fi] = dev_copy
+                    hit(dev_copy)
                     continue
-                self.cache_misses += 1
+                misses += 1
                 prev = missing.get(d)
                 if prev is None:
                     missing[d] = copy
@@ -736,7 +790,12 @@ class TPUDevice(Device):
                                min(copy.version, prev.version)))
                     if copy.version > prev.version:
                         missing[d] = copy
-                assigns.append((task, f.flow_index, d))
+                assigns.append((task, fi, d))
+        self.cache_hits += len(hits)
+        self.cache_misses += misses
+        self.null_flows_skipped += nulls
+        if hits:
+            self._touch(hits)
         if not missing:
             return
         keys = list(missing)
@@ -1073,8 +1132,9 @@ class TPUDevice(Device):
                 for dtask in batch:   # exec phase (exec streams analog)
                     # the body replaces each written flow's value: what it
                     # supersedes stays allocated until the program has run
-                    held = sum(_copy_nbytes(c) for c in
-                               self._written_copies(dtask.task))
+                    written = [(c, _copy_nbytes(c)) for c in
+                               self._written_copies(dtask.task)]
+                    held = sum(nb for _, nb in written)
                     self._make_room(held)
                     warming = () if dtask.submit in self._task_programs \
                         else self._meet_task_program(dtask)
@@ -1089,6 +1149,10 @@ class TPUDevice(Device):
                         self.executed_tasks += 1
                         self._count_dispatch(dtask.task.task_class, 1)
                         self._mark_written(dtask.task)
+                        for c, nb in written:
+                            delta = _copy_nbytes(c) - nb
+                            if delta:
+                                self._recharge([c], delta)
         with _Wall(self, "t_complete", "devmod.complete"):
             # per task, the plane adds to a counter (sched.release) and
             # opens no span
@@ -1337,6 +1401,11 @@ class TPUDevice(Device):
                     c.version += 1
                 if npad and w in fn.donates:
                     self._scratch[sig[w]].extend(parts[B:])
+                # a donated flow's results have its inputs' shape and dtype
+                new, old = parts[0], cols[w][0]
+                if w not in fn.donates and (new.shape != old.shape
+                                            or new.dtype != old.dtype):
+                    self._recharge(copies[w], new.nbytes - old.nbytes)
             for dtask in batch:
                 self.executed_tasks += 1
                 self._mark_written(dtask.task)
@@ -1589,6 +1658,8 @@ class TPUDevice(Device):
                  "invalidated_copies": self.invalidated_copies,
                  "cache_hits": self.cache_hits,
                  "cache_misses": self.cache_misses,
+                 "lru_touches": self.lru_touches,
+                 "lru_recharged": self.lru_recharged,
                  "bytes_in": self.bytes_in, "bytes_out": self.bytes_out,
                  "bytes_d2d": self.bytes_d2d, "d2d_tiles": self.d2d_tiles,
                  "pushouts": self.pushouts, "writebacks": self.writebacks,

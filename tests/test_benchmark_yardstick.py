@@ -6,8 +6,8 @@ the pivoted LU cell's own)
 and ``yardstick_writeback_early_share.py``, ``yardstick_flood_metrics.py``,
 ``yardstick_stage_in_ms.py``, ``yardstick_dispatch_metrics.py``,
 ``yardstick_donated_share.py``, ``yardstick_qr_cell.py``,
-``yardstick_ring_excused_share.py`` and ``yardstick_getrf_cell.py`` beside
-this file are collected here under
+``yardstick_ring_excused_share.py``, ``yardstick_getrf_cell.py`` and
+``yardstick_lru_touches.py`` beside this file are collected here under
 their own names, so each counts, and a name that two files give is an error
 here and not one test fewer.  They need no chip.  The rehearsals run in
 processes of their own, and all from this one file, so that under ``--dist
@@ -73,7 +73,8 @@ for _dir, _name in ((_BENCH, "test_yardstick"), (_BENCH, "test_phase_metrics"),
                     (_HERE, "yardstick_dispatch_metrics"),
                     (_HERE, "yardstick_donated_share"),
                     (_HERE, "yardstick_qr_cell"),
-                    (_HERE, "yardstick_ring_excused_share")):
+                    (_HERE, "yardstick_ring_excused_share"),
+                    (_HERE, "yardstick_lru_touches")):
     _tests = {}
     for _k, _v in vars(_load(_dir, _name)).items():
         if _k in _IN_PLACE:
