@@ -751,7 +751,7 @@ def _check_output_arrow(report, tp, tc, flow, d, locals_, node, succ_tc, sl,
         return
     try:
         fi, _di = _find_input_dep(succ_tc, d.flow_name(locals_), tc.name,
-                                  sl)
+                                  sl, locals_)
     except (KeyError, LookupError):
         report.add(
             "missing-input-edge", ERROR,

@@ -43,6 +43,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from ..core.backoff import Backoff
 from ..core.params import params as _params
 from ..prof import pins, spans as _spans
 from ..prof.pins import PinsEvent
@@ -746,15 +747,18 @@ class InprocCommEngine(CommEngine):
 
     def sync(self) -> None:
         """All-ranks barrier over AMs, progressing while waiting."""
-        import time
         gen = self._barrier_gen = self._barrier_gen + 1
         seen = self._barrier_seen.setdefault(gen, set())
         for r in range(self.nranks):
             if r != self.rank:
                 self.send_am(AM_TAG_BARRIER, r, {"gen": gen})
         deadline = time.monotonic() + 30.0
+        backoff = Backoff()
         while len(seen) < self.nranks - 1:
-            self.progress()
+            if self.progress():
+                backoff.reset()
+            else:
+                backoff.wait()   # a spin here starves the peers' threads
             if time.monotonic() > deadline:
                 raise TimeoutError(f"rank {self.rank} barrier timeout")
         del self._barrier_seen[gen]

@@ -41,6 +41,7 @@ from typing import Any
 
 import numpy as np
 
+from ..core.backoff import Backoff
 from ..core.params import params as _params
 from ..data.data import data_create
 from ..data.datatype import wire_slice_key
@@ -502,11 +503,14 @@ class RemoteDepEngine:
         """Progress until this rank has no in-flight activations and an
         all-ranks barrier passes twice with silence in between (context-level
         drain; taskpool-level termination is the termdet's job)."""
-        import time
         deadline = time.monotonic() + timeout
+        backoff = Backoff()
         for _round in range(2):
             while self.inflight() or self.ce.pending() or self._outq:
-                self.progress()
+                if self.progress():
+                    backoff.reset()
+                else:
+                    backoff.wait()   # a spin here starves the peers' threads
                 if time.monotonic() > deadline:
                     raise TimeoutError(f"rank {self.my_rank} quiesce timeout")
             self.ce.sync()
@@ -841,7 +845,7 @@ class RemoteDepEngine:
                 if rank != self.my_rank:
                     continue
                 fi, di = _find_input_dep(succ_tc, dep.target_flow, tc.name,
-                                         succ_locals)
+                                         succ_locals, t.locals)
                 # the wire carries the producer's type; a typed edge
                 # repacks on the read side (remote_dep.h:102-113 dtt_dst
                 # over dtt_src), lazily and shared per (copy, type)

@@ -171,9 +171,9 @@ def data_create(value: Any, device_index: int = 0, key: Any = None,
 
 
 def scratch_copy(dtt: TileType) -> DataCopy:
-    """A fresh zeroed tile of the declared type — THE scratch allocation
-    policy, shared by ``prepare_input`` (WRITE-only/NEW flows) and the
-    compiled-DAG path so the two incarnations can never diverge."""
+    """A fresh zeroed tile of the declared type — the scratch allocation
+    policy of ``prepare_input`` (WRITE-only/NEW flows), which the lowering
+    mirrors."""
     import numpy as np
     d = data_create(np.zeros(dtt.shape, dtype=dtt.dtype), dtt=dtt)
     return d.get_copy(0)

@@ -94,9 +94,9 @@ class Device:
             task.selected_device = None
             self.load_add(-task_load(task, self))
 
-    def note_executed(self, n: int = 1) -> None:
+    def note_executed(self) -> None:
         with self._lock:
-            self.executed_tasks += n
+            self.executed_tasks += 1
 
     def taskpool_register(self, taskpool: Any) -> None:
         """Hook for per-taskpool device state (kernel resolution etc.)."""

@@ -90,13 +90,6 @@ _params.register("llm_steps_per_pool", 8,
 # moves in powers of two; past ~32 the step-timeout and per-stream
 # budget clipping dominate, so the search never wanders further
 _params.declare_knob("llm_steps_per_pool", lo=1, hi=32, scale="log2")
-_params.register("llm_compiled_pools", True,
-                 "submit decode superpools through the funneled "
-                 "compiled-DAG executor (runtime/dagrun.py, PR 2's "
-                 "native select->release loop) instead of the dynamic "
-                 "scheduler: lowest per-task overhead, at the cost of "
-                 "task-grain WFQ interleaving WITHIN a pool (tenant "
-                 "fairness still applies across pools)")
 _params.register("llm_lower_regions", False,
                  "region-lower each decode superpool (ptg.lowering."
                  "lower_regions) before submission: per-step XLA "
@@ -1092,8 +1085,7 @@ class ContinuousBatcher:
                 tp = self._maybe_lower_regions(tp)
                 submitted.append((self._server.submit(
                     tp, tenant=tenant,
-                    priority=max(st.priority for st in group),
-                    compiled=bool(_params.get("llm_compiled_pools"))),
+                    priority=max(st.priority for st in group)),
                     tp, group))
                 with self._lock:
                     self.decode_submits += 1

@@ -63,7 +63,6 @@ _params.register("prof_snapshot_interval", 0.0,
 _now = time.perf_counter_ns
 _N_EVENTS = max(int(e) for e in PinsEvent) + 1
 _SB, _SE = PinsEvent.SELECT_BEGIN, PinsEvent.SELECT_END
-_DFB, _DFE = PinsEvent.DAG_FETCH_BEGIN, PinsEvent.DAG_FETCH_END
 _CEB, _CEE = PinsEvent.COMPLETE_EXEC_BEGIN, PinsEvent.COMPLETE_EXEC_END
 # the begin/end pairs inside a completion: its COMPLETE_EXEC_BEGIN record
 # already names the task they are about, so they never reach the recorder
@@ -234,15 +233,8 @@ class FlightRecorder:
                 r.idle += 1
                 r.idle_ns = _now()
                 return
-            if event is _SB or event is _DFB:
-                return        # info-free begins: the END record suffices
-        elif event is _DFE and payload == 0:
-            # an empty compiled-DAG fetch: the AGAIN-spin analog of an
-            # empty select — liveness tick, not ring spam (a wedged DAG
-            # must not flush its own pre-stall history)
-            r.idle += 1
-            r.idle_ns = _now()
-            return
+            if event is _SB:
+                return        # info-free begin: the END record suffices
         r.counts[event] += 1
         if type(payload) is int:
             r.vsums[event] += payload
@@ -344,8 +336,7 @@ class MetricsSnapshotter:
             s["props"][ns] = {k: v for k, v in d.items() if k != "sde"}
         if recorder is not None:
             counts, vsums = recorder.aggregate()
-            s["tasks_retired"] = (counts[PinsEvent.COMPLETE_EXEC_END]
-                                  + vsums[PinsEvent.DAG_COMPLETE_END])
+            s["tasks_retired"] = counts[PinsEvent.COMPLETE_EXEC_END]
         with self._lock:
             self.series.append(s)
             if len(self.series) > self.MAX_SAMPLES:
@@ -452,8 +443,7 @@ def build_stall_report(context: Any = None, reason: str = "",
             lambda: context.scheduler.queue_depths(context))
         report["active_taskpools"] = _best_effort(lambda: [
             {"name": tp.name,
-             "nb_tasks": tp.tdm.nb_tasks if tp.tdm is not None else None,
-             "compiled_dag": getattr(tp, "_compiled_dag", None) is not None}
+             "nb_tasks": tp.tdm.nb_tasks if tp.tdm is not None else None}
             for tp in list(context._active_taskpools)])
         ce = context.comm_engine
         if ce is not None and hasattr(ce, "debug_state"):
@@ -531,12 +521,11 @@ def stall_dump(context: Any = None, reason: str = "", last: int = 32,
 def runtime_report(max_workers: int = 6) -> dict:
     """Compact runtime self-measurement (cumulative since process start).
 
-    ``tasks_retired`` is the TOTAL (dynamic + compiled-DAG), matching the
-    snapshotter's counter track so the two halves of one run report can
-    never contradict each other; the per-path components ride alongside.
+    ``tasks_retired`` counts completions (``COMPLETE_EXEC_END``), as the
+    snapshotter's counter track does, so the two halves of one run report
+    can never contradict each other.
     """
-    rep: dict[str, Any] = {"tasks_retired": 0, "dynamic_tasks_retired": 0,
-                           "dag_tasks_completed": 0,
+    rep: dict[str, Any] = {"tasks_retired": 0,
                            "h2d_bytes": 0, "comm_activations_sent": 0,
                            "snapshots": len(snapshotter.series),
                            "workers": {}}
@@ -590,10 +579,7 @@ def runtime_report(max_workers: int = 6) -> dict:
         rep["flightrec"] = "disabled"
         return rep
     counts, vsums = r.aggregate()
-    rep["dynamic_tasks_retired"] = counts[PinsEvent.COMPLETE_EXEC_END]
-    rep["dag_tasks_completed"] = vsums[PinsEvent.DAG_COMPLETE_END]
-    rep["tasks_retired"] = (rep["dynamic_tasks_retired"]
-                            + rep["dag_tasks_completed"])
+    rep["tasks_retired"] = counts[PinsEvent.COMPLETE_EXEC_END]
     # what the recorder costs a task, in ring writes: reckoned here from
     # the rings' totals, nothing counted on the hot path for it
     rep["notes_per_task_retired"] = (

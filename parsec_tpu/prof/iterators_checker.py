@@ -47,7 +47,7 @@ def check_task(task: Any) -> int:
         for succ_locals in dep.each_target(t.locals):
             try:
                 _find_input_dep(succ_tc, dep.flow_name(t.locals),
-                                tc.name, succ_locals)
+                                tc.name, succ_locals, t.locals)
             except (KeyError, LookupError) as e:
                 raise IteratorsCheckerError(
                     f"{t}: arrow to {dep.target_class}({succ_locals})."

@@ -54,12 +54,8 @@ class PinsEvent(IntEnum):
     DATA_FLUSH_END = 15
     TASKPOOL_INIT = 16
     TASKPOOL_FINI = 17
-    # compiled-DAG executor batch spans (payload: batch size) — the fast
-    # path stays observable instead of falling back when PINS is active
-    DAG_FETCH_BEGIN = 18
-    DAG_FETCH_END = 19
-    DAG_COMPLETE_BEGIN = 20
-    DAG_COMPLETE_END = 21
+    # 18-21 are unused: numbers are never reused, so a recorded trace
+    # keeps reading the same events
     # a select that pulled work from beyond the stream's own queue
     # (payload: (task, distance)) — feeds the print_steals module
     SELECT_STEAL = 22
@@ -103,8 +99,8 @@ enabled = False
 # the flight-recorder hook (prof/flight_recorder.py): a callable
 # ``(event, payload) -> None`` or None.  Kept separate from the callback
 # chains so the always-on recorder costs one slot call per site without
-# flipping ``enabled`` (which would tax the compiled executor's per-task
-# instrumentation branches).  Exposed as the assignable ``pins.recorder``
+# flipping ``enabled`` (which would tax the per-task instrumentation
+# branches).  Exposed as the assignable ``pins.recorder``
 # attribute through the module-class property below; a hook with a
 # ``site`` method compiles its own part of each slot (:func:`_recorder_site`).
 _recorder: Callable[[Any, Any], None] | None = None
@@ -154,9 +150,10 @@ def _slot(event: int) -> Callable[[Any, Any], None] | None:
 
 def _rebuild() -> None:
     """Recompile every slot (caller holds ``_lock``, or is single-threaded
-    module init).  In-place assignment keeps the table identity stable."""
-    for i in range(N_EVENTS):
-        hooks[i] = _slot(i)
+    module init).  In-place assignment keeps the table identity stable;
+    an unused number's slot stays None."""
+    for e in PinsEvent:
+        hooks[e] = _slot(int(e))
 
 
 def set_recorder(value: Callable[[Any, Any], None] | None) -> None:

@@ -65,32 +65,6 @@ class TaskProfilerModule:
                 pins.register(event, cb)
                 self._cbs.append((event, cb))
 
-        # compiled-DAG batch spans: the fast path's fetch/complete phases
-        # (payload = batch size, not a task) — making the native executor's
-        # hot loop visible in the same trace
-        for phase, (b, e), color in (
-                ("dag_fetch", (PinsEvent.DAG_FETCH_BEGIN,
-                               PinsEvent.DAG_FETCH_END), "#00cccc"),
-                ("dag_complete", (PinsEvent.DAG_COMPLETE_BEGIN,
-                                  PinsEvent.DAG_COMPLETE_END), "#cc00cc")):
-            self._keys[phase] = profiling.add_dictionary_keyword(
-                phase, color, ("batch",))
-
-            def mk_batch(phase, start):
-                key_pair = self._keys[phase]
-
-                def cb(es, payload):
-                    info = ({"batch": int(payload)}
-                            if isinstance(payload, int) else None)
-                    profiling.trace(key_pair[0 if start else 1],
-                                    event_id=0, object_id=0, info=info)
-                return cb
-
-            for start, event in ((True, b), (False, e)):
-                cb = mk_batch(phase, start)
-                pins.register(event, cb)
-                self._cbs.append((event, cb))
-
     def uninstall(self) -> None:
         for event, cb in self._cbs:
             pins.unregister(event, cb)

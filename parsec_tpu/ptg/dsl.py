@@ -283,11 +283,6 @@ class TaskClassBuilder:
             rc = f(es, task, g_ns(), _ns(task.locals))
             return HOOK_RETURN_DONE if rc is None else rc
 
-        # the compiled-DAG executor (runtime/dagrun.py) bypasses this
-        # wrapper and calls the body directly with a namespace it builds
-        # once per task — the unwrap halves the per-task Python layers
-        hook.ptg_body = f
-        hook.ptg_gns = g_ns
         return hook
 
     # -- helpers ------------------------------------------------------------

@@ -24,7 +24,6 @@ from typing import Any, Callable
 from ..core.params import params as _params
 from ..core.backoff import Backoff
 from ..core.mca import repository
-from ..device.device import cpu_device as _cpu_device
 from ..prof import pins, spans
 from ..prof.pins import PinsEvent
 from .deps import DependencyTracking
@@ -306,40 +305,28 @@ class Context:
                 self.taskpool_list.append(tp)
                 self._next_comm_id += 1
                 tp.comm_id = self._next_comm_id
-                self._tp_by_comm_id[tp.comm_id] = tp
         if tp.on_enqueue is not None:
             tp.on_enqueue(tp)
-        # compiled-DAG incarnation: enumerable single-rank PTG pools skip the
-        # scheduler entirely (dagrun.py — the scheduling.c:562 loop, native)
-        from .dagrun import compile_taskpool_dag
-        dag = compile_taskpool_dag(tp, self)
-        if dag is not None:
-            # account BEFORE publishing: an idle worker may claim and finish
-            # the dag the instant _compiled_dag is visible, and its -ntasks
-            # must not land on a zero counter
-            tp.tdm.taskpool_addto_nb_tasks(dag.ntasks)
-            tp.tdm.ready()
-            tp._compiled_dag = dag
-            if self.comm_engine is not None and not local_only:
-                self.comm_engine.taskpool_registered(tp)
-            with self._cond:
-                self._cond.notify_all()   # wake a mid-wait driving thread
-            return
         n = tp.nb_local_tasks()
         if n >= 0:
             tp.tdm.taskpool_addto_nb_tasks(n)
         startup = tp.startup(self)
         tp.tdm.ready()
-        if self.comm_engine is not None and not local_only:
-            self.comm_engine.taskpool_registered(tp)
+        if not local_only:
+            # found by comm id only once its tasks are counted: an activation
+            # that arrived earlier waits for taskpool_registered's replay, or
+            # the task it releases would complete on a zero counter
+            with self._lock:
+                self._tp_by_comm_id[tp.comm_id] = tp
+            if self.comm_engine is not None:
+                self.comm_engine.taskpool_registered(tp)
         if startup:
             schedule_tasks(self._submit_es, list(startup), 0)
 
     def record_failure(self, e: BaseException) -> None:
         """Record a fatal background/driver failure (first one wins) and
         wake every waiter — the one locked path all recording sites share
-        (worker threads, the comm thread, compiled-DAG drivers, the
-        caller-driven loop)."""
+        (worker threads, the comm thread, the caller-driven loop)."""
         with self._lock:
             if self._worker_error is None:
                 self._worker_error = e
@@ -556,9 +543,6 @@ class Context:
             try:
                 task, distance = select_task(es)
                 if task is None:
-                    # idle worker: claim a compiled-DAG pool if one waits
-                    # (keeps start()+test()-polling callers progressing)
-                    self._run_compiled_dags(es)
                     if self.comm_engine is not None and es.th_id == 0:
                         self.comm_engine.progress(es)
                     backoff.wait()
@@ -600,7 +584,6 @@ class Context:
         deadline = None if timeout is None else time.monotonic() + timeout
         if self._threads:
             while True:
-                self._run_compiled_dags(deadline=deadline)
                 with self._cond:
                     if self._worker_error is not None:
                         raise RuntimeError(
@@ -612,12 +595,10 @@ class Context:
                     if rem is not None and rem <= 0:
                         raise ContextWaitTimeout(
                             "context wait timed out; " + self._live_desc())
-                    # wake on termination, worker error, or a freshly
-                    # enqueued compiled-DAG pool needing this driver
+                    # wake on termination or a worker error
                     ok = self._cond.wait_for(
                         lambda: predicate()
-                        or self._worker_error is not None
-                        or self._has_pending_dag(), rem)
+                        or self._worker_error is not None, rem)
                     if not ok:
                         raise ContextWaitTimeout(
                             "context wait timed out; " + self._live_desc())
@@ -628,7 +609,6 @@ class Context:
                       deadline: float | None) -> None:
         """The hot loop on the calling thread (master-thread funneled
         mode), until ``predicate`` holds."""
-        self._run_compiled_dags(deadline=deadline)
         es = self._submit_es
         es.owner_ident = threading.get_ident()
         backoff = Backoff()
@@ -644,8 +624,6 @@ class Context:
             try:
                 task, distance = select_task(es)
                 if task is None:
-                    # pools enqueued mid-drive
-                    self._run_compiled_dags(deadline=deadline)
                     if self.comm_engine is not None:
                         self.comm_engine.progress(es)
                     if predicate():
@@ -672,55 +650,6 @@ class Context:
                 # complete
                 self.record_failure(e)
                 raise
-
-    def _has_pending_dag(self) -> bool:
-        """A compiled pool still waiting for a driver (claimed-and-running
-        pools don't count: their driver will notify on completion).  Binds
-        each dag once: a driver may null ``_compiled_dag`` concurrently."""
-        return any(dag is not None and dag.pending
-                   for dag in (getattr(tp, "_compiled_dag", None)
-                               for tp in self._active_taskpools))
-
-    def _run_compiled_dags(self, es: Any = None,
-                           deadline: float | None = None) -> None:
-        """Drive any compiled-DAG taskpools to completion from this thread.
-
-        Compiled pools are funneled: one thread (the waiter, or an idle
-        worker) claims the pool and runs the fetch/execute/complete loop —
-        the master-thread progress path, with select/release native
-        (dagrun.py).  Python bodies hold the GIL, so a single driver loses
-        nothing over the worker pool.  A ``deadline`` expiry leaves the pool
-        unclaimed and resumable and raises TimeoutError."""
-        with self._lock:
-            pending = [tp for tp in self._active_taskpools
-                       if getattr(tp, "_compiled_dag", None) is not None]
-        for tp in pending:
-            dag = getattr(tp, "_compiled_dag", None)
-            if dag is None or not dag.claim():
-                continue
-            try:
-                finished = dag.run(
-                    es if es is not None else self._submit_es, deadline)
-            except BaseException as e:
-                # record the failure BEFORE terminating the pool: a waiter
-                # woken by the termination must see the error, not success
-                self.record_failure(e)
-                tp._compiled_dag = None
-                tp.tdm.taskpool_addto_nb_tasks(-dag.ntasks)
-                raise
-            if not finished:
-                # dag.run yielded: deadline expiry, or an all-AGAIN pass
-                # waiting on another pool's progress.  The pool stays
-                # pending and resumable either way.
-                if deadline is not None and time.monotonic() > deadline:
-                    raise ContextWaitTimeout(
-                        "context wait timed out; " + self._live_desc())
-                continue
-            tp._compiled_dag = None
-            tp.tdm.taskpool_addto_nb_tasks(-dag.ntasks)
-            # compiled pools are single-CPU-chore by construction and
-            # bypass execute_task: account their bodies here
-            _cpu_device.note_executed(dag.ntasks)
 
     # ----------------------------------------------------------- internals
     def _taskpool_terminated(self, tp: Taskpool) -> None:

@@ -297,13 +297,32 @@ def prepare_input(es: ExecutionStream, task: Task) -> None:
                     f"with unresolved input {type(v).__name__}")
 
 
+def _names(d: Any, succ_locals: Any, src_locals: dict) -> bool:
+    """Whether input dep ``d``, evaluated on the successor's locals, names
+    the predecessor whose locals are ``src_locals`` (one instance of a
+    ranged dep's range is enough)."""
+    for p in d.each_target(succ_locals):
+        if all(src_locals.get(k) == v for k, v in p.items()):
+            return True
+    return False
+
+
 def _find_input_dep(succ_tc: TaskClass, flow_name: str, src_class: str,
-                    succ_locals: dict) -> tuple[int, int]:
+                    succ_locals: dict, src_locals: dict) -> tuple[int, int]:
+    """The input dep of ``succ_tc.flow_name`` that an edge from the
+    ``src_class`` instance with ``src_locals`` satisfies: the first from
+    that class whose guard holds and, where the flow has several deps from
+    it (Ex07's ``Update.ctl``, one per reader), whose predecessor params
+    name that instance."""
     for f in succ_tc.flows:
         if f.name != flow_name:
             continue
-        for di, d in enumerate(f.deps_in):
-            if d.target_class == src_class and d.active(succ_locals):
+        feeds = [(di, d) for di, d in enumerate(f.deps_in)
+                 if d.target_class == src_class]
+        several = len(feeds) > 1
+        for di, d in feeds:
+            if d.active(succ_locals) and (
+                    not several or _names(d, succ_locals, src_locals)):
                 return f.flow_index, di
         raise LookupError(
             f"{succ_tc.name}.{flow_name}: no active input dep from {src_class}")
@@ -378,7 +397,11 @@ class _EdgePlan:
     its ``in_space`` and ``locals_view``, the index of the successor flow
     and, of that flow's input deps, the ones this class feeds (``(mask bit,
     guard)`` in declaration order: the first whose guard holds for the
-    successor's locals is the dep the edge satisfies).
+    successor's locals is the dep the edge satisfies).  Where the flow has
+    several deps from this class, a guard alone does not tell them apart:
+    ``cands`` is then empty and ``named`` holds them as ``(mask bit,
+    Dep)``, for the walk to pick the one whose predecessor params name the
+    releasing task (:func:`_names`).
 
     ``planned`` says the edge needs nothing beyond that.  It is False, and
     the walk derives per edge what the plan could not state ahead (the
@@ -389,7 +412,7 @@ class _EdgePlan:
     """
 
     __slots__ = ("flow", "dep", "guard", "ctl", "flow_index", "succ_tc",
-                 "in_space", "view", "succ_fi", "cands", "planned")
+                 "in_space", "view", "succ_fi", "cands", "named", "planned")
 
 
 def _plan_edge(tp: Any, tc: TaskClass, flow: Any, dep: Any) -> _EdgePlan:
@@ -397,10 +420,10 @@ def _plan_edge(tp: Any, tc: TaskClass, flow: Any, dep: Any) -> _EdgePlan:
     ep.flow, ep.dep, ep.guard = flow, dep, dep.guard
     ep.ctl, ep.flow_index = flow.is_ctl, flow.flow_index
     ep.succ_tc = ep.in_space = ep.view = ep.succ_fi = None
-    ep.cands = ()
+    ep.cands = ep.named = ()
     # rank-local, unsimulated pools resolve ahead; the others ask per edge
     # where the successor (or the home tile) lives and when it was ready
-    ep.planned = tp.context.nb_ranks <= 1 and not tp.sim_enabled
+    ep.planned = _rank_local(tp) and not tp.sim_enabled
     if dep.target_class is None:
         return ep               # a memory edge: the write-back
     succ = ep.succ_tc = tp.task_class(dep.target_class)
@@ -411,8 +434,11 @@ def _plan_edge(tp: Any, tc: TaskClass, flow: Any, dep: Any) -> _EdgePlan:
         if d.target_class == tc.name]
     if feeds:
         ep.succ_fi = sflow.flow_index
-        ep.cands = tuple((1 << succ.dep_bit(sflow.flow_index, di), d.guard)
-                         for di, d in feeds)
+        bits = [1 << succ.dep_bit(sflow.flow_index, di) for di, _ in feeds]
+        if len(feeds) == 1:
+            ep.cands = ((bits[0], feeds[0][1].guard),)
+        else:
+            ep.named = tuple(zip(bits, (d for _, d in feeds)))
     typed = dep.dtt is not None or any(d.dtt is not None for _, d in feeds)
     if not feeds or typed or succ.find_deps_fn is not None \
             or succ.make_key_fn is not None:
@@ -487,7 +513,7 @@ def release_deps(es: ExecutionStream, task: Task) -> None:
         succ_tc = ep.succ_tc
         if succ_tc is None:
             if not planned:
-                home_rank = _rank_of_data(ctx, dep, tv)
+                home_rank = _rank_of_data(tp, dep, tv)
                 if home_rank is not None and home_rank != ctx.my_rank:
                     # home tile lives on another rank: ship the final
                     # version (the remote write-back path of
@@ -512,12 +538,16 @@ def release_deps(es: ExecutionStream, task: Task) -> None:
                     if active is None or active(sv):
                         break
                 else:
-                    raise LookupError(
-                        f"{succ_tc.name}.{dep.target_flow}: no active "
-                        f"input dep from {tc.name}")
+                    for bit, d in ep.named:
+                        if d.active(sv) and _names(d, sv, task.locals):
+                            break
+                    else:
+                        raise LookupError(
+                            f"{succ_tc.name}.{dep.target_flow}: no active "
+                            f"input dep from {tc.name}")
                 nplanned += 1
             else:
-                rank = _rank_of_task(ctx, succ_tc, sv)
+                rank = _rank_of_task(tp, succ_tc, sv)
                 if rank is not None and rank != ctx.my_rank:
                     remote = ctx.remote_dep_accumulate(
                         remote, task, flow, dep, succ_tc, succ_locals, rank)
@@ -533,7 +563,7 @@ def release_deps(es: ExecutionStream, task: Task) -> None:
                         if task.sim_exec_date > tp._sim_ready.get(skey, 0.0):
                             tp._sim_ready[skey] = task.sim_exec_date
                 fi, di = _find_input_dep(succ_tc, dep.flow_name(tv),
-                                         tc.name, sv)
+                                         tc.name, sv, task.locals)
                 bit = 0 if succ_tc.counted else 1 << succ_tc.dep_bit(fi, di)
             send = out_copy
             if out_copy is not None:
@@ -628,8 +658,15 @@ def apply_writeback_to_home(dc, key: tuple, out_copy,
     home.version = max(home.version, out_copy.version) + 1
 
 
-def _rank_of_task(ctx, tc: TaskClass, locals_: dict):
-    if ctx.nb_ranks <= 1 or tc.affinity is None:
+def _rank_local(tp: Any) -> bool:
+    """Whether every task and tile of ``tp`` is this rank's: a context of
+    one rank, or a rank-private pool (``local_only``: the nested pools of
+    recursive bodies, over collections that name no rank of their own)."""
+    return tp.context.nb_ranks <= 1 or tp.local_only
+
+
+def _rank_of_task(tp: Any, tc: TaskClass, locals_: dict):
+    if _rank_local(tp) or tc.affinity is None:
         return None
     dc, key = tc.affinity(locals_)
     if not isinstance(key, tuple):
@@ -637,8 +674,8 @@ def _rank_of_task(ctx, tc: TaskClass, locals_: dict):
     return dc.rank_of(*key)
 
 
-def _rank_of_data(ctx, dep, locals_: dict):
-    if ctx.nb_ranks <= 1 or dep.data_ref is None:
+def _rank_of_data(tp: Any, dep, locals_: dict):
+    if _rank_local(tp) or dep.data_ref is None:
         return None
     dc, key = dep.data_ref(locals_)
     return dc.rank_of(*key)

@@ -253,7 +253,7 @@ class RuntimeServer:
     # -- submission ------------------------------------------------------
     def submit(self, tp: Taskpool, *, tenant: str = "default",
                priority: int = 0, deadline: float | None = None,
-               block: bool = True, compiled: bool = False,
+               block: bool = True,
                result_fn: Callable[[Taskpool], Any] | None = None
                ) -> Ticket:
         """Submit one taskpool; returns its :class:`Ticket`.
@@ -267,11 +267,8 @@ class RuntimeServer:
         computes the ticket's value at completion (default: the taskpool
         itself — read your collections off it).
 
-        Served pools run the DYNAMIC scheduler path by default so the
-        weighted-fair shim interleaves tenants at task grain;
-        ``compiled=True`` opts back into the funneled compiled-DAG
-        executor (lowest per-task overhead, but the whole pool dispatches
-        as one fairness-opaque unit)."""
+        Served pools run the dynamic scheduler, so the weighted-fair shim
+        interleaves tenants at task grain."""
         deadline_at = None if deadline is None \
             else time.monotonic() + deadline
         ticket = Ticket(self, tp.name, tenant, priority, deadline_at)
@@ -315,8 +312,6 @@ class RuntimeServer:
         sub = _Submission(tenant, priority, deadline_at, cost, ticket,
                           result_fn)
         tp._serve_sub = sub
-        if not compiled:
-            tp._serve_no_dag = True     # dagrun.compile_taskpool_dag gate
         # check-and-register atomically: a drain that began while this
         # thread sat inside admit() must either see the ticket in flight
         # (and wait for it) or shed it here — never tear the context down

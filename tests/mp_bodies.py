@@ -198,23 +198,16 @@ def traced_chain_body(ctx, rank, nranks):
     multi-file dbp / per-rank .dot inputs the offline tools consume)."""
     import os
 
-    import parsec_tpu.runtime.dagrun  # noqa: F401  registers the param
     from parsec_tpu.core.mca import repository
-    from parsec_tpu.core.params import params
     from parsec_tpu.prof.profiling import profiling
 
     out_dir = os.environ["PARSEC_TEST_TRACE_DIR"]
-    old = params.get("runtime_dag_compile")
-    params.set("runtime_dag_compile", False)   # dynamic loop: full PINS
     profiling.init()
     prof_comp = repository.find("pins", "task_profiler")
     prof_mod = prof_comp.open()
     graph_comp = repository.find("pins", "grapher")
     graph_mod = graph_comp.open()
-    try:
-        chain_body(ctx, rank, nranks)
-    finally:
-        params.set("runtime_dag_compile", old)
+    chain_body(ctx, rank, nranks)
     graph_mod.write_dot(os.path.join(out_dir, f"rank{rank}.dot"))
     graph_comp.close(graph_mod)
     profiling.dump(os.path.join(out_dir, f"rank{rank}.prof"))

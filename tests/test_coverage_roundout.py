@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 from parsec_tpu import ptg
-import parsec_tpu.runtime.dagrun  # noqa: F401  (registers runtime_dag_compile)
 from parsec_tpu.core.params import params
 from parsec_tpu.core.rwlock import RWLock
 from parsec_tpu.data.data import TileType
@@ -136,7 +135,6 @@ class TestDebugMarks:
     def test_ring_captures_events(self, param):
         from parsec_tpu.core.mca import repository
         from parsec_tpu.prof import debug_marks
-        param("runtime_dag_compile", False)   # marks watch the full loop
         comp = repository.find("pins", "debug_marks")
         mod = comp.open()   # install re-creates the module-level ring
         ring = debug_marks.ring
@@ -267,7 +265,6 @@ class TestParanoid:
 
     def test_normal_run_clean_under_paranoid(self, param):
         param("debug_paranoid", True)
-        param("runtime_dag_compile", False)   # exercise the dynamic path
         trace = []
         ctx = Context(nb_cores=0)
         ctx.add_taskpool(_small_pool(trace))
@@ -281,7 +278,6 @@ class TestThreadBinding:
         """runtime_bind_threads pins workers round-robin (best-effort);
         the run must complete and execute every task either way."""
         param("runtime_bind_threads", True)
-        param("runtime_dag_compile", False)
         trace = []
         ctx = Context(nb_cores=2)
         ctx.add_taskpool(_small_pool(trace))

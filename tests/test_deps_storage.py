@@ -10,8 +10,6 @@ import pytest
 from parsec_tpu import ptg
 from parsec_tpu.runtime import Context
 
-import parsec_tpu.runtime.dagrun  # noqa: F401  registers runtime_dag_compile
-
 
 def _ep_pool(NT=40, DEPTH=25):
     p = ptg.PTGBuilder("ep", NT=NT, DEPTH=DEPTH)
@@ -32,7 +30,6 @@ def _drain_ep(param, storage, native, NT=40, DEPTH=25):
     entries its hashed table was handed."""
     param("deps_storage", storage)
     param("runtime_native", native)
-    param("runtime_dag_compile", False)   # exercise release_dep itself
     ctx = Context(nb_cores=0)
     inserts = []
     insert = ctx.deps._table.insert
@@ -45,7 +42,6 @@ def _drain_ep(param, storage, native, NT=40, DEPTH=25):
 
 def test_index_array_tier_selected_for_static_boxes(param):
     param("deps_storage", "index-array")
-    param("runtime_dag_compile", False)
     ctx = Context(nb_cores=0)
     assert ctx.deps._index_store is not None
     tp = _ep_pool(8, 6)
@@ -105,7 +101,6 @@ def test_triangular_space_falls_back_cleanly(param):
     """A class whose ranges depend on earlier params has no static box:
     the index-array tier must fall back to the hashed tier, silently."""
     param("deps_storage", "index-array")
-    param("runtime_dag_compile", False)
     done = []
     p = ptg.PTGBuilder("tri", N=6)
     t = p.task("T",
@@ -132,7 +127,6 @@ def test_oversized_static_box_falls_back_to_hashed_tier(param):
     space) — the class silently takes the hashed tier instead."""
     param("deps_storage", "index-array")
     param("deps_index_array_max_slots", 16)   # force the guard
-    param("runtime_dag_compile", False)
     ctx = Context(nb_cores=0)
     store = ctx.deps._index_store
     assert store is not None

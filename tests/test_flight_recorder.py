@@ -13,7 +13,6 @@ import tracemalloc
 import pytest
 
 from parsec_tpu import ptg
-import parsec_tpu.runtime.dagrun  # noqa: F401 — registers runtime_dag_compile
 from parsec_tpu.core.params import params  # noqa: F401 — param registry
 from parsec_tpu.prof import (export_run_report, flight_recorder, pins,
                              runtime_report, trace_state)
@@ -50,29 +49,25 @@ def test_ring_wraparound_keeps_last_n(fresh_recorder):
 def test_counts_survive_wraparound_and_sum_payloads(fresh_recorder):
     for i in range(30):
         pins.fire(PinsEvent.COMPLETE_EXEC_END, None, None)
-    pins.fire(PinsEvent.DAG_COMPLETE_END, None, 1000)
-    pins.fire(PinsEvent.DAG_COMPLETE_END, None, 24)
+    pins.fire(PinsEvent.DEVICE_STAGE_IN, None, 1000)
+    pins.fire(PinsEvent.DEVICE_STAGE_IN, None, 24)
     counts, vsums = fresh_recorder.aggregate()
     assert counts[PinsEvent.COMPLETE_EXEC_END] == 30
-    assert vsums[PinsEvent.DAG_COMPLETE_END] == 1024
+    assert vsums[PinsEvent.DEVICE_STAGE_IN] == 1024
     rep = runtime_report()
-    assert rep["dynamic_tasks_retired"] == 30
-    assert rep["dag_tasks_completed"] == 1024
-    assert rep["tasks_retired"] == 1054   # total = the snapshotter's meaning
+    assert rep["tasks_retired"] == 30     # the snapshotter's meaning
+    assert rep["h2d_bytes"] == 1024
 
 
 def test_idle_selects_become_liveness_ticks_not_ring_spam(fresh_recorder):
     pins.fire(PinsEvent.EXEC_BEGIN, None, 7)
-    for _ in range(500):                      # an idle-polling worker
+    for _ in range(600):                      # an idle-polling worker
         pins.fire(PinsEvent.SELECT_BEGIN, None, None)
         pins.fire(PinsEvent.SELECT_END, None, None)   # no task: empty
-    for _ in range(100):                      # a wedged compiled DAG
-        pins.fire(PinsEvent.DAG_FETCH_BEGIN, None, None)
-        pins.fire(PinsEvent.DAG_FETCH_END, None, 0)   # empty fetch
     ring = fresh_recorder.snapshot()[threading.current_thread().name]
     assert ring["total"] == 1                 # real history not rotated out
     assert ring["events"][0]["event"] == "EXEC_BEGIN"
-    # only EMPTY selects / fetches tick the idle counter: SELECT_BEGIN is
+    # only EMPTY selects tick the idle counter: SELECT_BEGIN is
     # payload-free even on productive selects and must not count
     assert ring["idle_selects"] == 600
 
@@ -174,8 +169,8 @@ def test_recorder_assignment_retargets_dispatch_slots():
         assert h is not None
         h(None, 42)
         assert seen == [(PinsEvent.EXEC_BEGIN, 42)]
-        pins.fire(PinsEvent.DAG_COMPLETE_END, None, 7)   # fire() same table
-        assert seen[-1] == (PinsEvent.DAG_COMPLETE_END, 7)
+        pins.fire(PinsEvent.DEVICE_STAGE_IN, None, 7)   # fire() same table
+        assert seen[-1] == (PinsEvent.DEVICE_STAGE_IN, 7)
     finally:
         pins.recorder = old_rec
     assert pins.hooks is table_before
@@ -220,7 +215,6 @@ def test_wait_timeout_raises_typed_and_dumps(tmp_path, param, capsys):
     produces a ContextWaitTimeout (caught by TYPE, not message text) and
     a stall dump naming every worker's last event and the queue depths,
     serialized to stderr and the flightrec-<rank>.json artifact."""
-    param("runtime_dag_compile", False)   # dynamic path: per-task PINS
     param("prof_flightrec_dir", str(tmp_path))
     ev = threading.Event()
     ctx = Context(nb_cores=2)
@@ -261,7 +255,6 @@ def test_fini_bounded_drain_aborts_instead_of_hanging(tmp_path, param):
     """fini(timeout=...) on a wedged pool falls through to abort-style
     teardown within the bound instead of blocking forever (ADVICE r5:
     a caller's 'finally: ctx.fini()' hung in exactly this case)."""
-    param("runtime_dag_compile", False)
     param("prof_flightrec_dir", str(tmp_path))
     ev = threading.Event()
     ctx = Context(nb_cores=1)
@@ -279,7 +272,6 @@ def test_fini_bounded_drain_aborts_instead_of_hanging(tmp_path, param):
 def test_fini_after_timed_out_wait_dumps_only_once(tmp_path, param, capsys):
     """bench's 'finally: ctx.fini(expired)' after a timed-out wait must
     not produce a second dump — one diagnosis per stall."""
-    param("runtime_dag_compile", False)
     param("prof_flightrec_dir", str(tmp_path))
     ev = threading.Event()
     ctx = Context(nb_cores=1)
@@ -292,7 +284,6 @@ def test_fini_after_timed_out_wait_dumps_only_once(tmp_path, param, capsys):
 
 
 def test_wait_timeout_dump_can_be_disabled(param):
-    param("runtime_dag_compile", False)
     param("prof_stall_dump", False)
     ev = threading.Event()
     ctx = Context(nb_cores=1)
@@ -312,7 +303,6 @@ def test_wait_timeout_dump_can_be_disabled(param):
 # ---------------------------------------------------------------------------
 
 def test_snapshotter_samples_counters_and_props(param):
-    param("runtime_dag_compile", False)
     param("prof_snapshot_interval", 0.03)
     snap = flight_recorder.snapshotter
     before = len(snap.series)
@@ -341,7 +331,6 @@ def test_export_run_report_roundtrip_chrome(tmp_path, param):
     """Flight-recorder events, counter series, and Profiling streams all
     land in ONE chrome trace that round-trips through JSON."""
     from parsec_tpu.core.mca import repository
-    param("runtime_dag_compile", False)
     trace_state.init()
     comp = repository.find("pins", "task_profiler")
     mod = comp.open()
@@ -446,7 +435,7 @@ def test_a_completion_writes_one_record(front, accel_device, roomy_recorder):
     assert len({e["task"] for e in done}) == ntasks
     assert all(isinstance(e["task"], int) and e["info"] for e in done)
     rep = runtime_report()
-    assert rep["dynamic_tasks_retired"] == ntasks
+    assert rep["tasks_retired"] == ntasks
     assert rep["notes_per_task_retired"] == round(
         roomy_recorder.writes() / rep["tasks_retired"], 3)
     assert rep["notes_per_task_retired"] == round(
@@ -550,7 +539,7 @@ def test_chains_on_release_and_schedule_see_every_event(
     assert len(released) == len(set(released)) == ntasks
     # the startup batch, then one a completion that readied GEMM(m, n, k+1)
     assert len(scheduled) == len(calls) == 1 + ntasks - 4 * 4
-    assert runtime_report()["dynamic_tasks_retired"] == ntasks
+    assert runtime_report()["tasks_retired"] == ntasks
 
 
 def test_runtime_report_is_json_serializable_and_compact():

@@ -56,26 +56,6 @@ def test_lifo_threaded_stress():
     assert len(lifo) == 0
 
 
-def test_deque_two_ended():
-    dq = native.NativeDeque()
-    dq.push_back(1)
-    dq.push_back(2)
-    dq.push_front(0)
-    assert len(dq) == 3
-    assert dq.pop_front() == 0
-    assert dq.pop_back() == 2
-    assert dq.pop_front() == 1
-    assert dq.pop_front() is None
-
-
-def test_heap_priority_order():
-    h = native.NativeHeap()
-    for prio, v in [(1, 10), (5, 50), (3, 30)]:
-        h.push(prio, v)
-    assert [h.pop(), h.pop(), h.pop()] == [50, 30, 10]
-    assert h.pop() is None
-
-
 def test_deptable_mask_protocol():
     t = native.NativeDepTable(64)
     assert not t.release(7, 0b001, 0b111)
@@ -136,13 +116,6 @@ def test_deptable_threaded_stress():
         th.join()
     assert sum(ready_counts) == NKEYS       # each key ready exactly once
     assert len(t) == 0
-
-
-def test_counter():
-    c = native.NativeCounter(2)
-    assert c.add(-1) == 1
-    assert c.add(-1) == 0
-    assert c.get() == 0
 
 
 def test_pack_key64_is_exact_or_refused():

@@ -6,11 +6,11 @@ of one run read two ways, the ledger's verdicts.  No assertion compares a
 wall-clock reading, or a ratio of two, with a constant: a speed is a
 median on a named device (``PERF_LEDGER.jsonl``, the root ``PERF.md``),
 never a tier-1 gate.  The cases that used to read a clock here live where
-their workload is built: ``test_dagrun.py`` (the compiled dispatch path),
-``test_release_batching.py`` (dep release), ``test_ready_queue.py`` (pop
-and steal order), ``test_serve.py``, ``test_llm.py``, ``test_llm_spec.py``,
-``test_llm_prefix.py``, ``test_lowering.py``, ``test_lowering_regions.py``,
-``test_tune.py`` and ``test_tracing.py``."""
+their workload is built: ``test_release_batching.py`` (dep release),
+``test_ready_queue.py`` (pop and steal order), ``test_serve.py``,
+``test_llm.py``, ``test_llm_spec.py``, ``test_llm_prefix.py``,
+``test_lowering.py``, ``test_lowering_regions.py``, ``test_tune.py`` and
+``test_tracing.py``."""
 
 import pickle
 import time

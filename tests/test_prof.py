@@ -11,25 +11,11 @@ import numpy as np
 import pytest
 
 from parsec_tpu.core.mca import repository
-from parsec_tpu.core.params import params
 from parsec_tpu.data_dist.matrix import TiledMatrix
 from parsec_tpu.prof.counters import (TASKS_ENABLED, TASKS_RETIRED,
                                       properties, sde)
 from parsec_tpu.prof.profiling import Profiling, profiling
 from parsec_tpu.runtime import Context
-
-import parsec_tpu.runtime.dagrun  # noqa: F401  registers runtime_dag_compile
-
-
-@pytest.fixture
-def dynamic_path():
-    """Full-protocol PINS modules (4-phase trace, grapher, SDE retire
-    counts) observe the DYNAMIC scheduling loop; the compiled-DAG executor
-    emits only EXEC + batch-level DAG spans (see test_compiled_dag_trace)."""
-    old = params.get("runtime_dag_compile")
-    params.set("runtime_dag_compile", False)
-    yield
-    params.set("runtime_dag_compile", old)
 
 
 def _run_small_gemm(nb_cores=2):
@@ -58,7 +44,7 @@ def traced():
     profiling.fini()
 
 
-def test_trace_well_formed_and_converts(tmp_path, traced, dynamic_path):
+def test_trace_well_formed_and_converts(tmp_path, traced):
     _run_small_gemm()
     assert traced.validate() == []
     recs = traced.to_records()
@@ -83,49 +69,6 @@ def test_trace_well_formed_and_converts(tmp_path, traced, dynamic_path):
     assert (df[df["name"] == "task_exec"]["duration_ns"] > 0).all()
     # info values round-trip with their types, not as repr strings
     assert (df[df["name"] == "task_exec"]["info.task"] == "GEMM").all()
-
-
-def test_compiled_dag_trace(tmp_path, traced):
-    """VERDICT r3 #4: the compiled-DAG fast path is observable — an EP DAG
-    run with runtime_dag_compile=True produces per-task exec events plus
-    batch-granular dag_fetch/dag_complete spans, exportable to a Chrome
-    trace."""
-    import json
-
-    from parsec_tpu import ptg
-
-    assert params.get("runtime_dag_compile")
-    NT, DEPTH = 8, 5
-    p = ptg.PTGBuilder("ep", NT=NT, DEPTH=DEPTH)
-    t = p.task("EP",
-               d=ptg.span(0, lambda g, l: g.DEPTH - 1),
-               n=ptg.span(0, lambda g, l: g.NT - 1))
-    f = t.flow("ctl", ptg.CTL)
-    f.input(pred=("EP", "ctl", lambda g, l: {"d": l.d - 1, "n": l.n}),
-            guard=lambda g, l: l.d > 0)
-    f.output(succ=("EP", "ctl", lambda g, l: {"d": l.d + 1, "n": l.n}),
-             guard=lambda g, l: l.d < g.DEPTH - 1)
-    t.body(lambda es, task, g, l: None)
-    tp = p.build()
-    ctx = Context(nb_cores=0)
-    ctx.add_taskpool(tp)
-    ctx.wait(timeout=60)
-    ctx.fini()
-    # the dag_* spans below exist ONLY on the compiled path — their
-    # presence proves the pool compiled despite PINS being active
-    recs = traced.to_records()
-    execs = [r for r in recs if r["name"] == "task_exec"]
-    assert len(execs) == NT * DEPTH
-    assert all(r["info.task"] == "EP" for r in execs)
-    completes = [r for r in recs if r["name"] == "dag_complete"]
-    assert completes
-    assert sum(r["info.batch"] for r in completes) == NT * DEPTH
-    assert {r["name"] for r in recs} >= {"dag_fetch", "dag_complete"}
-
-    trace = traced.to_chrome_trace(str(tmp_path / "ep.json"))
-    names = {e["name"] for e in trace["traceEvents"] if e["ph"] == "X"}
-    assert {"task_exec", "dag_fetch", "dag_complete"} <= names
-    json.load(open(tmp_path / "ep.json"))   # well-formed on disk
 
 
 def test_lowered_execute_span(traced):
@@ -162,7 +105,7 @@ def test_standalone_profiling(tmp_path):
     assert recs[0]["info.step"] == 0
 
 
-def test_grapher_dot(tmp_path, dynamic_path):
+def test_grapher_dot(tmp_path):
     comp = repository.find("pins", "grapher")
     mod = comp.open()
     try:
@@ -179,7 +122,7 @@ def test_grapher_dot(tmp_path, dynamic_path):
     assert text.count("->") >= 4
 
 
-def test_sde_counters(dynamic_path):
+def test_sde_counters():
     comp = repository.find("pins", "sde")
     mod = comp.open()
     sde.reset()

@@ -82,7 +82,6 @@ def _fanout_tree(depth, delay=0.002):
 
 
 def test_print_steals_counts(param):
-    param("runtime_dag_compile", False)   # keep selects on the dynamic path
     comp = repository.find("pins", "print_steals")
     mod = comp.open()
     try:
@@ -99,7 +98,6 @@ def test_print_steals_counts(param):
 
 
 def test_alperf_samples_rate(param):
-    param("runtime_dag_compile", False)
     param("pins_alperf_interval", 0.05)
     comp = repository.find("pins", "alperf")
     mod = comp.open()

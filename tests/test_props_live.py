@@ -11,7 +11,6 @@ import time
 import numpy as np
 
 from parsec_tpu import ptg
-import parsec_tpu.runtime.dagrun  # noqa: F401  (registers runtime_dag_compile)
 from parsec_tpu.data_dist.matrix import VectorTwoDimCyclic
 from parsec_tpu.prof.counters import properties, read_live_snapshot, sde
 from parsec_tpu.runtime import Context
@@ -45,7 +44,6 @@ def test_snapshot_readable_during_run(tmp_path, param):
     path = str(tmp_path / "props.json")
     param("props_stream", path)
     param("props_stream_interval", 0.02)
-    param("runtime_dag_compile", False)   # keep the dynamic path visible
 
     V = VectorTwoDimCyclic("V", lm=4, mb=4,
                            init_fn=lambda m, size: np.zeros(size))

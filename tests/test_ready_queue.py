@@ -177,18 +177,25 @@ def two_pushers_a_thief_and_the_spill_deliver_each_task_once(prio):
             for w in range(2)]
     got = [[], []]
     stop = threading.Event()
+    thief_took = threading.Event()
 
     def push(w):
         for i in range(0, n, 50):
             mod.schedule(es0, mine[w][i:i + 50])
 
     def pop(es, out):
+        if es is es0:
+            # the owner pops once the thief holds a task, stolen or
+            # spilled: else a late-starting thief may find nothing left
+            thief_took.wait(timeout=60)
         while not stop.is_set() or mod.pending_tasks(ctx):
             t, _ = mod.select(es)
             if t is not None:
                 out.append(t)
             if len(out) % 7 == 0:       # the flood's pop, in between
                 out += [t for t, _ in mod.select_class(es, "a", 3)[0]]
+            if out and es is es1:
+                thief_took.set()
 
     threads = [threading.Thread(target=push, args=(w,)) for w in range(2)]
     poppers = [threading.Thread(target=pop, args=(es, got[i]))

@@ -175,6 +175,14 @@ def _rec_rank_body(ctx, rank, nranks):
     return True
 
 
+def test_recursive_gemm_2rank_nested_pools_stay_local():
+    """The nested pools are rank-private: their collections (sub-tiles of a
+    flow copy) name no rank, so a successor inside one is this rank's own,
+    never an activation sent to the rank such a collection would say."""
+    res = run_multirank(2, _rec_rank_body)
+    assert all(res)
+
+
 def test_recursive_gemm_8rank_mesh():
     """Outer tiles block-cyclic over 8 ranks; every rank's bodies spawn
     rank-private nested pools (different counts per rank) without

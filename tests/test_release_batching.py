@@ -29,8 +29,6 @@ import pytest
 from parsec_tpu import ptg
 from parsec_tpu.runtime import Context
 
-import parsec_tpu.runtime.dagrun  # noqa: F401 — registers runtime_dag_compile
-
 K_IN = 3     # max in-edges per node (one CTL flow per slot)
 
 
@@ -83,7 +81,6 @@ def _build_pool(in_edges, layers, width, log, lock):
 
 def _drain(param, in_edges, layers, width, storage, nb_cores):
     param("deps_storage", storage)
-    param("runtime_dag_compile", False)   # exercise release_deps itself
     log, lock = [], threading.Lock()
     tp = _build_pool(in_edges, layers, width, log, lock)
     ctx = Context(nb_cores=nb_cores)
@@ -133,7 +130,6 @@ def test_release_many_groups_take_one_path(param):
     through the index-array tier's batch path and still accounts every
     release (the SDE-style engagement proof the dense tier keeps)."""
     param("deps_storage", "index-array")
-    param("runtime_dag_compile", False)
     width = 16
     # FAN(0) -> every SINK(n): one completing task, 16 same-class records
     in_edges = {(1, n): [0] for n in range(width)}
@@ -152,13 +148,11 @@ def test_release_many_groups_take_one_path(param):
 def test_a_release_pays_one_lock_a_class_group_and_one_schedule_a_batch(
         param, monkeypatch):
     """2,000 tasks in 40 layers of 50, each releasing its two successors of
-    the one class: with ``runtime_dag_compile`` off every completion is one
-    ``release_many`` call, which takes the class array's lock once for both
-    records, and every batch that made something ready is one
+    the one class: every completion is one ``release_many`` call, which
+    takes the class array's lock once for both records, and every batch that made something ready is one
     ``schedule_tasks`` call."""
     from parsec_tpu.runtime import scheduling
     param("deps_storage", "index-array")
-    param("runtime_dag_compile", False)
     layers, width = 40, 50
     in_edges = {(d, n): [n, (n + 1) % width]
                 for d in range(1, layers) for n in range(width)}
@@ -293,7 +287,6 @@ def test_an_edge_the_plan_cannot_resolve_takes_the_general_walk(
     on two ranks keep the per-edge walk for those edges, give the answers
     they always gave, and say so in the counters: of ``release_edges``
     handed to local successors, ``release_edges_planned`` went by plan."""
-    param("runtime_dag_compile", False)
     seen = []
     if case == "two_rank":
         from parsec_tpu.comm import run_multirank
@@ -331,7 +324,6 @@ def test_eight_streams_count_every_edge_they_release(param):
     3,900 edges, all by plan, and the counters hold exactly that, which one
     lost update would break."""
     import sys
-    param("runtime_dag_compile", False)
     layers, width = 40, 50
     in_edges = {(d, n): [n, (n + 1) % width]
                 for d in range(1, layers) for n in range(width)}

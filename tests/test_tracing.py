@@ -184,26 +184,19 @@ def test_traced_pool_records_task_spans(installed_spans):
         t.body(lambda es, task, g, l: None)
         return p.build()
 
-    import parsec_tpu.runtime.dagrun  # noqa: F401 — registers the param
-    from parsec_tpu.core.params import params
-    saved = params.get("runtime_dag_compile")
-    params.set("runtime_dag_compile", False)    # dynamic: full PINS
-    try:
-        tp = pool()
-        tr = spans.new_trace()
-        tp._trace = tr
-        tp._trace_enq_ns = time.perf_counter_ns()
-        with Context(nb_cores=0) as ctx:
-            ctx.add_taskpool(tp)
-            ctx.wait(timeout=60)
-            n_traced = len(installed_spans.by_trace(tr.trace_id))
-            untraced = pool()
-            before = len(installed_spans.spans)
-            ctx.add_taskpool(untraced)
-            ctx.wait(timeout=60)
-            assert len(installed_spans.spans) == before
-    finally:
-        params.set("runtime_dag_compile", saved)
+    tp = pool()
+    tr = spans.new_trace()
+    tp._trace = tr
+    tp._trace_enq_ns = time.perf_counter_ns()
+    with Context(nb_cores=0) as ctx:
+        ctx.add_taskpool(tp)
+        ctx.wait(timeout=60)
+        n_traced = len(installed_spans.by_trace(tr.trace_id))
+        untraced = pool()
+        before = len(installed_spans.spans)
+        ctx.add_taskpool(untraced)
+        ctx.wait(timeout=60)
+        assert len(installed_spans.spans) == before
     names = {s[0] for s in installed_spans.by_trace(tr.trace_id)}
     assert {"exec", "release", "queue_wait"} <= names, names
     assert n_traced >= 6 * 2 + 1    # exec+release per task + queue_wait
@@ -458,10 +451,8 @@ def test_tracing_overhead_within_budget(monkeypatch):
     own: tracing added no hot-path site, only the existing PINS branch.
     INSTALLED, a traced pool of n tasks records exactly n ``exec`` and n
     ``release`` spans.  The phase plane, off, builds no object."""
-    import parsec_tpu.runtime.dagrun  # noqa: F401 — runtime_dag_compile
     from collections import Counter
 
-    from parsec_tpu.core.params import params
     from parsec_tpu.prof import pins
     from parsec_tpu.prof.pins import PinsEvent
     from parsec_tpu.runtime import Context
@@ -490,8 +481,6 @@ def test_tracing_overhead_within_budget(monkeypatch):
     table = spans.phase_totals()
     monkeypatch.setattr(spans, "_Phase", built)
     nt, depth = 20, 25
-    saved = params.get("runtime_dag_compile")
-    params.set("runtime_dag_compile", False)    # the dynamic path
     rec = spans.install()
     try:
         assert len(recorder_chains()) == len(task_span_events)
@@ -503,7 +492,6 @@ def test_tracing_overhead_within_budget(monkeypatch):
         ctx.fini()
         names = Counter(s[0] for s in rec.by_trace(tp._trace.trace_id))
     finally:
-        params.set("runtime_dag_compile", saved)
         spans.uninstall()
         if prev is not None:
             spans.install(recorder_obj=prev)
