@@ -30,6 +30,7 @@ def _model_graphs(nt: int, ranks: int = 1):
                                     TwoDimBlockCyclic, VectorTwoDimCyclic)
     from ..models import (cholesky, irregular, lu, pingpong, qr, reduction,
                           stencil, stencil2d, tiled_gemm)
+    from ..models.qrtree import QRTree
     nb = 8
     n = nt * nb
 
@@ -48,6 +49,12 @@ def _model_graphs(nt: int, ranks: int = 1):
     yield "qr", qr.tiled_qr_ptg(
         TwoDimBlockCyclic("A", n, n, nb, nb),
         TwoDimBlockCyclic("T", n, n, nb, nb), devices="cpu")
+    # the hierarchical tree on a tall grid: domains of two rows, the last
+    # of a step shorter where 3 nt - k is odd
+    yield "hqr", qr.tiled_hqr_ptg(
+        *(TwoDimBlockCyclic(name, 3 * n, n, nb, nb)
+          for name in ("A", "TS", "TT")), QRTree(3 * nt, nt, 2),
+        devices="cpu")
     yield "pingpong", pingpong.pingpong_ptg(_vec("V"), 2 * nt)
     yield "reduction", reduction.bt_reduction_ptg(_vec("R"))
     yield "stencil1d", stencil.stencil_1d_ptg(

@@ -78,7 +78,8 @@ _params.register("device_tpu_allow_cpu", False,
 
 def _fused_program(apply: Callable, dyld: str, lanes: int,
                    donates: tuple[int, ...] = (),
-                   compiler_options: dict | None = None) -> Callable:
+                   compiler_options: dict | None = None,
+                   stacked: bool = False) -> Callable:
     """The one jitted program of a same-class batch: ``apply`` once a lane on
     the lane's own tiles (the flat arguments are flow-major: lane i's are
     ``flat[i::lanes]``), the results per written flow, a tuple of the lanes'.
@@ -89,11 +90,23 @@ def _fused_program(apply: Callable, dyld: str, lanes: int,
     (:func:`_donatable` says which can be; the compiled module pairs lane
     i's input with lane i's result); kept on the program as ``.donates``.
     ``compiler_options``: XLA's, for this program alone (a traceable's
-    ``tpu_compiler_options`` on the TPU)."""
+    ``tpu_compiler_options`` on the TPU).  ``stacked``: each flow's lanes
+    are stacked and ``apply`` runs once under ``jax.vmap`` (a traceable's
+    ``vmap_lanes``): for a kernel whose one lane is a large program, such as
+    a Householder QR, that XLA batches as one, where a copy a lane would
+    multiply the code."""
     import jax
 
     def fused(*flat):
         with jax.named_scope("body"):
+            if stacked:
+                import jax.numpy as jnp
+                outs = jax.vmap(apply)(*(
+                    jnp.stack(flat[f:f + lanes])
+                    for f in range(0, len(flat), lanes)))
+                if not isinstance(outs, (tuple, list)):
+                    outs = (outs,)
+                return tuple(tuple(o[i] for i in range(lanes)) for o in outs)
             outs = [apply(*flat[i::lanes]) for i in range(lanes)]
         if not isinstance(outs[0], (tuple, list)):
             return (tuple(outs),)
@@ -119,6 +132,27 @@ def _donatable(apply: Callable, lane: list, written: list[int]) -> tuple:
         outs = (outs,)
     return tuple(w for w, o in zip(written, outs)
                  if o.shape == lane[w].shape and o.dtype == lane[w].dtype)
+
+
+def _avals(args: list[tuple], device: Any) -> list:
+    """``(shape, dtype)`` pairs as abstract arguments placed on ``device``:
+    what a program is compiled for ahead of its first call."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+    where = SingleDeviceSharding(device)
+    return [jax.ShapeDtypeStruct(shape, dtype, sharding=where)
+            for shape, dtype in args]
+
+
+def _stacked_temps(fn: Callable, avals: list) -> int:
+    """The bytes a stacked fused program allocates on the device beside
+    its arguments and results (XLA's ``memory_analysis``), read once when
+    the program is built, by compiling it for ``avals``: its first call
+    then finds it in the persistent compile cache.  A batched QR stacks its
+    lanes' tiles and their results: 913 MiB at 32 TSQRT lanes for the v5e,
+    against 384 of results (PERF.md, section 7)."""
+    mem = fn.lower(*avals).compile().memory_analysis()
+    return int(getattr(mem, "temp_size_in_bytes", 0) or 0)
 
 
 def _compile_quietly(fn: Callable, avals: list) -> None:
@@ -1090,8 +1124,10 @@ class TPUDevice(Device):
         if dyld is None or find_traceable(dyld) is None:
             return
         sched = es.context.scheduler
-        taken, put_back = sched.select_class(
-            es, tc, _params.get("device_tpu_batch_max") - len(batch))
+        most = _params.get("device_tpu_batch_max")
+        if getattr(tc, "batch_max", None) is not None:
+            most = min(most, tc.batch_max)
+        taken, put_back = sched.select_class(es, tc, most - len(batch))
         for t, distance in taken:
             if es.context.best_device(t, self.type) is self:
                 prepare_input(es, t)
@@ -1106,9 +1142,11 @@ class TPUDevice(Device):
         batch = [self._pending.popleft()]
         if _params.get("device_tpu_batch"):
             first = batch[0]
+            most = getattr(first.task.task_class, "batch_max", None)
             while self._pending and \
                     self._pending[0].task.task_class is first.task.task_class \
-                    and self._pending[0].submit is first.submit:
+                    and self._pending[0].submit is first.submit \
+                    and (most is None or len(batch) < most):
                 batch.append(self._pending.popleft())
         return batch
 
@@ -1331,12 +1369,17 @@ class TPUDevice(Device):
         # scoped VMEM: models/lu.py); no other backend knows them
         opts = getattr(tr.apply, "tpu_compiler_options", None) \
             if self.jax_device.platform == "tpu" else None
+        stacked = getattr(tr.apply, "vmap_lanes", False)
         if fn is None:
+            args = [a for a in sig for _ in range(Bp)]
             fn = self._vmap_cache[key] = _fused_program(
                 tr.apply, dyld, Bp,
-                _donatable(tr.apply, [vs[0] for vs in cols], written), opts)
-            warming = self._compile_for_peers(
-                "_vmap_cache", key, fn, [a for a in sig for _ in range(Bp)])
+                _donatable(tr.apply, [vs[0] for vs in cols], written), opts,
+                stacked)
+            warming = self._compile_for_peers("_vmap_cache", key, fn, args)
+            if stacked:
+                fn.temps = _stacked_temps(
+                    fn, _avals(args, self.jax_device))
         if fn.donates and not all(self._sole_holder(copies[w], cols[w])
                                   for w in fn.donates
                                   if copies[w] is not None):
@@ -1345,25 +1388,29 @@ class TPUDevice(Device):
             key += ("plain",)
             fn = self._vmap_cache.get(key)
             if fn is None:
+                args = [a for a in sig for _ in range(Bp)]
                 fn = self._vmap_cache[key] = _fused_program(
-                    tr.apply, dyld, Bp, compiler_options=opts)
-                warming = self._compile_for_peers(
-                    "_vmap_cache", key, fn,
-                    [a for a in sig for _ in range(Bp)])
+                    tr.apply, dyld, Bp, compiler_options=opts,
+                    stacked=stacked)
+                warming = self._compile_for_peers("_vmap_cache", key, fn,
+                                                  args)
+                if stacked:
+                    fn.temps = _stacked_temps(
+                        fn, _avals(args, self.jax_device))
         # what the call allocates: Bp results for each written flow that is
         # not donated, which supersede B current versions and pad Bp - B
         # lanes and stay until the call has run (a flow's tiles are of one
         # shape: one nbytes a flow, not a tile); a donated flow's results
-        # take its inputs' buffers.  Its temporaries are not asked for: at
+        # take its inputs' buffers.  A stacked program's temporaries are
+        # asked for too (``temps``).  A per-lane program's are not: at
         # 4 MiB tiles the v5e compiler's memory_analysis gives the program 0
         # bytes of them for gemm / gemm_nt (64 lanes), syrk_ln (16),
         # qr_tsmqr / qr_unmqr (32), 11 MiB for trsm_rlt (16: 64 MiB of
-        # results) and 100 MiB for qr_tsqrt (32: 384 MiB; no cell batches
-        # it); tests/test_fused_tpu_compile.py holds the classes the cells
-        # batch to temporaries under a quarter of their results, donating
-        # or not
+        # results); tests/test_fused_tpu_compile.py holds the per-lane
+        # classes the cells batch to temporaries under a quarter of their
+        # results, donating or not
         held = Bp * sum(cols[w][0].nbytes for w in written
-                        if w not in fn.donates)
+                        if w not in fn.donates) + getattr(fn, "temps", 0)
         self._make_room(held)
         # pad lanes: copies of lane 0, whose results are dropped; in a
         # donated flow a scratch tile each, which the pad result takes and
@@ -1429,8 +1476,6 @@ class TPUDevice(Device):
         argument (no array: a reference more to a tile would keep a call
         from donating it).  Returns the threads to join; nothing with one
         accelerator."""
-        import jax
-        from jax.sharding import SingleDeviceSharding
         kind = self.jax_device.device_kind
         threads = []
         for peer in registry.devices:
@@ -1439,11 +1484,9 @@ class TPUDevice(Device):
                     or peer.jax_device.device_kind != kind:
                 continue
             getattr(peer, cache)[key] = fn
-            where = SingleDeviceSharding(peer.jax_device)
-            avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=where)
-                     for shape, dtype in args]
-            thread = threading.Thread(target=_compile_quietly,
-                                      args=(fn, avals), daemon=True)
+            thread = threading.Thread(
+                target=_compile_quietly,
+                args=(fn, _avals(args, peer.jax_device)), daemon=True)
             thread.start()
             threads.append(thread)
         return threads

@@ -1,4 +1,6 @@
-"""Tiled QR factorization (flat tree): the PTG of DPLASMA's ``zgeqrf.jdf``.
+"""Tiled QR factorization: the PTGs of DPLASMA's ``zgeqrf.jdf`` (the flat
+tree, ``tiled_qr_ptg``) and ``zgeqrf_param.jdf`` (a hierarchical tree,
+``tiled_hqr_ptg``).
 
 The tile algorithm of ``dplasma_sgeqrf`` with A in tiles and the block
 reflectors' triangular factors in a second descriptor T (tile (m, k), m >= k):
@@ -12,11 +14,21 @@ reflectors' triangular factors in a second descriptor T (tile (m, k), m >= k):
 - ``TSMQR(m,n,k)`` — trailing update of the pair ``[A(k,n); A(m,n)]`` by
   that block reflector, chained over m along a column and over k in place.
 
+``dplasma_sgeqrf_param`` runs the same kernels over a tree
+(``qrtree.py``): a GEQRT on the head of every domain, the domain's rows
+killed onto it by TS kills, and the heads killed onto row k by two more:
+
+- ``TTQRT(m,k)``  — QR of two stacked upper triangles ``[triu(R_p);
+  triu(A(m,k))]`` (LAPACK ``tpqrt`` with l = nb): the reflectors' lower
+  block V2 is upper triangular and goes to A(m,k)'s upper triangle, where
+  the head's own GEQRT reflectors stay below it;
+- ``TTMQR(m,n,k)`` — TSMQR's update with that triangular V2 (``tpmqrt``).
+
 GEQRT and TSMQR write two tiles a task, TSQRT three.  The inner blocking is
 the tile (``ib = nb``): T is a full nb x nb upper-triangular tile and a block
 reflector is applied as three dense products.
 
-Precision: every product and triangular solve of the four traceables is traced
+Precision: every product and triangular solve of the six traceables is traced
 under ``jax.default_matmul_precision("highest")`` (``_highest``: true f32 on
 the TPU's MXU, ``Precision.HIGHEST``), stated here and selected by no
 parameter: a Householder QR whose reflector products are
@@ -33,6 +45,7 @@ import numpy as np
 from .. import ptg
 from ..data_dist.matrix import TiledMatrix
 from ..device.kernels import register_kernel, traceable_body
+from .qrtree import TS, TT, QRTree
 
 
 # ---------------------------------------------------------------------------
@@ -107,15 +120,37 @@ def _tsqrt_cpu(es: Any, task: Any, g: Any, l: Any) -> None:
     _write(task.flow_data("T"), larft_np(np.vstack([np.eye(nb), v2]), tau))
 
 
-def _tsmqr_cpu(es: Any, task: Any, g: Any, l: Any) -> None:
+def _ttqrt_cpu(es: Any, task: Any, g: Any, l: Any) -> None:
+    r, b = task.flow_data("R"), task.flow_data("B")
+    rv = np.asarray(r.value, np.float64)
+    bv = np.asarray(b.value, np.float64)
+    nb = rv.shape[0]
+    h, tau = _house_np(np.vstack([np.triu(rv), np.triu(bv)]))
+    # the stack's lower block stays upper triangular through every
+    # reflection: its zeros are exact
+    v2 = np.triu(h[nb:])
+    _write(r, np.triu(h[:nb]) + np.tril(rv, -1))
+    # the head's GEQRT reflectors, strictly below, stay as they were
+    _write(b, v2 + np.tril(bv, -1))
+    _write(task.flow_data("T"), larft_np(np.vstack([np.eye(nb), v2]), tau))
+
+
+def _tsmqr_cpu(es: Any, task: Any, g: Any, l: Any, tri: bool = False
+               ) -> None:
     a1, a2 = task.flow_data("A1"), task.flow_data("A2")
     v = np.asarray(task.flow_data("V").value, np.float64)
+    if tri:                 # TTMQR: V2 is the tile's upper triangle
+        v = np.triu(v)
     t = np.asarray(task.flow_data("T").value, np.float64)
     a1v = np.asarray(a1.value, np.float64)
     a2v = np.asarray(a2.value, np.float64)
     w = t.T @ (a1v + v.T @ a2v)
     _write(a1, a1v - w)
     _write(a2, a2v - v @ w)
+
+
+def _ttmqr_cpu(es: Any, task: Any, g: Any, l: Any) -> None:
+    _tsmqr_cpu(es, task, g, l, tri=True)
 
 
 # ---------------------------------------------------------------------------
@@ -221,8 +256,43 @@ def _tsmqr_traceable(a1, a2, v, t):
     return a1 - w, a2 - _dot(v, w)
 
 
+@_highest
+def _ttqrt_traceable(r, b, t):
+    """``(h, tau)`` = Householder QR of ``[triu(R); triu(B)]``.  Its
+    reflectors are ``[I; V2]`` with ``V2 = triu(h[nb:])``: the lower block
+    keeps the zeros below its diagonal through every reflection.
+    ``R <- triu(h[:nb]) + tril(R, -1)``, ``B <- V2 + tril(B, -1)`` (the
+    head's GEQRT reflectors, bit for bit), ``T <- larft([I; V2], tau)``."""
+    _, jnp, _ = _jnp()
+    r, b = jnp.asarray(r, jnp.float32), jnp.asarray(b, jnp.float32)
+    nb = r.shape[0]
+    h, tau = _householder(jnp.concatenate([jnp.triu(r), jnp.triu(b)],
+                                          axis=0))
+    v2 = jnp.triu(h[nb:])
+    v = jnp.concatenate([jnp.eye(nb, dtype=r.dtype), v2], axis=0)
+    return (jnp.triu(h[:nb]) + jnp.tril(r, -1), v2 + jnp.tril(b, -1),
+            larft(v, tau))
+
+
+@_highest
+def _ttmqr_traceable(a1, a2, v, t):
+    """TSMQR's three dense products with ``V2 = triu(V)``: the tile's
+    strictly lower part is its head's GEQRT reflectors, not this one's
+    (6 nb^3 where LAPACK's ``tpmqrt`` counts 2)."""
+    _, jnp, _ = _jnp()
+    return _tsmqr_traceable(a1, a2, jnp.triu(v), t)
+
+
+# a fused batch of a QR's lanes runs as one batched QR (``vmap``): one lane
+# of these is a program of 12-18 MiB for the v5e, and 32 lanes unrolled
+# compiled to 570 MiB in 73 s where stacked they compile to 50 MiB in 34 s
+# (compiled for the described v5e, not run: PERF.md, section 6)
+for _tr in (_geqrt_traceable, _tsqrt_traceable, _ttqrt_traceable):
+    _tr.vmap_lanes = True
+
 _TRACEABLES = {"qr_geqrt": _geqrt_traceable, "qr_unmqr": _unmqr_traceable,
-               "qr_tsqrt": _tsqrt_traceable, "qr_tsmqr": _tsmqr_traceable}
+               "qr_tsqrt": _tsqrt_traceable, "qr_tsmqr": _tsmqr_traceable,
+               "qr_ttqrt": _ttqrt_traceable, "qr_ttmqr": _ttmqr_traceable}
 
 
 @functools.cache
@@ -397,3 +467,226 @@ def tiled_qr_ptg(A: TiledMatrix, T: TiledMatrix,
         ts.body(_tsqrt_cpu)
         tm.body(_tsmqr_cpu)
     return p.build()
+
+
+# ---------------------------------------------------------------------------
+# the hierarchical PTG (zgeqrf_param.jdf)
+# ---------------------------------------------------------------------------
+
+# the panel's classes and flows, and the trailing update's: a head's tile
+# (``head``), the killer's tile through its kills (``on``), a killed head's
+# own tile (``own``), the column a task works on (``col``)
+_PANEL = {"head": ("GEQRT", "A"), TS: "TSQRT", TT: "TTQRT", "on": "R",
+          "own": "B", "col": lambda l: l.k}
+_UPDATE = {"head": ("UNMQR", "C"), TS: "TSMQR", TT: "TTMQR", "on": "A1",
+           "own": "A2", "col": lambda l: l.n}
+
+
+def _at(side: dict, l: Any, m: int) -> dict:
+    """The locals of ``side``'s task on row m at the step (and column) of
+    ``l``."""
+    if side is _PANEL:
+        return {"k": l.k, "m": m}
+    return {"k": l.k, "m": m, "n": l.n}
+
+
+# the most lanes a device batch of an update class holds.  A lane of these
+# is its own 2.3-3 MiB of code for the v5e, and each power of two up to the
+# largest batch a program the compile cache keeps (0.55-0.6 MiB of cache a
+# lane); at 64 lanes a solve's programs came to 235 MiB of cache, past the
+# 190 the chip's machine keeps, and every run compiled them all again.  At
+# 32 UNMQR and TSMQR run the programs of the flat tree's cells, and a TT
+# level's 16 kills bound TTMQR (PERF.md, section 6)
+UPDATE_LANES = {"UNMQR": 32, "TSMQR": 32, "TTMQR": 16}
+
+
+def tiled_hqr_ptg(A: TiledMatrix, TS_: TiledMatrix, TT_: TiledMatrix,
+                  tree: QRTree, devices: str = "auto") -> "ptg.PTGTaskpool":
+    """Build the hierarchical QR PTG of ``zgeqrf_param.jdf`` over a tall or
+    square tile grid: A is factored in place (R in its top NT x NT tiles,
+    above the diagonal; every reflector below it: a TS kill's V2 fills its
+    tile, a TT kill's V2 is the upper triangle of a head's tile whose lower
+    part holds the head's GEQRT reflectors).  TS(m, k) takes the triangular
+    factor of GEQRT and TSQRT on row m at step k, TT(m, k) that of TTQRT;
+    nothing else of them is read or written.  Every edge comes from
+    ``tree`` (``qrtree.py``), whose tables are built when the pool is
+    enqueued; the pool keeps ``panel_levels``, the panel's critical path
+    in kernels summed over the steps."""
+    MT, NT = A.mt, A.nt
+    if (tree.mt, tree.nt) != (MT, NT):
+        raise ValueError(f"{tree!r} is not over A's {MT} x {NT} tiles")
+    for T in (TS_, TT_):
+        assert (T.mt, T.nt, T.mb, T.nb) == (A.mt, A.nt, A.mb, A.nb), \
+            "TS and TT are tiled as A"
+    t = tree
+    p = ptg.PTGBuilder("hqr", A=A, TS=TS_, TT=TT_, NT=NT)
+    steps = ptg.span(0, NT - 1)
+    cols = ptg.span(lambda g, l: l.k + 1, NT - 1)
+    more = lambda g, l: l.k < NT - 1                        # noqa: E731
+
+    def after(fb: Any, side: dict, killer: Any, nxt: Any) -> None:
+        """Where the tile of row ``killer(l)`` goes once it has killed the
+        rows before ``nxt(l)`` (None: all of its kills): to its next kill,
+        to the TT kill of itself, or, on row k, home."""
+        for kind in (TS, TT):
+            fb.output(succ=(side[kind], side["on"],
+                            lambda g, l: _at(side, l, nxt(l))),
+                      guard=lambda g, l, _k=kind:
+                      (q := nxt(l)) is not None and t.kind(l.k, q) == _k)
+        fb.output(succ=(side[TT], side["own"],
+                        lambda g, l: _at(side, l, killer(l))),
+                  guard=lambda g, l: nxt(l) is None and killer(l) != l.k)
+        fb.output(data=("A", lambda g, l: (l.k, side["col"](l))),
+                  guard=lambda g, l: nxt(l) is None and killer(l) == l.k)
+
+    def before(fb: Any, side: dict, killer: Any, prev: Any) -> None:
+        """Where the tile of row ``killer(l)`` comes from when it has killed
+        the rows up to ``prev(l)`` (None: none yet, its head task's)."""
+        cls, flow = side["head"]
+        fb.input(pred=(cls, flow, lambda g, l: _at(side, l, killer(l))),
+                 guard=lambda g, l: prev(l) is None)
+        for kind in (TS, TT):
+            fb.input(pred=(side[kind], side["on"],
+                           lambda g, l: _at(side, l, prev(l))),
+                     guard=lambda g, l, _k=kind:
+                     (q := prev(l)) is not None and t.kind(l.k, q) == _k)
+
+    def from_step_before(fb: Any, side: dict) -> None:
+        """Row m's tile in this task's column, as step k - 1 left it: the
+        update of the kill of m there."""
+        fb.input(data=("A", lambda g, l: (l.m, side["col"](l))),
+                 guard=lambda g, l: l.k == 0)
+        for kind, cls in ((TS, "TSMQR"), (TT, "TTMQR")):
+            fb.input(pred=(cls, "A2", lambda g, l: {
+                "k": l.k - 1, "m": l.m, "n": side["col"](l)}),
+                guard=lambda g, l, _k=kind:
+                l.k > 0 and t.kind(l.k - 1, l.m) == _k)
+
+    def to_step_after(fb: Any) -> None:
+        """An update's row-m tile onward, to the first task on it at step
+        k + 1: the panel's or the update's, as row m is a head there."""
+        for cls, flow, panel, head in (
+                ("GEQRT", "A", True, True), ("TSQRT", "B", True, False),
+                ("UNMQR", "C", False, True), ("TSMQR", "A2", False, False)):
+            fb.output(succ=(cls, flow, (lambda g, l: {"k": l.k + 1,
+                                                      "m": l.m})
+                            if panel else (lambda g, l: {
+                                "k": l.k + 1, "m": l.m, "n": l.n})),
+                      guard=lambda g, l, _p=panel, _h=head:
+                      (l.n == l.k + 1) is _p
+                      and t.is_head(l.k + 1, l.m) is _h)
+
+    def updates(cls: str, flow: str) -> tuple:
+        return (cls, flow, lambda g, l: [{"k": l.k, "m": l.m, "n": n}
+                                         for n in range(l.k + 1, NT)])
+
+    killer = lambda l: t.killer(l.k, l.m)                   # noqa: E731
+    me = lambda l: l.m                                      # noqa: E731
+    first = lambda l: t.first_kill(l.k, l.m)                # noqa: E731
+    last = lambda l: t.last_kill(l.k, l.m)                  # noqa: E731
+    prev = lambda l: t.prev_kill(l.k, l.m)                  # noqa: E731
+    nxt = lambda l: t.next_kill(l.k, l.m)                   # noqa: E731
+
+    # ---- GEQRT(k, m): m a head of step k -----------------------------------
+    ge = p.task("GEQRT", k=steps, m=lambda g, l: t.heads(l.k))
+    ge.affinity("A", lambda g, l: (l.m, l.k))
+    ge.priority(lambda g, l: 4 * (NT - l.k) + 3)          # the panel first
+    gA = ge.flow("A", ptg.RW)
+    from_step_before(gA, _PANEL)
+    gA.output(succ=updates("UNMQR", "V"), guard=more)
+    after(gA, _PANEL, me, first)
+    gT = ge.flow("T", ptg.RW)
+    gT.input(data=("TS", lambda g, l: (l.m, l.k)))
+    gT.output(succ=updates("UNMQR", "T"), guard=more)
+    gT.output(data=("TS", lambda g, l: (l.m, l.k)))
+
+    # ---- UNMQR(k, m, n): a head's row ---------------------------------------
+    un = p.task("UNMQR", k=ptg.span(0, NT - 2),
+                m=lambda g, l: t.heads(l.k), n=cols)
+    un.affinity("A", lambda g, l: (l.m, l.n))
+    un.priority(lambda g, l: 4 * (NT - l.k) + 1)
+    un.batch_max(UPDATE_LANES["UNMQR"])
+    un.flow("V", ptg.READ).input(
+        pred=("GEQRT", "A", lambda g, l: {"k": l.k, "m": l.m}))
+    un.flow("T", ptg.READ).input(
+        pred=("GEQRT", "T", lambda g, l: {"k": l.k, "m": l.m}))
+    uC = un.flow("C", ptg.RW)
+    from_step_before(uC, _UPDATE)
+    after(uC, _UPDATE, me, first)
+
+    # ---- TSQRT(k, m) / TTQRT(k, m): the kill of row m ---------------------
+    # R is the killer's tile, carried from kill to kill; its strictly lower
+    # part (the killer's GEQRT reflectors) is never changed, so UNMQR reads
+    # the right V from any version of the tile.  TTQRT's B is a killed
+    # head's tile after its own kills, whose lower part is kept the same way
+    kills = {}
+    for name, kind, tcoll in (("TSQRT", TS, "TS"), ("TTQRT", TT, "TT")):
+        rows = t.ts_rows if kind == TS else t.tt_rows
+        kq = p.task(name, k=steps, m=lambda g, l, _r=rows: _r(l.k))
+        kq.affinity("A", lambda g, l: (l.m, l.k))
+        kq.priority(lambda g, l: 4 * (NT - l.k) + 2)
+        kR = kq.flow("R", ptg.RW)
+        before(kR, _PANEL, killer, prev)
+        after(kR, _PANEL, killer, nxt)
+        kB = kq.flow("B", ptg.RW)
+        if kind == TS:
+            from_step_before(kB, _PANEL)
+        else:
+            before(kB, _PANEL, me, last)
+        kB.output(succ=updates("TSMQR" if kind == TS else "TTMQR", "V"),
+                  guard=more)
+        kB.output(data=("A", lambda g, l: (l.m, l.k)))
+        kT = kq.flow("T", ptg.RW)
+        kT.input(data=(tcoll, lambda g, l: (l.m, l.k)))
+        kT.output(succ=updates("TSMQR" if kind == TS else "TTMQR", "T"),
+                  guard=more)
+        kT.output(data=(tcoll, lambda g, l: (l.m, l.k)))
+        kills[kind] = kq
+
+    # ---- TSMQR(k, m, n) / TTMQR(k, m, n): the kill's update of column n ---
+    mqrs = {}
+    for name, kind in (("TSMQR", TS), ("TTMQR", TT)):
+        rows = t.ts_rows if kind == TS else t.tt_rows
+        mq = p.task(name, k=ptg.span(0, NT - 2),
+                    m=lambda g, l, _r=rows: _r(l.k), n=cols)
+        mq.affinity("A", lambda g, l: (l.m, l.n))
+        mq.priority(lambda g, l: 4 * (NT - l.k))
+        mq.batch_max(UPDATE_LANES[name])
+        m1 = mq.flow("A1", ptg.RW)
+        before(m1, _UPDATE, killer, prev)
+        after(m1, _UPDATE, killer, nxt)
+        m2 = mq.flow("A2", ptg.RW)
+        if kind == TS:
+            from_step_before(m2, _UPDATE)
+        else:
+            before(m2, _UPDATE, me, last)
+        to_step_after(m2)
+        src = "TSQRT" if kind == TS else "TTQRT"
+        mq.flow("V", ptg.READ).input(
+            pred=(src, "B", lambda g, l: {"k": l.k, "m": l.m}))
+        mq.flow("T", ptg.READ).input(
+            pred=(src, "T", lambda g, l: {"k": l.k, "m": l.m}))
+        mqrs[kind] = mq
+
+    # LAPACK's counts feed best-device selection
+    nb = A.mb
+    classes = ((ge, 4 / 3, "qr_geqrt", _geqrt_cpu),
+               (un, 2, "qr_unmqr", _unmqr_cpu),
+               (kills[TS], 2, "qr_tsqrt", _tsqrt_cpu),
+               (kills[TT], 2 / 3, "qr_ttqrt", _ttqrt_cpu),
+               (mqrs[TS], 4, "qr_tsmqr", _tsmqr_cpu),
+               (mqrs[TT], 2, "qr_ttmqr", _ttmqr_cpu))
+    for tc, count, dyld, cpu in classes:
+        tc.time_estimate(lambda task, dev, _c=count:
+                         _c * nb ** 3 / (dev.gflops_fp32 * 1e9))
+        if devices in ("auto", "tpu"):
+            tc.body(device="tpu", dyld=dyld)
+        if devices in ("auto", "cpu"):
+            tc.body(cpu)
+    pool = p.build()
+
+    def enqueued(tp: Any) -> None:
+        # the tree's tables, built here: under ctx.add_taskpool's span
+        tp.panel_levels = t.panel_levels
+    pool.on_enqueue = enqueued
+    return pool

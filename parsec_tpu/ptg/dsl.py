@@ -158,6 +158,7 @@ class TaskClassBuilder:
         self._stage_in_hook: Callable | None = None
         self._stage_out_hook: Callable | None = None
         self._pad_rows: tuple[int, int] | None = None
+        self._batch_max: int | None = None
 
     # -- structure ----------------------------------------------------------
     def affinity(self, collection: Any, key_fn: Callable) -> "TaskClassBuilder":
@@ -194,6 +195,14 @@ class TaskClassBuilder:
         The kernel has to leave a zero tile zero; what it returns for one
         goes back to the device's pool of zeros."""
         self._pad_rows = (int(lead), int(bucket))
+        return self
+
+    def batch_max(self, lanes: int) -> "TaskClassBuilder":
+        """A device batch of the class holds at most ``lanes`` instances
+        (below ``device_tpu_batch_max``): each lane of a fused batch program
+        is code of its own, and every lane count up to the largest batch is
+        a program that the compile cache has to hold."""
+        self._batch_max = int(lanes)
         return self
 
     # -- user-defined overrides (the jdf.h:185-210 UD property family) ------
@@ -370,6 +379,7 @@ class TaskClassBuilder:
         if self._stage_out_hook is not None:
             tc.stage_out_hook = self._stage_out_hook
         tc.pad_rows = self._pad_rows
+        tc.batch_max = self._batch_max
 
         # execution-space membership (the generated bounds-check role):
         # parameters validate in declaration order against their ranges.

@@ -263,6 +263,9 @@ class TaskClass:
         # a device batch may append zero tiles to the trailing data flows
         # (``pad_rows``: (lead, bucket), set by the front end; None: never)
         self.pad_rows: tuple[int, int] | None = None
+        # the most instances a device batch holds (``batch_max``, set by the
+        # front end; None: the device's own bound)
+        self.batch_max: int | None = None
         # make_key on the C path: itemgetter over the param names
         from operator import itemgetter
         if len(self.params) >= 2:

@@ -2,12 +2,13 @@
 (``benchmarks/tests/``: the manifest, the trace reduction, the control, a
 traced rehearsal of every cell sound and broken, the phase metrics, the 64k
 cell's own, the DTD cell's own, the QR cell's own, the four-chip cell's own,
-the pivoted LU cell's own)
+the pivoted LU cell's own, the hierarchical QR cell's own)
 and ``yardstick_writeback_early_share.py``, ``yardstick_flood_metrics.py``,
 ``yardstick_stage_in_ms.py``, ``yardstick_dispatch_metrics.py``,
 ``yardstick_donated_share.py``, ``yardstick_qr_cell.py``,
-``yardstick_ring_excused_share.py``, ``yardstick_getrf_cell.py`` and
-``yardstick_lru_touches.py`` beside this file are collected here under
+``yardstick_ring_excused_share.py``, ``yardstick_getrf_cell.py``,
+``yardstick_lru_touches.py`` and ``yardstick_hqr_cell.py`` beside this
+file are collected here under
 their own names, so each counts, and a name that two files give is an error
 here and not one test fewer.  They need no chip.  The rehearsals run in
 processes of their own, and all from this one file, so that under ``--dist
@@ -27,7 +28,10 @@ one chip and stopped holding when PR 40 appended ``geqrf52k.ctx4``:
 ``yardstick_qr_cell.py`` is that test without that one assertion.  Nor are
 the two tests that stopped holding when PR 42 gave the start-up metric the
 accepted cells' list: ``yardstick_getrf_cell.py`` has them as they hold
-now, and they run where the others stood."""
+now, and they run where the others stood.  Nor is ``test_getrf44k.py``'s
+test of the manifest, which holds the shared lists to end with its cell and
+stopped holding when ``hqr128kx8k.dynamic`` was appended:
+``yardstick_hqr_cell.py`` has it with the later cell after it."""
 
 import importlib.util
 import os
@@ -43,7 +47,9 @@ _SUPERSEDED = {
     # by test_manifest_still_lists_the_qr_cell_where_it_was_appended
     "test_manifest_lists_the_qr_cell_where_it_was_appended",
     # by test_manifest_still_lists_the_phase_metrics_on_the_two_16k_cells
-    "test_manifest_lists_the_phase_metrics_on_the_dynamic_cells_only"}
+    "test_manifest_lists_the_phase_metrics_on_the_dynamic_cells_only",
+    # by test_manifest_still_lists_the_getrf_cell_where_it_was_appended
+    "test_manifest_lists_the_getrf_cell_where_it_was_appended"}
 # tests of yardstick_getrf_cell.py that take the place of one that stopped
 # holding (PR 42), where it stood in the run: the file's order decides what
 # its rehearsals meet beside them on the other workers
@@ -66,7 +72,7 @@ _getrf_cell = _load(_HERE, "yardstick_getrf_cell")
 for _dir, _name in ((_BENCH, "test_yardstick"), (_BENCH, "test_phase_metrics"),
                     (_BENCH, "test_potrf64k"), (_BENCH, "test_dtd_gemm"),
                     (_BENCH, "test_geqrf32k"), (_BENCH, "test_geqrf52k_ctx4"),
-                    (_BENCH, "test_getrf44k"),
+                    (_BENCH, "test_getrf44k"), (_BENCH, "test_hqr128kx8k"),
                     (_HERE, "yardstick_writeback_early_share"),
                     (_HERE, "yardstick_flood_metrics"),
                     (_HERE, "yardstick_stage_in_ms"),
@@ -74,7 +80,8 @@ for _dir, _name in ((_BENCH, "test_yardstick"), (_BENCH, "test_phase_metrics"),
                     (_HERE, "yardstick_donated_share"),
                     (_HERE, "yardstick_qr_cell"),
                     (_HERE, "yardstick_ring_excused_share"),
-                    (_HERE, "yardstick_lru_touches")):
+                    (_HERE, "yardstick_lru_touches"),
+                    (_HERE, "yardstick_hqr_cell")):
     _tests = {}
     for _k, _v in vars(_load(_dir, _name)).items():
         if _k in _IN_PLACE:

@@ -115,3 +115,30 @@ def test_the_pivoting_panel_compiles_with_its_own_scoped_vmem(one_chip):
                          tr.tpu_compiler_options).lower(
         piv, *rows).compile().memory_analysis()
     assert mem.alias_size_in_bytes == 16 * NB * NB * 4 + 4 * NB * 4
+
+
+def test_a_batch_of_tree_kills_compiles_as_one_batched_qr(one_chip):
+    """``jit_fused_qr_ttqrt`` at 16 lanes, a binary tree's first level at
+    the hierarchical QR cell's size: the traceable's ``vmap_lanes``
+    stacks each flow's lanes and runs one batched Householder QR, where a
+    copy a lane compiled to 285 MiB of code at 16 lanes and 570 at 32 (the
+    described v5e; PERF.md, section 6).  The donated R and B take their inputs'
+    buffers; T, written whole, reads nothing of its input.  The stacks are
+    temporaries larger than the results (523 MiB against 192), which is
+    why ``_run_vmapped`` asks the budget for a stacked program's
+    (``temps``)."""
+    import parsec_tpu.models.qr  # noqa: F401  (registers traceables)
+    from parsec_tpu.device.tpu import _donatable, _fused_program
+    from parsec_tpu.ptg.lowering import find_traceable
+    lanes = 16
+    apply = find_traceable("qr_ttqrt").apply
+    assert apply.vmap_lanes
+    tile = jax.ShapeDtypeStruct((NB, NB), jnp.float32, sharding=one_chip)
+    donates = _donatable(apply, [tile] * 3, [0, 1, 2])
+    mem = _fused_program(apply, "qr_ttqrt", lanes, donates,
+                         stacked=True).lower(
+        *[tile] * (3 * lanes)).compile().memory_analysis()
+    assert mem.generated_code_size_in_bytes < 64 << 20, mem
+    assert mem.alias_size_in_bytes == 2 * lanes * NB * NB * 4
+    assert mem.output_size_in_bytes < 3 * lanes * NB * NB * 4 + (1 << 20)
+    assert mem.temp_size_in_bytes > 3 * lanes * NB * NB * 4

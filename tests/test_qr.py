@@ -177,8 +177,11 @@ def test_every_written_flow_gets_a_new_version_and_a_dirty_device_copy(
         assert dev.calls_by_class == {"TSQRT": 1}
         # three written tiles a lane, 32 lanes: each result took the buffer
         # of the version it supersedes, the pad lane's three a scratch
-        # tile's, so nothing stays allocated for the call beside the pool
-        assert dev._held_bytes == 0 and dev.donated_results == 32 * 3
+        # tile's, so the ring holds nothing for the call but the stacked
+        # QR's temporaries until it has run
+        (fn,) = dev._vmap_cache.values()
+        assert dev._held_bytes == fn.temps > 0
+        assert dev.donated_results == 32 * 3
         assert dev._scratch_bytes == 3 * NB * NB * 4
     else:
         held = sum(c.value.nbytes for c in dev._written_copies(tasks[0]))

@@ -1,6 +1,7 @@
 """``devmod.lru_touches_per_hit``: the manifest lists it after the forty-four
-entries before it, on the six dynamic cells of the PTG front end and not
-on ``gemm16k.dtd`` (``yardstick_stage_in_ms.py`` holds the set of metrics the
+entries before it, on the dynamic cells of the PTG front end (the six of
+its PR and ``hqr128kx8k.dynamic``, appended since) and not on
+``gemm16k.dtd`` (``yardstick_stage_in_ms.py`` holds the set of metrics the
 DTD cell shares with its twin); its reader is held to hand-made accelerators
 (a program from before the touch, which lacks the counter, no hit yet, sums
 over the accelerators); a traced rehearsal of a dynamic cell reports a share under
@@ -19,7 +20,8 @@ from yardstick_writeback_early_share import BENCH, ROOT, _load, _rehearse
 
 NAME = "devmod.lru_touches_per_hit"
 CELLS = ["gemm16k.dynamic", "potrf16k.dynamic", "potrf64k.dynamic",
-         "geqrf32k.dynamic", "geqrf52k.ctx4", "getrf44k.dynamic"]
+         "geqrf32k.dynamic", "geqrf52k.ctx4", "getrf44k.dynamic",
+         "hqr128kx8k.dynamic"]
 
 
 def test_manifest_lists_the_touch_share_on_the_ptg_dynamic_cells():
